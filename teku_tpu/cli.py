@@ -156,9 +156,9 @@ def _configure_kernel(args, yaml_cfg):
     """Kernel-layer knobs that must be decided BEFORE jax loads:
 
     - the mont_mul engine (`--mont-path` / TEKU_TPU_MONT_MUL: vpu |
-      mxu | auto; auto = the int8 digit-split MXU path exactly when
-      the dispatch device is a TPU) — resolved by ops/mxu.py at trace
-      time in the probe/dispatch threads;
+      mxu | auto; auto = vpu until a chip measurement earns the int8
+      digit-split MXU path its place) — resolved by ops/mxu.py at
+      trace time in the probe/dispatch threads;
     - the scalars-stage MSM path (`--msm-path` / TEKU_TPU_MSM: ladder
       | pippenger | auto; auto = the GLV+Pippenger bucketed MSM
       exactly when the dispatch device is a TPU and the batch clears
@@ -211,8 +211,8 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     ``auto`` (the default) and ``supervised`` boot the node immediately
     on the pure oracle and return a BackendSupervisor the node runs in
     the background: device bring-up gets unbounded-but-observable
-    patience instead of a 120 s probe that a ~25-minute TPU init can
-    never beat (VERDICT round 5), and on READY the facade hot-swaps.
+    patience instead of a 120 s probe that a minutes-long cold compile
+    can never beat, and on READY the facade hot-swaps.
     ``jax`` keeps the reference-style hard preflight (Teku.java:74);
     ``pure`` opts out.  Returns (name, supervisor-or-None)."""
     from .crypto.bls import loader
@@ -232,7 +232,8 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
                                 msm_path=msm_path, mesh=mesh)
     except loader.BlsLoadError as exc:
         raise SystemExit(f"BLS preflight failed: {exc}")
-    print(f"BLS implementation: {name}")
+    where = "" if name == "pure" else f" on {loader.device_label()}"
+    print(f"BLS implementation: {name}{where}")
     return name, None
 
 
@@ -1276,8 +1277,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="mont_mul engine for the verify kernels: vpu "
                         "(elementwise int64), mxu (int8 digit-split "
                         "matmul on the TPU matrix unit), auto "
-                        "(default: mxu exactly when the dispatch "
-                        "device is a TPU).  mxu on a non-TPU device "
+                        "(default: vpu, until a chip measurement "
+                        "earns mxu its place).  mxu on a non-TPU device "
                         "falls back to vpu with one warning.  Env: "
                         "TEKU_TPU_MONT_MUL")
     n.add_argument("--msm-path", default=None,

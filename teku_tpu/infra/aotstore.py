@@ -29,6 +29,7 @@ import logging
 import os
 import pickle
 import threading
+import zlib
 from typing import Callable, Optional, Sequence, Tuple
 
 from .env import env_int, env_str
@@ -43,7 +44,19 @@ _OFF_VALUES = ("off", "0", "none", "disabled")
 
 # bump when the blob layout changes: old-format entries must read as
 # a mismatch (one WARN + fresh compile), never unpickle garbage
-FORMAT = 1
+# (2: entries carry the ids of the devices they execute on and are
+# zlib-compressed — a serialized TPU executable is ~2x its code size and
+# compresses ~5x, and the staged programs run to ~100 MB of code each)
+FORMAT = 2
+
+
+def _encode(entry: dict) -> bytes:
+    return zlib.compress(
+        pickle.dumps(entry, protocol=pickle.HIGHEST_PROTOCOL), 1)
+
+
+def _decode(blob: bytes) -> dict:
+    return pickle.loads(zlib.decompress(blob))
 
 _lock = threading.Lock()
 _counts = {"load": 0, "miss": 0, "save": 0, "error": 0}
@@ -129,7 +142,7 @@ def identity() -> dict:
     dev = jax.devices()[0]
     return {"format": FORMAT, "jax": jax.__version__,
             "platform": dev.platform,
-            "device_kind": getattr(dev, "device_kind", ""),
+            "device_kind": dev.device_kind,
             "device_count": jax.device_count(),
             "fingerprint": fingerprint()}
 
@@ -202,10 +215,16 @@ def save(kernel: str, sig: tuple, compiled) -> Optional[str]:
     try:
         payload, in_tree, out_tree = serialize_executable.serialize(
             compiled)
-        blob = pickle.dumps(
+        # the devices the program executes on, in assignment order: a
+        # load must hand them back, or jax loads the executable over
+        # EVERY device of the backend and a one-device program then
+        # demands one shard per device
+        devices = [d.id for d in
+                   compiled.runtime_executable().local_devices()]
+        blob = _encode(
             {"identity": identity(), "kernel": kernel, "sig": sig,
-             "triple": (payload, in_tree, out_tree)},
-            protocol=pickle.HIGHEST_PROTOCOL)
+             "devices": devices,
+             "triple": (payload, in_tree, out_tree)})
         os.makedirs(base, exist_ok=True)
         path = _entry_path(base, kernel, sig)
         tmp = path + f".tmp.{os.getpid()}"
@@ -239,7 +258,7 @@ def load(kernel: str, sig: tuple) -> Optional[Callable]:
         return None
     from jax.experimental import serialize_executable
     try:
-        entry = pickle.loads(blob)
+        entry = _decode(blob)
         stored = entry["identity"]
     except Exception:
         _count("error")
@@ -258,9 +277,12 @@ def load(kernel: str, sig: tuple) -> Optional[Callable]:
                    "compiling fresh — re-run `cli precompile`")
         return None
     try:
+        import jax
+        by_id = {d.id: d for d in jax.devices()}
         payload, in_tree, out_tree = entry["triple"]
         fn = serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree)
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in entry["devices"]])
     except Exception as exc:
         _count("error")
         _warn_once("corrupt",
@@ -294,14 +316,17 @@ class AotDispatcher:
     executable when a valid entry exists, the wrapped jit otherwise —
     after which calls go straight to the resolved callable (the memo
     is the AOT twin of jax's in-memory jit cache).  A store
-    executable that rejects its arguments at call time (an aval
-    corner the signature missed) permanently falls back to the jit
+    executable that fails its FIRST call (an aval corner the signature
+    missed, a blob the runtime rejects) permanently falls back to the jit
     for that signature: correctness never depends on the store."""
 
     def __init__(self, kernel: str, jit_fn: Callable):
         self.kernel = kernel
         self._jit = jit_fn
         self._memo: dict = {}
+        # signatures whose store executable has not completed a call
+        # yet: its first call is the only one that may fall back
+        self._unproven: set = set()
         self._memo_lock = threading.Lock()
 
     def _resolve(self, sig: tuple, args: Sequence) -> Callable:
@@ -332,20 +357,31 @@ class AotDispatcher:
             fn = self._resolve(sig, args)
             with self._memo_lock:
                 fn = self._memo.setdefault(sig, fn)
-        if fn is self._jit:
+                if fn is not self._jit:
+                    self._unproven.add(sig)
+        if sig not in self._unproven:
             return fn(*args)
         try:
-            return fn(*args)
-        except TypeError:
-            # signature drift between the store entry and jit's aval
-            # canonicalization: serve from the jit from now on
+            # wait for the result: a failure of the loaded program may
+            # only surface when the device runs it
+            import jax
+            out = jax.block_until_ready(fn(*args))
+        except Exception as exc:
+            # a bad blob (devices, avals, a runtime that rejects the
+            # executable) costs the store, never the device: serve this
+            # signature from the jit from now on
             with self._memo_lock:
                 self._memo[sig] = self._jit
-            _warn_once(f"calldrift:{self.kernel}",
-                       f"aot store: {self.kernel} executable rejected "
-                       "its arguments; serving that signature from "
-                       "jit")
+                self._unproven.discard(sig)
+            _count("error")
+            _warn_once(f"callfail:{self.kernel}",
+                       f"aot store: {self.kernel} executable failed its "
+                       f"first call ({type(exc).__name__}: {exc}); "
+                       "serving that signature from jit")
             return self._jit(*args)
+        with self._memo_lock:
+            self._unproven.discard(sig)
+        return out
 
     def precompile(self, avals: Sequence) -> str:
         """Lower + compile this kernel at `avals` and persist it.
@@ -363,6 +399,7 @@ class AotDispatcher:
         re-checks the disk store (a fresh process in miniature)."""
         with self._memo_lock:
             self._memo.clear()
+            self._unproven.clear()
 
 
 _DISPATCHERS: dict = {}

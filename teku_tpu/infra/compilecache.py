@@ -7,10 +7,15 @@ shape, flags), so JAX's persistent compilation cache
 (``jax_compilation_cache_dir``) turns every boot after the first into
 cache LOADS — warm boots skip the compile entirely.
 
-``configure()`` is called by ``cli node`` / ``cli devnet`` and bench.py
-(ON BY DEFAULT; opt out with TEKU_TPU_XLA_CACHE_DIR=off).  It is safe
-in both import orders: before jax is imported it sets the JAX_* env
-vars the config reads at definition time; after, it updates jax.config
+``configure()`` is called by ``cli node`` / ``cli devnet``, bench.py,
+chip_smoke.py and the tests.  The directory is placed from OUTSIDE:
+where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and
+nothing here names a directory; where it is not, the cache goes to the
+fixed ``<checkout>/.jax_cache`` (the path is part of jax's cache key,
+so a directory that moves never hits).  jax's own
+``JAX_ENABLE_COMPILATION_CACHE=0`` switches the cache off.  Safe in
+both import orders: before jax is imported it sets the JAX_* env vars
+the config reads at definition time; after, it updates jax.config
 directly.  Nothing here initializes a backend — boot stays O(1).
 
 Observability: a jax.monitoring listener counts the runtime's
@@ -29,16 +34,15 @@ import sys
 import threading
 
 from . import clock
-from .env import env_float, env_str
+from .env import env_float
 from .metrics import GLOBAL_REGISTRY
 
 _LOG = logging.getLogger(__name__)
 
-ENV_DIR = "TEKU_TPU_XLA_CACHE_DIR"
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_MIN_COMPILE_S = "TEKU_TPU_XLA_CACHE_MIN_COMPILE_S"
 ENV_KERNEL_COMPILE_S = "TEKU_TPU_KERNEL_COMPILE_MIN_S"
 ENV_COMPILE_SPAN_MIN_S = "TEKU_TPU_COMPILE_SPAN_MIN_S"
-_OFF_VALUES = ("off", "0", "none", "disabled")
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -72,7 +76,7 @@ _M_BACKEND = GLOBAL_REGISTRY.labeled_counter(
 
 
 def default_dir() -> str:
-    """Repo-adjacent default (shared with the driver entry hooks)."""
+    """The fixed repo-adjacent default."""
     here = os.path.dirname(os.path.abspath(__file__))
     repo = os.path.dirname(os.path.dirname(here))
     return os.path.join(repo, ".jax_cache")
@@ -137,10 +141,7 @@ def ensure_instrumented() -> bool:
     with _lock:
         if _installed["listener"]:
             return True
-    try:
-        from jax import monitoring
-    except Exception:  # pragma: no cover - jax-less host tooling
-        return False
+    from jax import monitoring
     with _lock:
         if not _installed["listener"]:
             monitoring.register_event_listener(_on_event)
@@ -150,62 +151,34 @@ def ensure_instrumented() -> bool:
     return True
 
 
-def configure(cache_dir=None, min_compile_s=None, enabled=True):
-    """Wire the persistent cache; returns the cache dir or None (off).
+def configure(min_compile_s=None) -> str:
+    """Wire the persistent cache; returns the cache dir.
 
-    Precedence: explicit args > env (TEKU_TPU_XLA_CACHE_DIR /
-    TEKU_TPU_XLA_CACHE_MIN_COMPILE_S) > defaults (on, repo-adjacent
-    dir, 2 s minimum compile time so trivial programs don't churn the
-    disk).  TEKU_TPU_XLA_CACHE_DIR=off disables.
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` where that is set —
+    jax reads it itself, no directory is set in code — and the fixed
+    repo-adjacent ``.jax_cache`` otherwise.  `min_compile_s` (arg >
+    TEKU_TPU_XLA_CACHE_MIN_COMPILE_S > 1 s, the kernel-grade
+    threshold) keeps trivial programs from churning the disk.
     """
-    env_dir = env_str(ENV_DIR)
-    if cache_dir is None:
-        cache_dir = env_dir
-    if (not enabled or (cache_dir is not None
-                        and str(cache_dir).lower() in _OFF_VALUES)):
-        # the off switch must actually turn a previously-enabled cache
-        # OFF, not just stop reporting it
-        if "jax" in sys.modules:
-            import jax
-            try:
-                if getattr(jax.config, "jax_compilation_cache_dir",
-                           None):
-                    jax.config.update("jax_compilation_cache_dir", None)
-                    from jax._src import compilation_cache as _cc
-                    _cc.reset_cache()
-            except Exception:  # pragma: no cover - internal API drift
-                pass
-        else:
-            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-        _installed["dir"] = None
-        return None
-    if cache_dir is None:
-        cache_dir = default_dir()
+    placed = os.environ.get(JAX_ENV_DIR)
     if min_compile_s is None:
-        min_compile_s = env_float(ENV_MIN_COMPILE_S, 2.0, lo=0.0)
-    settings = {
-        "jax_compilation_cache_dir": str(cache_dir),
-        "jax_persistent_cache_min_compile_time_secs": min_compile_s,
-        "jax_persistent_cache_min_entry_size_bytes": -1,
-    }
+        # default = the kernel-grade threshold: whatever a boot counts
+        # as a kernel-grade compile is persisted, so a warm boot never
+        # repeats one (a 1-2 s program used to fall between the two)
+        min_compile_s = env_float(ENV_MIN_COMPILE_S, 1.0, lo=0.0)
     if "jax" in sys.modules:
         import jax
-        dir_changed = (
-            getattr(jax.config, "jax_compilation_cache_dir", None)
-            != str(cache_dir))
-        for key, value in settings.items():
-            try:
-                jax.config.update(key, value)
-            except Exception:  # pragma: no cover - old/new jax drift
-                _LOG.warning("compile cache: jax has no config %s", key)
-        if dir_changed:
+        if not placed and (jax.config.jax_compilation_cache_dir
+                           != default_dir()):
+            jax.config.update("jax_compilation_cache_dir", default_dir())
             # jax binds its cache OBJECT to the dir at first use; a
             # config update alone leaves reads/writes on the old dir
-            try:
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:  # pragma: no cover - internal API drift
-                pass
+            from jax.experimental.compilation_cache import (
+                compilation_cache)
+            compilation_cache.reset_cache()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_compile_s)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         ensure_instrumented()
     else:
         # jax not imported yet (cli boot path): the env vars are read
@@ -213,17 +186,18 @@ def configure(cache_dir=None, min_compile_s=None, enabled=True):
         # cache without paying the jax import here.  The listener is
         # installed by whichever component imports jax first and asks
         # for stats (provider module import / bench / supervisor).
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
+        if not placed:
+            os.environ[JAX_ENV_DIR] = default_dir()
         os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = \
             str(min_compile_s)
         os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
-    _installed["dir"] = str(cache_dir)
-    _LOG.info("persistent XLA compile cache: %s", cache_dir)
-    return str(cache_dir)
+    _installed["dir"] = placed or default_dir()
+    _LOG.info("persistent XLA compile cache: %s", _installed["dir"])
+    return _installed["dir"]
 
 
 def cache_dir():
-    """The configured dir (None when off/unconfigured)."""
+    """The configured dir (None before configure())."""
     return _installed["dir"]
 
 

@@ -138,7 +138,7 @@ class WrongResult(Fault):
 
 class SlowRamp(Hang):
     """Slow-ramp init: the site takes `seconds` before succeeding —
-    models the ~25-minute TPU plugin bring-up at test timescales.
+    models a minutes-long device bring-up at test timescales.
     Mechanically a Hang; the distinct name marks *bring-up* slowness
     (site succeeds afterwards) vs a *dispatch* wedge."""
 

@@ -30,10 +30,13 @@ Cofactor clearing is Budroni-Pintore via the psi endomorphism, matching
 the oracle's production path (crypto/bls/hash_to_curve.py:152-158).
 """
 
+import functools
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..crypto.bls import fields as F
 from ..crypto.bls import hash_to_curve as OH
@@ -113,19 +116,28 @@ def map_to_curve_sswu_proj(u):
     tv3 = T.fq2_compress(T.fq2_mul(T.fq2_compress(T.fq2_sqr(tv)), tv))
     gval2 = T.fq2_compress(T.fq2_mul(tv3, gval))
 
-    found1 = jnp.zeros(tv2_zero.shape, dtype=bool)
-    y1 = cand
-    found2 = jnp.zeros(tv2_zero.shape, dtype=bool)
-    y2 = cand2
-    for root in (None, "R1", "R2", "R3"):
-        t1 = cand if root is None else T.fq2_mul(_c(root, u), cand)
-        m1 = T.fq2_eq(T.fq2_sqr(t1), gval) & ~found1
-        y1 = T.fq2_select(m1, t1, y1)
-        found1 |= m1
-        t2 = cand2 if root is None else T.fq2_mul(_c(root, u), cand2)
-        m2 = T.fq2_eq(T.fq2_sqr(t2), gval2) & ~found2
-        y2 = T.fq2_select(m2, t2, y2)
-        found2 |= m2
+    # the four root-of-unity multiples of both candidates, their squares
+    # and the eight match tests, each as ONE wide call (lane axis -2:
+    # [cand, R1 cand, R2 cand, R3 cand, cand2, R1 cand2, ...])
+    roots = T._fq2s([_c(r, u) for r in ("R1", "R2", "R3")] * 2)
+    scaled = T._fq2u(T.fq2_mul(roots, T._fq2s([cand] * 3 + [cand2] * 3)))
+    tries = [cand] + scaled[:3] + [cand2] + scaled[3:]
+    d = T.fq2_sub(T.fq2_sqr(T._fq2s(tries)),
+                  T._fq2s([gval] * 4 + [gval2] * 4))
+    match = jnp.all(fp.canonical(jnp.stack(d, axis=-2)) == 0,
+                    axis=(-2, -1))                       # (..., 8)
+
+    def first_match(tries, match):
+        found = jnp.zeros(tv2_zero.shape, dtype=bool)
+        y = tries[0]
+        for i, t in enumerate(tries):
+            m = match[..., i] & ~found
+            y = T.fq2_select(m, t, y)
+            found |= m
+        return found, y
+
+    found1, y1 = first_match(tries[:4], match[..., :4])
+    _, y2 = first_match(tries[4:], match[..., 4:])
 
     xn = T.fq2_select(found1, x1n, T.fq2_compress(T.fq2_mul(tv, x1n)))
     yp = T.fq2_select(found1, y1, y2)
@@ -144,24 +156,39 @@ def iso_map_proj(xn, xd, yp):
     xd_pows = [None, xd, xd2, xd3]
     xn_pows = [None, xn, xn2, xn3]
 
-    def homog(coeffs):
-        """sum_i k_i xn^i xd^(d-i) for ascending coeffs of degree d."""
-        d = len(coeffs) - 1
-        acc = None
-        for i, k in enumerate(coeffs):
-            kc = T._bcast2(T.fq2_const(k), xn)
-            term = kc
-            if i:
-                term = T.fq2_mul(term, xn_pows[i])
-            if d - i:
-                term = T.fq2_mul(T.fq2_compress(term), xd_pows[d - i])
-            acc = term if acc is None else T.fq2_add(acc, term)
-        return T.fq2_compress(acc)
+    # every term k_i xn^i xd^(d-i) of all four polynomials takes the
+    # same two multiplies (by xn^i, then by xd^(d-i)); each round is ONE
+    # wide call over all terms instead of one call per term
+    polys = (ISO3_X_NUM, ISO3_X_DEN, ISO3_Y_NUM, ISO3_Y_DEN)
+    terms = [(k, i, len(co) - 1 - i)
+             for co in polys for i, k in enumerate(co)]
+    vals = [T._bcast2(T.fq2_const(k), xn) for k, _, _ in terms]
 
-    XN = homog(ISO3_X_NUM)                       # deg 3
-    XD = T.fq2_mul(xd, homog(ISO3_X_DEN))        # deg 2 -> * xd
-    YN = T.fq2_mul(yp, homog(ISO3_Y_NUM))        # y factor: yp/xd^3
-    YD = T.fq2_mul(xd3, homog(ISO3_Y_DEN))       # matching xd^3
+    def on_lanes(op, vals, lanes, *rest):
+        """vals with op applied to the `lanes` subset as one wide call."""
+        done = T._fq2u(op(T._fq2s([vals[j] for j in lanes]), *rest))
+        vals = list(vals)
+        for j, v in zip(lanes, done):
+            vals[j] = v
+        return vals
+
+    by_xn = [j for j, (_, i, _) in enumerate(terms) if i]
+    by_xd = [j for j, (_, _, e) in enumerate(terms) if e]
+    vals = on_lanes(T.fq2_mul, vals, by_xn,
+                    T._fq2s([xn_pows[terms[j][1]] for j in by_xn]))
+    vals = on_lanes(T.fq2_compress, vals, by_xd)
+    vals = on_lanes(T.fq2_mul, vals, by_xd,
+                    T._fq2s([xd_pows[terms[j][2]] for j in by_xd]))
+    sums, pos = [], 0
+    for co in polys:
+        sums.append(functools.reduce(T.fq2_add, vals[pos:pos + len(co)]))
+        pos += len(co)
+    x_num, x_den, y_num, y_den = T._fq2u(T.fq2_compress(T._fq2s(sums)))
+
+    XN = x_num                                   # deg 3
+    XD = T.fq2_mul(xd, x_den)                    # deg 2 -> * xd
+    YN = T.fq2_mul(yp, y_num)                    # y factor: yp/xd^3
+    YD = T.fq2_mul(xd3, y_den)                   # matching xd^3
     return XN, T.fq2_compress(XD), T.fq2_compress(YN), T.fq2_compress(YD)
 
 
@@ -199,8 +226,16 @@ def clear_cofactor(p):
         return PT.point_neg(PT.G2_KIT,
                             PT.scalar_mul_static(PT.G2_KIT, X_ABS, q))
 
-    a = PT.point_add(PT.G2_KIT, mul_x(p), PT.point_neg(PT.G2_KIT, p))
-    res = PT.point_add(PT.G2_KIT, mul_x(a), PT.point_neg(PT.G2_KIT, p))
+    neg_p = PT.point_neg(PT.G2_KIT, p)
+
+    # a = [x]P - P, then res = [x]a - P: the same step twice, scanned so
+    # the graph holds ONE |x|-ladder
+    def step(q, _):
+        q = PT.point_add(PT.G2_KIT, mul_x(q), neg_p)
+        return q, q
+
+    _, both = lax.scan(step, p, None, length=2)
+    a, res = T.tree_unstack(both, 2)
     res = PT.point_add(PT.G2_KIT, res, PT.g2_psi(a))
     dbl = PT.point_double(PT.G2_KIT, p)
     res = PT.point_add(PT.G2_KIT, res, PT.g2_psi(PT.g2_psi(dbl)))

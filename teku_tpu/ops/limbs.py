@@ -34,8 +34,7 @@ dims; batching is plain array broadcasting.
 TWO mont_mul engines live behind one contract: the VPU pad-and-sum
 path below, and the MXU int8 digit-split matmul path (ops/mxu.py) —
 `mont_mul`/`mont_sqr` dispatch at trace time on the process-global
-path config (`--mont-path` / TEKU_TPU_MONT_MUL; auto = mxu only on a
-TPU dispatch device).  Both emit one compressed unit in (-P, 2P)
+path config (`--mont-path` / TEKU_TPU_MONT_MUL; auto = vpu).  Both emit one compressed unit in (-P, 2P)
 through the SAME `_mont_reduce` scan, so outputs are bit-identical.
 
 Layer validation: tests/test_ops_limbs.py checks every op against the
@@ -199,26 +198,33 @@ def _mont_reduce(t):
     return compress(t[..., :L])
 
 
-def mont_mul_vpu(a, b):
-    """Montgomery product a*b*R^-1 (one unit out, value in (-P, 2P)).
+def _product_columns(a, b):
+    """The 2L schoolbook product columns t[k] = sum_{i+j=k} a[i]*b[j].
 
-    Schoolbook column products built by pad-and-sum — no scatters, no
-    carries; XLA fuses the static pads into one elementwise reduction.
-    """
-    t = sum(_pad_last(a[..., i:i + 1] * b, i, L - i) for i in range(L))
-    return _mont_reduce(t)
+    One outer product, skewed so row i sits i columns to the right (the
+    zero-padded rows are flattened and re-cut one column narrower, which
+    shifts each row by its index), then one reduction over the rows: a
+    handful of ops per call site where pad-and-sum over L rows cost the
+    TPU compiler most of a mont_mul's compile time.  Exact int64 sums,
+    so the columns are bit-identical to any other summation order."""
+    a, b = jnp.broadcast_arrays(a, b)
+    outer = a[..., :, None] * b[..., None, :]             # (..., L, L)
+    rows = _pad_last(outer, 0, L)                         # (..., L, 2L)
+    flat = rows.reshape(rows.shape[:-2] + (2 * L * L,))
+    skew = flat[..., :L * (2 * L - 1)].reshape(
+        flat.shape[:-1] + (L, 2 * L - 1))
+    return _pad_last(skew.sum(axis=-2), 0, 1)
+
+
+def mont_mul_vpu(a, b):
+    """Montgomery product a*b*R^-1 (one unit out, value in (-P, 2P)):
+    schoolbook product columns, no scatters, no carries."""
+    return _mont_reduce(_product_columns(a, b))
 
 
 def mont_sqr_vpu(a):
-    """Montgomery squaring: symmetric cross products computed once and
-    doubled (~half the limb multiplies of mont_mul)."""
-    rows = []
-    for i in range(L):
-        diag = a[..., i:i + 1] * a[..., i:i + 1]
-        cross = 2 * a[..., i:i + 1] * a[..., i + 1:]
-        seg = jnp.concatenate([diag, cross], axis=-1)   # columns 2i..i+L-1
-        rows.append(_pad_last(seg, 2 * i, L - i))
-    return _mont_reduce(sum(rows))
+    """Montgomery squaring (the product columns of a with itself)."""
+    return _mont_reduce(_product_columns(a, a))
 
 
 # MXU path: same operand contract, same _mont_reduce, product columns
@@ -332,8 +338,7 @@ def pow_static(a, e: int, window: int = POW_WINDOW):
     _, table = lax.scan(build, one, None, length=1 << window)
 
     def body(acc, d):
-        for _ in range(window):
-            acc = mont_sqr(acc)
+        acc = lax.fori_loop(0, window, lambda _, x: mont_sqr(x), acc)
         acc = mont_mul(acc, jnp.take(table, d, axis=0))
         return acc, None
 
@@ -351,13 +356,13 @@ def inv(a):
 
 def inv_many(a):
     """Batched field inverse: ONE Fermat exponentiation for the whole
-    batch via Montgomery's trick, parallelized with prefix/suffix
-    product scans.
+    batch via Montgomery's trick, parallelized with a prefix/suffix
+    product scan.
 
     a: (..., L) Montgomery units, any batch shape (flattened internally).
-    Cost: one single-element a^(P-2) scan plus ~6 mont_muls per element
-    (two log-depth associative scans + the recombine), versus one full
-    380-bit Fermat scan per element for `inv` — the dominant
+    Cost: one single-element a^(P-2) scan plus ~2*log2(M) mont_muls per
+    element (one rolled log-depth scan + the recombine), versus one
+    full 380-bit Fermat scan per element for `inv` — the dominant
     compile-time and runtime win of the verification kernel.
 
     inv_many(0) ≡ 0 per-lane (zero lanes are masked out of the product
@@ -372,12 +377,25 @@ def inv_many(a):
     zero = is_zero(flat)                                  # (M,)
     one = jnp.broadcast_to(jnp.asarray(ONE_MONT), flat.shape)
     safe = jnp.where(zero[:, None], one, flat)
-    pre = lax.associative_scan(mont_mul, safe, axis=0)    # prefix products
-    suf = lax.associative_scan(mont_mul, safe, axis=0, reverse=True)
-    tinv = inv(pre[-1])                                   # ONE Fermat
+    # prefix AND suffix products in ONE rolled Hillis-Steele scan: row
+    # 0 runs forward, row 1 over the reversed batch; round k multiplies
+    # each lane by the lane 2^k before it (ones shifted in).  One
+    # mont_mul body in the graph instead of the ~4*log2(M) inlined
+    # copies of two associative scans.
+    both = jnp.stack([safe, safe[::-1]])                  # (2, M, L)
+    ones = jnp.broadcast_to(jnp.asarray(ONE_MONT), both.shape)
+
+    def sweep(k, x):
+        shifted = lax.dynamic_slice_in_dim(
+            jnp.concatenate([ones, x], axis=1), m - (1 << k), m, axis=1)
+        return mont_mul(x, shifted)
+
+    both = lax.fori_loop(0, (m - 1).bit_length(), sweep, both)
+    pre, suf = both[0], both[1][::-1]
+    tinv = inv(pre[-1:])                                  # ONE Fermat
     left = jnp.concatenate([one[:1], pre[:-1]], axis=0)   # prod before i
     right = jnp.concatenate([suf[1:], one[:1]], axis=0)   # prod after i
-    out = mont_mul(mont_mul(left, right), tinv[None])
+    out = mont_mul(mont_mul(left, right), tinv)
     out = jnp.where(zero[:, None], 0, out)
     return out.reshape(shape)
 

@@ -405,8 +405,8 @@ def window_combine(kit, wsums, window: int):
     rest = _tree(lambda a: a[1:], ws)
 
     def step(acc, wpt):
-        for _ in range(window):
-            acc = PT.point_double(kit, acc)
+        acc = lax.fori_loop(
+            0, window, lambda _, a: PT.point_double(kit, a), acc)
         return PT.point_add(kit, acc, wpt), None
 
     acc, _ = lax.scan(step, acc, rest)

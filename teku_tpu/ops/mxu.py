@@ -40,12 +40,14 @@ far inside `_mont_reduce`'s 2^62 input contract.
 Path selection is process-global config (CLI `--mont-path` / env
 `TEKU_TPU_MONT_MUL` / `set_path()`), resolved at TRACE time:
 
-- ``vpu``  — the elementwise pad-and-sum path (default on CPU);
+- ``vpu``  — the elementwise int64 path (the default);
 - ``mxu``  — the digit-split matmul path; on a non-TPU dispatch device
   this falls back to vpu with ONE warning (the int8 matmul shape is a
   pessimization on CPU/VPU backends — never fail, never be slow
   silently);
-- ``auto`` — mxu exactly when the dispatch device is a TPU;
+- ``auto`` — vpu on every device until a chip measurement earns mxu
+  its place (the TPU compile of the mxu programs costs 1.7-2.8x, see
+  resolve());
 - ``mxu-force`` — mxu regardless of device (tests and A/B microbench
   need the kernel ON the CPU oracle box).
 
@@ -114,11 +116,8 @@ def get_path() -> str:
 
 
 def _device_is_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def resolve() -> str:
@@ -132,20 +131,21 @@ def resolve() -> str:
         return "vpu"
     if configured == "mxu-force":
         return "mxu"
-    is_tpu = _device_is_tpu()
     if configured == "auto":
-        return "mxu" if is_tpu else "vpu"
+        # vpu on every device, a TPU included, until a chip measurement
+        # earns mxu its place: the TPU compiler takes 1.7-2.8x as long
+        # over the mxu path's programs and emits 2.3-2.5x the code
+        # (PERF.md "On the chip"), which alone would put a cold boot
+        # outside any patience, and nothing has measured it faster
+        return "vpu"
     # configured == "mxu"
-    if is_tpu:
+    if _device_is_tpu():
         return "mxu"
     with _lock:
         if not _warned_fallback[0]:
             _warned_fallback[0] = True
-            try:
-                import jax
-                device = jax.default_backend()
-            except Exception:  # pragma: no cover
-                device = "unknown"
+            import jax
+            device = jax.default_backend()
             _LOG.warning(
                 "--mont-path mxu requested but the dispatch device is "
                 "%r (not a TPU); falling back to the vpu path (use "

@@ -8,11 +8,12 @@ finalverify, reference: infrastructure/bls/src/main/java/tech/pegasys/
 teku/bls/impl/blst/BlstBLS12381.java:124-189).
 
 Compile/runtime structure: the BLS parameter |z| = 0xD201000000010000 has
-Hamming weight 6, so the 63 Miller iterations are grouped into runs —
-each maximal run of doubling-only iterations is one lax.scan (body traced
-once), and the 5 iterations that also add are unrolled.  The compiled
-graph is O(#runs), the runtime does no wasted add-steps, and everything
-broadcasts over leading batch dims.
+Hamming weight 6.  The 63 Miller iterations are ONE lax.scan over the
+static bit vector; the 5 iterations that also add take the add-step
+through a lax.cond on the scanned bit, so the compiled graph holds one
+doubling body and one add body (the TPU compiler pays per mont_mul call
+site, PERF.md "On the chip"), the runtime does no wasted add-steps, and
+everything broadcasts over leading batch dims.
 
 Final exponentiation: easy part then the Hayashida-Hayasaka-Teruya
 x-chain hard part, computing f^(3d) (cofactor 3 preserves is_one /
@@ -20,6 +21,8 @@ equality / bilinearity — see the oracle's derivation and import-time
 assert in crypto/bls/pairing.py:220-229); cyclotomic powers use
 Granger-Scott squaring.
 """
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -29,24 +32,8 @@ from ..crypto.bls.constants import X_ABS
 from . import limbs as fp
 from . import towers as T
 
-_X_BITS = bin(X_ABS)[3:]   # bits below the MSB
-
-
-def _parse_runs(bits: str):
-    """[(n_double_only, has_trailing_add_iter), ...] covering all bits."""
-    runs = []
-    n = 0
-    for c in bits:
-        if c == "0":
-            n += 1
-        else:
-            runs.append((n, True))
-            n = 0
-    if n:
-        runs.append((n, False))
-    return runs
-
-_RUNS = _parse_runs(_X_BITS)
+# bits of |z| below the MSB, MSB first: the scanned add/no-add schedule
+_X_BITS = np.array([c == "1" for c in bin(X_ABS)[3:]], dtype=np.bool_)
 
 
 # --------------------------------------------------------------------------
@@ -169,20 +156,19 @@ def miller_loop(p, q, mask=None):
     t = (q[0], q[1], T._bcast2(T.FQ2_ONE_NP, q[0]))
     f = T.fq12_ones(px.shape[:-1])
 
-    def dbl_iter(state, _):
+    def add_iter(state):
+        f, t = state
+        t, line = _add_step(t, q, px_neg, py)
+        return _mul_by_line(f, line), t
+
+    def iteration(state, bit):
         f, t = state
         f = T.fq12_sqr(f)
         t, line = _dbl_step(t, px_neg, py)
         f = _mul_by_line(f, line)
-        return (f, t), None
+        return lax.cond(bit, add_iter, lambda st: st, (f, t)), None
 
-    for n_dbl, has_add in _RUNS:
-        if n_dbl:
-            (f, t), _ = lax.scan(dbl_iter, (f, t), None, length=n_dbl)
-        if has_add:
-            (f, t), _ = dbl_iter((f, t), None)
-            t, line = _add_step(t, q, px_neg, py)
-            f = _mul_by_line(f, line)
+    (f, t), _ = lax.scan(iteration, (f, t), jnp.asarray(_X_BITS))
 
     f = T.fq12_conj(f)   # negative BLS parameter
     if mask is not None:
@@ -193,22 +179,7 @@ def miller_loop(p, q, mask=None):
 def batch_product(f):
     """Product of Fq12 values over the leading batch axis (axis 0) via
     log2-depth pairwise reduction."""
-    n = jax.tree_util.tree_leaves(f)[0].shape[0]
-    while n > 1:
-        half = n // 2
-        odd = n - 2 * half
-        a = jax.tree_util.tree_map(lambda x: x[:half], f)
-        b = jax.tree_util.tree_map(lambda x: x[half:2 * half], f)
-        prod = T.fq12_mul(a, b)
-        if odd:
-            tail = jax.tree_util.tree_map(lambda x: x[2 * half:], f)
-            f = jax.tree_util.tree_map(
-                lambda x, y: jnp.concatenate([x, y], axis=0), prod, tail)
-            n = half + 1
-        else:
-            f = prod
-            n = half
-    return jax.tree_util.tree_map(lambda x: x[0], f)
+    return T.tree_fold_pairs(T.fq12_mul, f)
 
 
 # --------------------------------------------------------------------------
@@ -216,18 +187,14 @@ def batch_product(f):
 # --------------------------------------------------------------------------
 
 def _cyclo_pow_abs_x(f):
-    """f^|z| for cyclotomic f: Granger-Scott squarings over the runs."""
-    result = f
+    """f^|z| for cyclotomic f: one scan of Granger-Scott squarings over
+    the bits of |z|, multiplying by f (lax.cond) at the one-bits."""
+    def iteration(r, bit):
+        r = T.fq12_cyclo_sqr(r)
+        return lax.cond(bit, lambda x: T.fq12_mul(x, f), lambda x: x,
+                        r), None
 
-    def sqr_iter(r, _):
-        return T.fq12_cyclo_sqr(r), None
-
-    for n_dbl, has_add in _RUNS:
-        total = n_dbl + (1 if has_add else 0)
-        if total:
-            result, _ = lax.scan(sqr_iter, result, None, length=total)
-        if has_add:
-            result = T.fq12_mul(result, f)
+    result, _ = lax.scan(iteration, f, jnp.asarray(_X_BITS))
     return result
 
 
@@ -236,16 +203,44 @@ def _pow_z(f):
     return T.fq12_conj(_cyclo_pow_abs_x(f))
 
 
+# The HHT hard part is five steps of one shape, cur <- cur^z * other:
+#   a = g^z * conj(g);  a = a^z * conj(a);  b = a^z * frob(a);
+#   t = b^z * 1;        c = t^z * frob^2(b)
+# so it runs as ONE scan over this schedule (the graph holds a single
+# x-power chain).  Columns: Frobenius power of `other`, conjugate it,
+# take it from the saved b instead of cur, it is one, save result as b.
+_HARD_STEPS = np.array([[0, 1, 0, 0, 0],
+                        [0, 1, 0, 0, 0],
+                        [1, 0, 0, 0, 1],
+                        [0, 0, 0, 1, 0],
+                        [2, 0, 1, 0, 0]], dtype=np.int32)
+
+
+def _where12(flag, a, b):
+    return jax.tree_util.tree_map(lambda x, y: jnp.where(flag, x, y),
+                                  a, b)
+
+
 def final_exponentiation(f):
     """f^(3*(p^12-1)/r): easy part, then the HHT x-chain hard part
     (identical chain to the oracle: crypto/bls/pairing.py:247-259)."""
     g = T.fq12_mul(T.fq12_conj(f), T.fq12_inv(f))
     g = T.fq12_mul(T.fq12_frobenius(g, 2), g)
-    a = T.fq12_mul(_pow_z(g), T.fq12_conj(g))            # g^(z-1)
-    a = T.fq12_mul(_pow_z(a), T.fq12_conj(a))            # g^((z-1)^2)
-    b = T.fq12_mul(_pow_z(a), T.fq12_frobenius(a, 1))    # a^(z+p)
-    c = T.fq12_mul(T.fq12_mul(_pow_z(_pow_z(b)), T.fq12_frobenius(b, 2)),
-                   T.fq12_conj(b))                       # b^(z^2+p^2-1)
+    one = T.fq12_ones(g[0][0][0].shape[:-1])
+
+    def step(carry, sched):
+        cur, b = carry
+        n_frob, conj, from_b, is_one, save_b = sched
+        other = lax.fori_loop(0, n_frob,
+                              lambda _, x: T.fq12_frobenius(x, 1),
+                              _where12(from_b != 0, b, cur))
+        other = _where12(conj != 0, T.fq12_conj(other), other)
+        other = _where12(is_one != 0, one, other)
+        cur = T.fq12_mul(_pow_z(cur), other)
+        return (cur, _where12(save_b != 0, cur, b)), None
+
+    (c, b), _ = lax.scan(step, (g, g), jnp.asarray(_HARD_STEPS))
+    c = T.fq12_mul(c, T.fq12_conj(b))                    # b^(z^2+p^2-1)
     return T.fq12_mul(c, T.fq12_mul(T.fq12_sqr(g), g))   # * g^3
 
 

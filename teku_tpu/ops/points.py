@@ -318,8 +318,8 @@ def scalar_mul_bits(k: FieldKit, bits, p, window: int = SCALAR_WINDOW):
         .sum(axis=-1), -1, 0)
 
     def body(acc, d):
-        for _ in range(window):
-            acc = point_double(k, acc)
+        acc = lax.fori_loop(0, window,
+                            lambda _, a: point_double(k, a), acc)
         acc = point_add(k, acc, gather(d))
         return acc, None
 
@@ -331,33 +331,19 @@ def scalar_mul_bits(k: FieldKit, bits, p, window: int = SCALAR_WINDOW):
 def scalar_mul_static(k: FieldKit, e: int, p):
     """[e]P for a static non-negative exponent.
 
-    The bit pattern is static, so zero bits pay ONLY a doubling: maximal
-    runs of doubling-only iterations run as one lax.scan each and the
-    point_adds are unrolled at the (few) one-bits — for the BLS parameter
-    (Hamming weight 6) this drops ~58 of 64 adds versus a naive
-    double-and-always-add ladder."""
+    The bit pattern is static, so zero bits pay ONLY a doubling: one
+    lax.scan over the bits doubles every iteration and takes the
+    point_add through a lax.cond at the (few) one-bits — for the BLS
+    parameter (Hamming weight 6) this drops ~58 of 64 adds versus a
+    naive double-and-always-add ladder, and the graph holds one double
+    body and one add body whatever the exponent."""
     assert e >= 0
     if e == 0:
         return infinity_like(k, p[0])
-    # acc starts at P (top bit), then per remaining bit: double (+ add)
-    bits = bin(e)[3:]
-    runs = []        # [(n_doubles, add_after)]
-    n = 0
-    for c in bits:
-        n += 1
-        if c == "1":
-            runs.append((n, True))
-            n = 0
-    if n:
-        runs.append((n, False))
-
-    if len(runs) > 16:
-        # DENSE exponent: the runs decomposition would inline ~one
-        # point_add per one-bit, building a graph big enough to crash
-        # XLA's compiler (observed: CPU backend segfault, TPU compile
-        # blowup).  One masked-add scan keeps the program tiny; the
-        # static-unroll fast path stays for the sparse exponents it was
-        # built for (the BLS parameter, Hamming weight 6).
+    bits = bin(e)[3:]    # acc starts at P (top bit)
+    if bits.count("1") > 16:
+        # DENSE exponent: the windowed ladder does fewer adds than one
+        # per one-bit
         nbits = len(bits) + 1
         bit_arr = jnp.asarray([int(c) for c in bin(e)[2:]],
                               dtype=jnp.int64)
@@ -365,14 +351,13 @@ def scalar_mul_static(k: FieldKit, e: int, p):
         bit_arr = jnp.broadcast_to(bit_arr, lane_shape + (nbits,))
         return scalar_mul_bits(k, bit_arr, p)
 
-    def dbl_body(acc, _):
-        return point_double(k, acc), None
+    def iteration(acc, bit):
+        acc = point_double(k, acc)
+        return lax.cond(bit, lambda a: point_add(k, a, p), lambda a: a,
+                        acc), None
 
-    acc = p
-    for n_dbl, has_add in runs:
-        acc, _ = lax.scan(dbl_body, acc, None, length=n_dbl)
-        if has_add:
-            acc = point_add(k, acc, p)
+    acc, _ = lax.scan(iteration, p,
+                      jnp.asarray([c == "1" for c in bits], dtype=bool))
     return acc
 
 
@@ -380,22 +365,7 @@ def point_batch_sum(k: FieldKit, p):
     """Sum points over the leading batch axis via log-depth pairwise
     adds.  (Lives here so the MSM kernels (ops/msm.py) and the verify
     pipeline (ops/verify.py) share one reduction.)"""
-    n = jax.tree_util.tree_leaves(p)[0].shape[0]
-    while n > 1:
-        half = n // 2
-        odd = n - 2 * half
-        a = jax.tree_util.tree_map(lambda x: x[:half], p)
-        b = jax.tree_util.tree_map(lambda x: x[half:2 * half], p)
-        s = point_add(k, a, b)
-        if odd:
-            tail = jax.tree_util.tree_map(lambda x: x[2 * half:], p)
-            p = jax.tree_util.tree_map(
-                lambda x, y: jnp.concatenate([x, y], axis=0), s, tail)
-            n = half + 1
-        else:
-            p = s
-            n = half
-    return jax.tree_util.tree_map(lambda x: x[0], p)
+    return T.tree_fold_pairs(lambda a, b: point_add(k, a, b), p)
 
 
 def scalar_from_uint64(vals):

@@ -98,6 +98,48 @@ def tree_unstack(t, n):
     return [jax.tree_util.tree_map(lambda x: x[i], t) for i in range(n)]
 
 
+def tree_fold_pairs(combine, t):
+    """Reduce a pytree over its leading axis with log-depth pairwise
+    `combine` rounds: round k folds lanes [0, h) with lanes [h, 2h),
+    h halving each round (an odd tail lane rides along unfolded).
+
+    A pow-2 width >= 4 runs its rounds as ONE fori_loop at the fixed
+    width n/2 — the graph holds a single `combine` body instead of
+    log2(n) inlined copies (the TPU compiler pays per call site) at the
+    price of computing dead lanes in the later rounds.  Live lanes see
+    exactly the pairing of the unrolled form, so results are
+    bit-identical."""
+    n = jax.tree_util.tree_leaves(t)[0].shape[0]
+    if n >= 4 and n & (n - 1) == 0:
+        half = n // 2
+
+        def fold(k, t):
+            h = half >> k             # live pairs this round
+            a = jax.tree_util.tree_map(lambda x: x[:half], t)
+            b = jax.tree_util.tree_map(
+                lambda x: lax.dynamic_slice_in_dim(x, h, half, axis=0), t)
+            s = combine(a, b)
+            return jax.tree_util.tree_map(
+                lambda x, y: jnp.concatenate([y, x[half:]], axis=0), t, s)
+
+        t = lax.fori_loop(0, n.bit_length() - 1, fold, t)
+        return jax.tree_util.tree_map(lambda x: x[0], t)
+    while n > 1:
+        half = n // 2
+        a = jax.tree_util.tree_map(lambda x: x[:half], t)
+        b = jax.tree_util.tree_map(lambda x: x[half:2 * half], t)
+        s = combine(a, b)
+        if n - 2 * half:
+            t = jax.tree_util.tree_map(
+                lambda x, y: jnp.concatenate([x, y[2 * half:]], axis=0),
+                s, t)
+            n = half + 1
+        else:
+            t = s
+            n = half
+    return jax.tree_util.tree_map(lambda x: x[0], t)
+
+
 def fq2_compress(a):
     t = fp.compress(_stk(a[0], a[1]))
     return (t[..., 0, :], t[..., 1, :])
@@ -442,13 +484,17 @@ def fq12_inv(a):
 
 
 def fq12_frobenius(a, power: int = 1):
-    result = a
-    for _ in range(power % 12):
-        c0 = fq6_frobenius(result[0])
-        c1 = fq6_frobenius(result[1])
+    def once(_, x):
+        c0 = fq6_frobenius(x[0])
+        c1 = fq6_frobenius(x[1])
         c1 = fq6_mul_by_fq2(c1, _bcast2(FROB12_C1, c1[0]))
-        result = fq12_compress((c0, c1))
-    return result
+        return fq12_compress((c0, c1))
+
+    power %= 12
+    if power == 1:
+        return once(0, a)
+    # one Frobenius body in the graph whatever the power
+    return lax.fori_loop(0, power, once, a)
 
 
 def fq12_eq(a, b):

@@ -99,8 +99,8 @@ def _lane_work(pk_xs, pk_ys, pk_present, hm_aff, sig_x_plain, sig_large,
     has far fewer distinct messages than lanes, and h2c is the largest
     per-lane stage.
 
-    Returns (ml (N-lane Fq12 values), wsig (N weighted sig points),
-    lane_ok (N,))."""
+    Returns (ml (N-lane Fq12 values), wsig (the weighted sig points'
+    sum, (1,)-batched), lane_ok (N,))."""
     pk_jac, sig_jac, lane_ok, miller_mask = stage_prepare(
         pk_xs, pk_ys, pk_present, sig_x_plain, sig_large, sig_inf,
         lane_valid)
@@ -200,10 +200,17 @@ def stage_gather_hm(hm_uniq, lane_map):
 def stage_scalars(pk_jac, sig_jac, r_bits):
     """Random-multiplier scalar muls (Jacobian G1 out — the affine
     conversion happens per-lane in stage_lane_affine or per-UNIQUE in
-    stage_group, whichever path runs)."""
+    stage_group, whichever path runs).
+
+    `wsig` comes back as the SUM of the weighted signatures, a
+    (1,)-batched point: stage_finish only ever consumes that sum, and
+    the MSM path (stage_scalars_pippenger) hands it the same shape, so
+    ONE stage_finish program serves both scalars paths (its TPU compile
+    is the second longest of the set, PERF.md "On the chip")."""
     pk_r_jac = PT.scalar_mul_bits(PT.G1_KIT, r_bits, pk_jac)
-    wsig = PT.scalar_mul_bits(PT.G2_KIT, r_bits, sig_jac)
-    return pk_r_jac, wsig
+    wsig = point_batch_sum(
+        PT.G2_KIT, PT.scalar_mul_bits(PT.G2_KIT, r_bits, sig_jac))
+    return pk_r_jac, jax.tree_util.tree_map(lambda x: x[None], wsig)
 
 
 def stage_lane_affine(pk_r_jac):
@@ -426,7 +433,6 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp",
     exponentiation is replicated.  Returns (ok, lane_ok) with lane_ok
     in the PERMUTED lane order (callers un-permute on the host).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lane = P(axis)
@@ -469,8 +475,8 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp",
                 lane3 if pippenger else lane2,  # glv digits | r bits
                 lane)
     out_specs = (P(), lane)
-    return shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def verify_kernel_sharded(mesh, axis: str = "dp"):
@@ -493,7 +499,6 @@ def verify_kernel_sharded(mesh, axis: str = "dp"):
     sig_large, sig_inf, r_bits, lane_valid) with verify_kernel's result
     (to be called with GLOBAL batch arrays; N must divide the mesh size).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lane = P(axis)
@@ -521,8 +526,8 @@ def verify_kernel_sharded(mesh, axis: str = "dp"):
                 ((lane2, lane2), (lane2, lane2)),   # hm affine x, y
                 (lane2, lane2), lane, lane, lane2, lane)
     out_specs = (P(), lane)
-    return shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def aggregate_points_kernel(kit, xs, ys, present):

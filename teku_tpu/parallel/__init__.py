@@ -42,7 +42,7 @@ DEFAULT_AXIS = "dp"
 
 ENV_VAR = "TEKU_TPU_MESH"
 
-# the last-constructed mesh's self-description: MULTICHIP runs and the
+# the last-constructed mesh's self-description: multi-chip runs and the
 # readiness snapshot must say WHICH devices the mesh took (make_mesh
 # silently taking the first N was satellite-fixed in PR 10)
 _ACTIVE = {"devices": [], "n": 0, "axis": DEFAULT_AXIS}

@@ -19,7 +19,6 @@ Fast-tier gates for the compile-wall killer (`infra/aotstore.py`):
 
 import logging
 import os
-import pickle
 import subprocess
 import sys
 
@@ -151,10 +150,10 @@ def test_identity_mismatch_one_warn_and_fresh_compile(
     (entry_name,) = os.listdir(aot_dir)
     path = os.path.join(aot_dir, entry_name)
     with open(path, "rb") as fh:
-        entry = pickle.loads(fh.read())
+        entry = aotstore._decode(fh.read())
     entry["identity"]["jax"] = "0.0.0-from-another-era"
     with open(path, "wb") as fh:
-        fh.write(pickle.dumps(entry))
+        fh.write(aotstore._encode(entry))
 
     disp.reset_memo()
     aotstore._reset_warnings()
@@ -203,15 +202,23 @@ def test_shape_sig_same_for_avals_and_concrete():
     assert aotstore.shape_sig(concrete) == aotstore.shape_sig(avals)
 
 
-def test_call_drift_falls_back_to_jit_with_one_warn(aot_dir, caplog):
+@pytest.mark.parametrize("exc_type", [TypeError, RuntimeError])
+def test_first_call_failure_falls_back_to_jit_with_one_warn(
+        aot_dir, caplog, exc_type):
+    """ANY failure of a store executable on its first call (aval
+    drift, a blob the runtime rejects) costs the store, never the
+    caller: counted as an error, that signature served from the jit."""
     x = jnp.arange(4, dtype=jnp.int64)
-    disp = aotstore.wrap("test:drift", jax.jit(_oracle))
+    disp = aotstore.wrap(f"test:drift:{exc_type.__name__}",
+                         jax.jit(_oracle))
     sig = aotstore.shape_sig((x,))
 
     def rejects(*_a):
-        raise TypeError("executable/argument drift")
+        raise exc_type("executable/argument drift")
 
     disp._memo[sig] = rejects
+    disp._unproven.add(sig)
+    before = aotstore.stats()
     with caplog.at_level(logging.WARNING,
                          logger="teku_tpu.infra.aotstore"):
         out = np.asarray(disp(x))
@@ -219,7 +226,58 @@ def test_call_drift_falls_back_to_jit_with_one_warn(aot_dir, caplog):
         out, _oracle(np.arange(4, dtype=np.int64)))
     # the fallback is PERMANENT for that signature
     assert disp._memo[sig] is disp._jit
-    assert any("rejected" in r.message for r in caplog.records)
+    assert aotstore.delta(before)["errors"] == 1
+    assert any("failed its first call" in r.message
+               for r in caplog.records)
+
+
+def _reload(disp):
+    """A fresh process in miniature: drop the memo and jax's caches so
+    the next call can only be served by the disk store."""
+    disp.reset_memo()
+    jax.clear_caches()
+
+
+def test_single_device_program_loads_on_its_one_device(aot_dir):
+    """The installed jax loads a deserialized executable over EVERY
+    device of the backend unless told otherwise: a one-device program
+    saved by one process must come back as a one-device program on
+    this 8-device backend, and run."""
+    assert jax.device_count() == 8
+    x = jnp.arange(16, dtype=jnp.int64)
+    disp = aotstore.wrap("test:one_device", jax.jit(_oracle))
+    want = np.asarray(disp(x))
+    _reload(disp)
+    before = aotstore.stats()
+    got = disp(x)
+    moved = aotstore.delta(before)
+    assert moved["loads"] == 1 and moved["errors"] == 0
+    # the LOADED executable ran (a fallback would reset the memo to
+    # the jit), on the one device the jit runs on
+    assert disp._memo[aotstore.shape_sig((x,))] is not disp._jit
+    assert got.devices() == {jax.devices()[0]}
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_mesh_subset_program_loads_on_its_own_devices(aot_dir):
+    """A program sharded over a 4-device SUBSET of the backend loads
+    back onto exactly those devices, in mesh order."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    devices = jax.devices()[2:6]
+    sharding = NamedSharding(Mesh(np.array(devices), ("dp",)), P("dp"))
+    disp = aotstore.wrap(
+        "test:mesh_subset",
+        jax.jit(_oracle, in_shardings=sharding, out_shardings=sharding))
+    x = jax.device_put(np.arange(16, dtype=np.int64), sharding)
+    want = np.asarray(disp(x))
+    _reload(disp)
+    before = aotstore.stats()
+    got = disp(x)
+    moved = aotstore.delta(before)
+    assert moved["loads"] == 1 and moved["errors"] == 0
+    assert disp._memo[aotstore.shape_sig((x,))] is not disp._jit
+    assert [s.device for s in got.addressable_shards] == devices
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_size_cap_evicts_oldest(aot_dir, monkeypatch):

@@ -82,7 +82,7 @@ def make_fake_supervisor(registry=None, *, ramp_s=0.0, breaker=None,
         faults.inject("backend.init", faults.SlowRamp(ramp_s))
     if fail_times:
         faults.inject("backend.init", faults.Raise(
-            RuntimeError("tunnel wedged"), times=fail_times))
+            RuntimeError("device wedged"), times=fail_times))
     installed = {}
 
     def probe():
@@ -165,7 +165,7 @@ def test_non_retryable_probe_degrades():
         registry = MetricsRegistry()
 
         def probe():
-            raise ImportError("no accelerator plugin in this image")
+            raise ImportError("no accelerator runtime in this image")
 
         sup = BackendSupervisor(
             probe=probe, install=lambda b: None, name="t",

@@ -447,46 +447,6 @@ def test_chaos_gates_skip_when_missing_or_virtual():
     assert checks["chaos_recovery_s"]["status"] == "ok"
 
 
-def test_coldstart_gates_on_fixtures():
-    """The AOT executable-store acceptance gates: a boot from a
-    populated store must perform ZERO kernel-grade fresh XLA compiles
-    and reach READY >= coldstart_speedup_min (3x) faster than the
-    empty-store cold boot that pays the compile wall."""
-    base = bench_diff.load_result(BASE)
-    out = bench_diff.compare(base, base)
-    checks = _by_metric(out)
-    assert checks["coldstart_warm_store_compiles"]["status"] == "ok"
-    assert checks["coldstart_speedup"]["status"] == "ok"
-
-    reg = bench_diff.load_result(REGRESSED)
-    out = bench_diff.compare(base, reg)
-    checks = _by_metric(out)
-    assert out["verdict"] == "regression"
-    # the regressed fixture recompiled 3 kernels warm and only hit 2x
-    assert checks["coldstart_warm_store_compiles"]["status"] \
-        == "regression"
-    assert checks["coldstart_speedup"]["status"] == "regression"
-
-
-def test_coldstart_gates_skip_when_missing_and_threshold():
-    """Skip-if-missing: the coldstart phase is opt-in
-    (BENCH_COLDSTART=1 — it pays a full compile wall on purpose), so
-    results without the block must compare clean.  The speedup floor
-    is operator-tunable."""
-    base = bench_diff.load_result(BASE)
-    stripped = {k: v for k, v in base.items() if k != "coldstart"}
-    checks = _by_metric(bench_diff.compare(base, stripped))
-    assert checks["coldstart_warm_store_compiles"]["status"] \
-        == "skipped"
-    assert checks["coldstart_speedup"]["status"] == "skipped"
-    # tighten the floor past the healthy fixture's measured 29.1x
-    out = bench_diff.compare(
-        base, base, thresholds={"coldstart_speedup_min": 50.0})
-    checks = _by_metric(out)
-    assert checks["coldstart_speedup"]["status"] == "regression"
-    assert checks["coldstart_warm_store_compiles"]["status"] == "ok"
-
-
 def test_ledger_gates_on_fixtures():
     """The PR-13 dispatch-ledger gates: per bench phase, lane-bucket
     padding waste must stay <= padding_waste_max (0.5) and the mesh

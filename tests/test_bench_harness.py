@@ -1,86 +1,95 @@
-"""bench.py bring-up hardening: the probe must fail FAST and loudly.
+"""bench.py names its device and refuses to measure anything but a TPU.
 
-Round 3 post-mortem: three in-process jax.devices() probes hung ~25
-minutes each before the CPU fallback fired, eating the driver's whole
-budget with zero evidence.  The probe now runs in a kill-able
-subprocess with a hard deadline, and every phase transition appends to
-a heartbeat file (reference keeps its benchmarks honest the same way —
-JMH timeouts in eth-benchmark-tests/.../BLSBenchmark.java).
+JAX is initialised once, in the bench's own process (a chip belongs to
+one process: no probe child, no child boots), every result names
+platform / device kind / device count, the device phases refuse a
+platform that is not `tpu` instead of falling back to the CPU, and a
+phase that fails fails the run.  Every phase transition still appends
+to a heartbeat file (reference keeps its benchmarks honest the same way
+— JMH timeouts in eth-benchmark-tests/.../BLSBenchmark.java).
 """
 
 import json
 import os
+import subprocess
 import sys
-import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 
 import bench  # noqa: E402
 
 
-def test_probe_kills_hung_backend_within_deadline():
-    t0 = time.time()
-    platform, why, _err = bench._probe_backend(
-        1.5, code="import time\ntime.sleep(600)\n")
-    elapsed = time.time() - t0
-    assert platform is None
-    assert "timeout" in why
-    assert elapsed < 30          # seconds, not round 3's 25 minutes
+@pytest.fixture
+def quiet_bench(tmp_path, monkeypatch):
+    """bench.OUT and the heartbeat isolated from the repo's files."""
+    monkeypatch.setattr(bench, "_HEARTBEAT_PATH",
+                        str(tmp_path / "hb.json"))
+    monkeypatch.setattr(bench, "OUT", dict(bench.OUT))
+    monkeypatch.setattr(bench, "_BACKEND_STATES", [])
+    monkeypatch.setattr(bench, "WD", bench._Watchdog())
+    return bench
 
 
-def test_probe_reports_crash_and_garbage():
-    platform, why, err = bench._probe_backend(
-        30, code="import sys\nsys.stderr.write('boom trace')\n"
-                 "sys.exit(3)\n")
-    assert platform is None and "rc=3" in why
-    assert "boom trace" in err   # child stderr is evidence, not lost
-    platform, why, _err = bench._probe_backend(
-        30, code="print('not json')\n")
-    assert platform is None and "garbage" in why
+def test_init_device_names_platform_kind_and_count(quiet_bench):
+    quiet_bench._init_device(need_tpu=False)
+    out = quiet_bench.OUT
+    assert out["platform"] == "cpu" and out["device_count"] == 8
+    assert out["device_kind"] and out["device"]
+    assert out["backend_states"][-1]["state"] == "ready"
 
 
-def test_probe_parses_healthy_backend():
-    code = ("import json\n"
-            "print(json.dumps({'platform': 'tpu', "
-            "'device': 'TPU_0(process=0,(0,0,0,0))'}))\n")
-    platform, device, _err = bench._probe_backend(30, code=code)
-    assert platform == "tpu"
-    assert device.startswith("TPU_0")
+def test_device_phases_refuse_a_platform_that_is_not_tpu(quiet_bench):
+    """No CPU fallback: nothing measured here may be written under
+    sigs/sec/chip."""
+    with pytest.raises(quiet_bench.NoTpuError, match="need a TPU"):
+        quiet_bench._init_device(need_tpu=True)
+    # the refusal still says what it found
+    assert quiet_bench.OUT["platform"] == "cpu"
+    assert quiet_bench.OUT["value"] == 0.0
 
 
-def test_probe_retries_until_success(monkeypatch):
-    """Round 4 gave up after ONE probe; the retry loop must try again
-    within budget and report each failure's stderr to the heartbeat."""
-    calls = []
-
-    def fake_probe(timeout_s, code=None):
-        calls.append(timeout_s)
-        if len(calls) < 2:
-            return None, "probe timeout after 1s", "tunnel stderr tail"
-        return "tpu", "TPU_0", ""
-
-    monkeypatch.setattr(bench, "_probe_backend", fake_probe)
-    monkeypatch.setenv("BENCH_PROBE_TIMEOUT_S", "1")
-    monkeypatch.setenv("BENCH_PROBE_ATTEMPTS", "3")
-    platform, device = bench._probe_with_retries(time.time() + 3600)
-    assert platform == "tpu" and device == "TPU_0"
-    assert len(calls) == 2
+def _run_bench(tmp_path, **env):
+    off = {f"BENCH_{p}": "0" for p in (
+        "THROUGHPUT", "P50", "MONT", "MSM", "DEDUP", "MESH", "KZG",
+        "EPOCH", "OVERLOAD", "MAINNET", "CHAOS")}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "bench.py")],
+        env={**os.environ, **off, "JAX_PLATFORMS": "cpu",
+             "BENCH_RUN_ID": "unit", **env},
+        cwd=str(tmp_path), capture_output=True, text=True, timeout=300)
+    return proc.returncode, json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def test_probe_retries_respect_budget(monkeypatch):
-    """With <90s remaining no further probe attempt may start."""
-    calls = []
+def test_bench_without_tpu_exits_nonzero_and_measures_nothing(tmp_path):
+    rc, out = _run_bench(tmp_path, BENCH_THROUGHPUT="1")
+    assert rc != 0
+    assert out["platform"] == "cpu" and out["value"] == 0.0
+    assert "need a TPU" in out["error"]
+    assert "fallback" not in out and "detail" not in out
 
-    def fake_probe(timeout_s, code=None):
-        calls.append(timeout_s)
-        return None, "probe timeout", ""
 
-    monkeypatch.setattr(bench, "_probe_backend", fake_probe)
-    monkeypatch.setenv("BENCH_PROBE_ATTEMPTS", "5")
-    platform, why = bench._probe_with_retries(time.time() + 60)
-    assert platform is None
-    assert calls == []           # budget already too thin to probe
+def test_failed_phase_fails_the_run(tmp_path):
+    """A phase's exception lands in `<phase>_error` AND in the exit
+    code (it used to be swallowed into the JSON with exit 0)."""
+    rc, out = _run_bench(tmp_path, BENCH_OVERLOAD="1",
+                         BENCH_OVERLOAD_FACTORS="not-a-number")
+    assert rc != 0
+    assert "ValueError" in out["overload_error"]
+    assert out["error"] == "failed phases: overload"
+
+
+def test_no_child_process_and_no_device_forcing_in_bench():
+    """One process holds the chip: bench starts no child, forces no
+    platform and no virtual devices, and names no cache dir of its
+    own."""
+    src = open(os.path.join(_REPO, "bench.py")).read()
+    for needle in ("subprocess", "mkdtemp", "ensure_virtual_devices",
+                   'environ["JAX_PLATFORMS"]', "jax_platforms",
+                   "TEKU_TPU_XLA_CACHE_DIR"):
+        assert needle not in src, needle
 
 
 def test_heartbeat_file_records_stages(tmp_path, monkeypatch):

@@ -1,6 +1,6 @@
 """bls_to_execution_changes: pool, gossip, block packing, REST family.
 
-The VERDICT done-criterion scenario: on a capella devnet a submitted
+The done-criterion scenario: on a capella devnet a submitted
 bls-change enters the pool (entry-validated, the reference's
 SignedBlsToExecutionChangeValidator semantics), is packed into a
 proposal, executes on-chain (credentials flip to 0x01), and is pruned

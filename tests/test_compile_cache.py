@@ -12,6 +12,9 @@ Fast-tier gates for the two boot-cost levers this repo leans on:
 """
 
 import logging
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -24,33 +27,31 @@ from teku_tpu.ops import limbs as fp
 from teku_tpu.ops import mxu
 
 
+def _rebind_cache():
+    # jax pins its cache object to the dir it first initialized with
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture
-def isolated_cache(tmp_path, monkeypatch):
+def isolated_cache(tmp_path):
     """Point the persistent cache at a fresh dir; restore after."""
     before = {
         "dir": jax.config.jax_compilation_cache_dir,
         "min_s": jax.config.jax_persistent_cache_min_compile_time_secs,
-        "min_b": jax.config.jax_persistent_cache_min_entry_size_bytes,
     }
-    monkeypatch.delenv(compilecache.ENV_DIR, raising=False)
-    cache_dir = tmp_path / "xla_cache"
-    yield str(cache_dir)
+    cache_dir = str(tmp_path / "xla_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    _rebind_cache()
+    yield cache_dir
     jax.config.update("jax_compilation_cache_dir", before["dir"])
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       before["min_s"])
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                      before["min_b"])
-    # rebind jax's cache object to the restored dir (it pins the dir
-    # it first initialized with; configure() does the same on change)
-    from jax._src import compilation_cache as _cc
-    _cc.reset_cache()
+    _rebind_cache()
 
 
 def test_compile_cache_round_trips(isolated_cache):
-    got = compilecache.configure(cache_dir=isolated_cache,
-                                 min_compile_s=0)
-    assert got == isolated_cache
-    assert compilecache.cache_dir() == isolated_cache
     assert compilecache.ensure_instrumented()
 
     # the traced program must be unique to this test run, or a
@@ -61,7 +62,6 @@ def test_compile_cache_round_trips(isolated_cache):
     first = jax.jit(lambda v: (v * 3 + 1).sum())(x)
     moved = compilecache.delta(before)
     assert moved["misses"] >= 1, "first jit must MISS the fresh dir"
-    import os
     assert os.listdir(isolated_cache), "miss must populate the dir"
 
     # a fresh process/config reload in miniature: drop the in-memory
@@ -89,17 +89,37 @@ def test_classify_first_dispatch_outcomes():
         {"hits": 0, "misses": 0}) == "compile"
 
 
-def test_configure_off_disables(monkeypatch):
-    prev_dir = jax.config.jax_compilation_cache_dir
-    monkeypatch.setenv(compilecache.ENV_DIR, "off")
-    assert compilecache.configure() is None
-    assert compilecache.cache_dir() is None
-    # off actually turns the jax-side cache off, not just the report
-    assert jax.config.jax_compilation_cache_dir is None
-    # re-enable for the rest of the suite (conftest wired this dir)
-    monkeypatch.delenv(compilecache.ENV_DIR)
-    if prev_dir:
-        assert compilecache.configure(cache_dir=prev_dir) == prev_dir
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CONFIGURE = {
+    # the cli boot order: configure() before anything imports jax
+    "before_jax": ("from teku_tpu.infra import compilecache\n"
+                   "got = compilecache.configure()\n"
+                   "import jax\n"),
+    # the bench / test order: jax is already imported
+    "after_jax": ("import jax\n"
+                  "from teku_tpu.infra import compilecache\n"
+                  "got = compilecache.configure()\n"),
+}
+
+
+@pytest.mark.parametrize("order", sorted(_CONFIGURE))
+@pytest.mark.parametrize("placed", [True, False])
+def test_cache_dir_is_placed_from_outside(tmp_path, order, placed):
+    """JAX_COMPILATION_CACHE_DIR set: the cache lives there and no
+    directory is set in code.  Unset: the fixed <checkout>/.jax_cache.
+    A fresh process each, because jax reads the variable at import."""
+    env = {k: v for k, v in os.environ.items()
+           if k != compilecache.JAX_ENV_DIR}
+    want = os.path.join(_REPO, ".jax_cache")
+    if placed:
+        want = env[compilecache.JAX_ENV_DIR] = str(tmp_path / "placed")
+    code = (_CONFIGURE[order]
+            + "print(got)\nprint(jax.config.jax_compilation_cache_dir)\n")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          cwd=_REPO, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr[-800:]
+    assert proc.stdout.split() == [want, want]
 
 
 def test_mxu_on_cpu_falls_back_with_one_warn(caplog):

@@ -388,8 +388,10 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     assert rec["h2c"]["cache_hits"] == 0
     assert single_impl.h2c_dispatch_count - before[3] == 1
     assert rec["h2c"]["dispatch_bucket"] >= 8
+    # aot_load: a warm .jax_aot/ serves the first dispatch (the same
+    # count from a cold and from a warm store)
     assert rec["compile"]["outcome"] in ("compile", "cache_load",
-                                         "cache_hit")
+                                         "aot_load", "cache_hit")
     assert rec["compile"]["enqueue_s"] >= 0
     assert rec["verdict"] is True
     assert rec["device"]["sync_s"] >= 0

@@ -118,9 +118,9 @@ def test_multi_key_lanes_share_message(impl):
 # --------------------------------------------------------------------------
 
 def test_gather_scatter_bit_exact():
-    import __graft_entry__ as ge
+    from teku_tpu.ops import examples
     (pk_xs, pk_ys, pk_present, u0, u1, group_idx, group_present,
-     sig_x, s_large, s_inf, r_bits, lane_valid) = ge._example_batch(4)
+     sig_x, s_large, s_inf, r_bits, lane_valid) = examples.example_batch(4)
     jits = V.staged_jits()
     hm_uniq = jits["h2c"](u0, u1)
     # lane_map derived from the group index

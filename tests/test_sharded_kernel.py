@@ -2,7 +2,7 @@
 
 Exercises the multi-chip path (shard_map over a dp axis with all_gather
 combines, teku_tpu/ops/verify.py:verify_kernel_sharded) that production
-runs over ICI — the exact program the driver's dryrun_multichip checks.
+runs over ICI (on four real chips: `python chip_smoke.py --mesh4`).
 """
 
 import numpy as np
@@ -11,7 +11,7 @@ import pytest
 import jax
 from jax.sharding import Mesh
 
-import __graft_entry__ as ge
+from teku_tpu.ops import examples
 from teku_tpu.ops import verify as V
 
 
@@ -25,7 +25,7 @@ def mesh():
 
 
 def test_sharded_kernel_valid_batch(mesh):
-    args = ge._example_batch_hm(8)
+    args = examples.example_batch_hm(8)
     sharded = jax.jit(V.verify_kernel_sharded(mesh, "dp"))
     ok, lane_ok = sharded(*args)
     assert bool(np.asarray(ok))
@@ -33,7 +33,7 @@ def test_sharded_kernel_valid_batch(mesh):
 
 
 def test_sharded_kernel_rejects_tampered_lane(mesh):
-    args = ge._example_batch_hm(8)
+    args = examples.example_batch_hm(8)
     # corrupt one lane's H(m) point: the whole-batch verdict must flip
     (pk_xs, pk_ys, pk_present, hm, sig_x, s_large, s_inf,
      r_bits, lane_valid) = args

@@ -106,12 +106,6 @@ DEFAULT_THRESHOLDS: Dict[str, float] = {
     # busy (BENCH_r18 latency phase: 0.0002).  Raise to ~0.3 on real
     # parallel hardware where enqueue overlaps device execution.
     "overlap_efficiency_min": 0.0,
-    # cold-start gate (AOT executable store PR): booting with a
-    # populated store must reach READY at least this many times faster
-    # than an empty-store cold boot that pays the full compile wall.
-    # Skip-if-missing: absent when BENCH_COLDSTART=0 (the default —
-    # the phase pays one full compile wall on purpose).
-    "coldstart_speedup_min": 3.0,
 }
 
 
@@ -398,30 +392,6 @@ def compare(base: dict, new: dict,
             mchaos.get("recovered"),
             lambda v: v is True,
             "the loadgen chaos mesh must readmit and grow back")
-
-    # cold-start gates (absolute, skip-if-missing): the coldstart
-    # phase boots the supervisor three times in fresh subprocesses —
-    # empty store, XLA cache only, populated AOT store.  With the AOT
-    # store warm, boot must deserialize executables instead of
-    # compiling: zero kernel-grade fresh XLA compiles (micro-op jnp
-    # compiles under TEKU_TPU_KERNEL_COMPILE_MIN_S don't count), and
-    # time-to-READY at least coldstart_speedup_min times better than
-    # the empty-store boot
-    cold = _get(new, "coldstart") \
-        if isinstance(_get(new, "coldstart"), dict) else {}
-    _check_absolute(
-        checks, "coldstart_warm_store_compiles",
-        cold.get("warm_store_kernel_compiles"),
-        lambda v: v == 0,
-        "a populated AOT store must boot to READY with zero "
-        "kernel-grade fresh XLA compiles")
-    _check_absolute(
-        checks, "coldstart_speedup",
-        cold.get("speedup_vs_empty"),
-        lambda v: v >= thr["coldstart_speedup_min"],
-        f"warm-store boot must be >= "
-        f"{thr['coldstart_speedup_min']}x faster to READY than "
-        f"the empty-store cold boot")
 
     # ledger gates (absolute, per phase, skip-if-missing): each bench
     # phase's dispatch-ledger summary must keep padding waste and mesh
