@@ -236,8 +236,17 @@ class GuardedBls12381(BLS12381):
         device_fn = getattr(device, op)
 
         def locked():
+            # runs on the breaker's dispatch thread: the hop to it ends
+            # here, and what follows until the lock is ours is the
+            # wait behind the other worker's whole dispatch
+            marks = tracing.current_marks()
+            marks.mark("lock_wait")
             with lock:
-                return device_fn(*args)
+                marks.stamp_lock("acquired")
+                try:
+                    return device_fn(*args)
+                finally:
+                    marks.stamp_lock("released")
 
         try:
             result = self.breaker.call(locked)
@@ -261,8 +270,9 @@ class GuardedBls12381(BLS12381):
                          type(exc).__name__, exc)
             self._notify_healer(exc, timeout=False)
         # the oracle serving a device's call IS the degraded-mode cost:
-        # a separate stage so traces show where the p50 went
-        with tracing.span("oracle_execute"):
+        # a separate stage so traces show where the p50 went (a phase
+        # of the dispatch where the service marks one)
+        with tracing.dispatch_marks("oracle_execute"):
             return getattr(self.oracle, op)(*args)
 
     def public_key_is_valid(self, public_key: bytes) -> bool:

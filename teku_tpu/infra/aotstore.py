@@ -22,6 +22,15 @@ argument signature resolves ONCE per process — to the deserialized
 store executable when present, to the wrapped jit otherwise — and the
 load/miss counters let ``ops/provider.py`` classify a first dispatch
 as ``aot_load`` alongside compile/cache_load.
+
+Each resolution leaves ONE load record (``load_records()``, a bounded
+list beside ``stats()``): which program, how it was come by
+(``aot_load`` | ``cache_load`` | ``compile`` | ``jit``), the entry's
+bytes, and the seconds of each cost a cold start pays for it — file
+read, deserialize (of which decode), compile, store write, the first
+call (waited for).
+The provider lists the ones paid inside a first dispatch's enqueue in
+that dispatch's ledger record (``compile.programs``).
 """
 
 import hashlib
@@ -29,9 +38,12 @@ import logging
 import os
 import pickle
 import threading
+import time
 import zlib
-from typing import Callable, Optional, Sequence, Tuple
+from collections import deque
+from typing import Callable, List, Optional, Sequence, Tuple
 
+from . import clock
 from .env import env_int, env_str
 from .metrics import GLOBAL_REGISTRY
 
@@ -64,6 +76,9 @@ _counts = {"load": 0, "miss": 0, "save": 0, "error": 0}
 # mismatch / unwritable store) — a stale store must not flood boot logs
 _warned: set = set()
 _fingerprint_memo: list = []
+# one record per resolved (kernel, signature); a process resolves a few
+# tens (shapes x staged programs), the bound is for a shape explosion
+_load_records: deque = deque(maxlen=512)
 
 _M_STORE = GLOBAL_REGISTRY.labeled_counter(
     "aot_store_total",
@@ -241,21 +256,42 @@ def save(kernel: str, sig: tuple, compiled) -> Optional[str]:
     return path
 
 
-def load(kernel: str, sig: tuple) -> Optional[Callable]:
+def load(kernel: str, sig: tuple,
+         record: Optional[dict] = None) -> Optional[Callable]:
     """Deserialize the stored executable for (kernel, sig), or None —
     missing entries count a miss; corrupt blobs and identity
     mismatches (jax version / device / code fingerprint) degrade to
-    None with ONE WARN per complaint, and the caller compiles fresh."""
+    None with ONE WARN per complaint, and the caller compiles fresh.
+    `record` (a load record in the making) takes the entry's bytes and
+    the seconds of the read and of the deserialize (`decode_s`: its
+    first part, decompress + unpickle + identity check, before XLA's
+    own deserialize-and-load)."""
     base = store_dir()
     if base is None:
         return None
     path = _entry_path(base, kernel, sig)
+    t_read = time.perf_counter()
     try:
         with open(path, "rb") as fh:
             blob = fh.read()
     except OSError:
         _count("miss")
         return None
+    t_deser = time.perf_counter()
+    if record is not None:
+        record["bytes"] = len(blob)
+        record["read_s"] = round(t_deser - t_read, 6)
+    try:
+        return _deserialize(path, blob, record)
+    finally:
+        if record is not None:
+            record["deserialize_s"] = round(
+                time.perf_counter() - t_deser, 6)
+
+
+def _deserialize(path: str, blob: bytes, record: Optional[dict]
+                 ) -> Optional[Callable]:
+    t_decode = time.perf_counter()
     from jax.experimental import serialize_executable
     try:
         entry = _decode(blob)
@@ -276,6 +312,8 @@ def load(kernel: str, sig: tuple) -> Optional[Callable]:
                    f"environment ({', '.join(drift)} changed); "
                    "compiling fresh — re-run `cli precompile`")
         return None
+    if record is not None:
+        record["decode_s"] = round(time.perf_counter() - t_decode, 6)
     try:
         import jax
         by_id = {d.id: d for d in jax.devices()}
@@ -291,6 +329,24 @@ def load(kernel: str, sig: tuple) -> Optional[Callable]:
         return None
     _count("load")
     return fn
+
+
+def _publish(rec: Optional[dict], t_call: float) -> None:
+    """File a load record once its program's first call has returned
+    (None: a signature another thread resolved first)."""
+    if rec is None:
+        return
+    rec["first_call_s"] = round(time.perf_counter() - t_call, 6)
+    with _lock:
+        _load_records.append(rec)
+
+
+def load_records(since: float = 0.0) -> List[dict]:
+    """The load records (copies, oldest first) of the programs resolved
+    at or after `since` on the `t_mono` axis."""
+    with _lock:
+        return [dict(r) for r in _load_records
+                if r["t_mono"] >= since]
 
 
 def stats() -> dict:
@@ -329,38 +385,70 @@ class AotDispatcher:
         self._unproven: set = set()
         self._memo_lock = threading.Lock()
 
-    def _resolve(self, sig: tuple, args: Sequence) -> Callable:
-        fn = load(self.kernel, sig)
+    def _resolve(self, sig: tuple, args: Sequence
+                 ) -> Tuple[Callable, dict]:
+        """The callable for `sig` and its load record (`first_call_s`
+        is the caller's to fill)."""
+        from . import compilecache
+        rec = {"kernel": self.kernel, "outcome": "jit", "bytes": 0,
+               "read_s": 0.0, "decode_s": 0.0, "deserialize_s": 0.0,
+               "compile_s": 0.0, "save_s": 0.0, "first_call_s": None,
+               "t_mono": round(clock.mono(), 6)}
+        fn = load(self.kernel, sig, rec)
         if fn is not None:
-            return fn
+            rec["outcome"] = "aot_load"
+            return fn, rec
         if store_dir() is not None:
             # self-populating miss: compile through the explicit AOT
             # path (same XLA work the jit would do, and the persistent
             # compile cache still applies) so the NEXT process loads
             # this signature instead of compiling it
             try:
+                before = compilecache.stats()
+                t_compile = time.perf_counter()
                 compiled = self._jit.lower(*args).compile()
-                save(self.kernel, sig, compiled)
-                return compiled
+                t_save = time.perf_counter()
+                rec["compile_s"] = round(t_save - t_compile, 6)
+                # `cache_load` where JAX's persistent cache served the
+                # executable, `compile` where XLA did the work
+                rec["outcome"] = compilecache.classify_first_dispatch(
+                    compilecache.delta(before))
+                path = save(self.kernel, sig, compiled)
+                rec["save_s"] = round(time.perf_counter() - t_save, 6)
+                if path is not None:
+                    rec["bytes"] = os.path.getsize(path)
+                return compiled, rec
             except Exception as exc:
                 _warn_once(f"aotpath:{self.kernel}",
                            f"aot store: {self.kernel} cannot take the "
                            f"AOT lowering path ({exc}); serving from "
                            "jit")
-        return self._jit
+        return self._jit, rec
 
     def __call__(self, *args):
         sig = shape_sig(args)
         with self._memo_lock:
             fn = self._memo.get(sig)
+        rec = None
         if fn is None:
-            fn = self._resolve(sig, args)
+            resolved, rec = self._resolve(sig, args)
             with self._memo_lock:
-                fn = self._memo.setdefault(sig, fn)
-                if fn is not self._jit:
+                fn = self._memo.setdefault(sig, resolved)
+                if fn is not resolved:
+                    rec = None      # another thread resolved it first
+                elif fn is not self._jit:
                     self._unproven.add(sig)
         if sig not in self._unproven:
-            return fn(*args)
+            if rec is None:
+                return fn(*args)
+            # the jit's own first call of this signature: trace and
+            # compile happen inside it, the device run is not waited for
+            t_call = time.perf_counter()
+            try:
+                return fn(*args)
+            finally:
+                _publish(rec, t_call)
+        t_call = time.perf_counter()
         try:
             # wait for the result: a failure of the loaded program may
             # only surface when the device runs it
@@ -378,9 +466,15 @@ class AotDispatcher:
                        f"aot store: {self.kernel} executable failed its "
                        f"first call ({type(exc).__name__}: {exc}); "
                        "serving that signature from jit")
-            return self._jit(*args)
+            if rec is not None:
+                rec["outcome"] = "jit"
+            try:
+                return self._jit(*args)
+            finally:
+                _publish(rec, t_call)
         with self._memo_lock:
             self._unproven.discard(sig)
+        _publish(rec, t_call)
         return out
 
     def precompile(self, avals: Sequence) -> str:

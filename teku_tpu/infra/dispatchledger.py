@@ -27,8 +27,17 @@ device time, verdict).  Each record captures:
   rule's inputs);
 - the resolved mesh plan (device count, per-shard row/lane loads,
   makespan ratio = max shard lane load / mean);
-- the compile outcome (compile | cache_load | cache_hit) with the
-  enqueue duration that paid it;
+- the compile outcome (compile | cache_load | aot_load | cache_hit)
+  with the enqueue duration that paid it and, on a first shape,
+  ``compile.programs``: one load record per AOT program resolved
+  inside that enqueue (``infra/aotstore.py`` ``load_records``);
+- ``phases``: the dispatch's whole life as ``[name, t_mono,
+  seconds]`` in order (thread_hop, lock_wait, host_prep,
+  device_enqueue, device_sync, return_hop, settle: the marks of
+  ``infra/tracing.py``, tiling first mark to last), ``lock``:
+  ``{acquired, released}`` of the guarded provider's device-entry
+  lock, and ``parent_seq``: the failed batch a bisect dispatch came
+  from (absent with tracing off);
 - the admission context the service annotated (plan mode, brownout
   level, verify-class mix, flush-failsafe firing) via the
   ``annotate()`` ContextVar — ``asyncio.to_thread`` copies the
@@ -61,7 +70,7 @@ from contextlib import contextmanager
 from contextvars import ContextVar
 from typing import Dict, List, Optional, Tuple
 
-from . import clock
+from . import clock, tracing
 from .env import env_int
 from .metrics import GLOBAL_REGISTRY, MetricsRegistry
 
@@ -222,7 +231,6 @@ class DispatchLedger:
             records = [r for r in records
                        if trace_id in (r.get("trace_ids") or ())]
         if slow:
-            from . import tracing
             slow_ids = {t["trace_id"] for t in tracing.slow_traces()}
             records = [r for r in records
                        if slow_ids & set(r.get("trace_ids") or ())]
@@ -332,4 +340,14 @@ def open_record(**fields) -> dict:
     rec = clock.stamp({})
     rec["admission"] = ann
     rec.update(fields)
+    # the dispatch's phase marks (infra/tracing.py) travel the same
+    # way: the record takes `phases` and `lock` BY REFERENCE, so the
+    # phases that end after the provider has published it (return_hop,
+    # settle, on the service's side) complete it in place
+    marks = tracing.current_marks()
+    if marks:
+        rec["phases"] = marks.phases
+        rec["lock"] = marks.lock
+        rec["parent_seq"] = marks.parent_seq
+        marks.record = rec
     return rec

@@ -2,10 +2,12 @@
 
 The north-star metric is attestation-gossip p50 verify latency, but an
 end-to-end number cannot say WHERE a slow verify spent its time — the
-asyncio queue, batch assembly, host-side limb packing, a JAX recompile,
-device execute, or an oracle fallback.  This module is the attribution
-layer (the reference's analogue is the per-stage labelled timers its
-Besu MetricsSystem hangs off the validation pipeline):
+asyncio queue, batch assembly, the hop to the dispatch thread, the wait
+at the guarded provider's lock, host-side limb packing, a JAX
+recompile, the device, the hop back, or an oracle fallback.  This
+module is the attribution layer (the reference's analogue is the
+per-stage labelled timers its Besu MetricsSystem hangs off the
+validation pipeline):
 
 - ``span(stage, **labels)`` — a context-manager stopwatch usable from
   asyncio tasks AND worker threads (monotonic ``perf_counter``); on
@@ -19,19 +21,34 @@ Besu MetricsSystem hangs off the validation pipeline):
   the slow-trace ring (+ the optional sampler);
 - ``new_trace``/``attach``/``finish`` — the unbundled form for flows
   whose root outlives one lexical scope (the batching service attaches
-  a whole batch's traces around one device dispatch; bench holds a
-  trace open across submit→future-resolve);
+  a whole batch's traces around one device dispatch; the benchmark
+  holds a trace open across submit→future-resolve);
+- ``new_marks``/``current_marks``/``dispatch_marks`` — ONE dispatch's
+  life as consecutive marks on ``clock.mono()``: a mark closes the
+  phase before it and opens the next, so the phases tile the dispatch
+  by construction across the three threads it passes through (event
+  loop → ``to_thread`` worker → the breaker's dispatch thread and
+  back).  Each closed phase goes once through ``record_stage`` (the
+  stage histogram), once into the dispatch's ledger record
+  (``phases``, shared by reference: ``infra/dispatchledger.py``
+  ``open_record``) and, at ``close()``, in one pass into the batch's
+  traces with its exact start; the phases that begin and end on one
+  thread are also entered as ``jax.profiler.TraceAnnotation``, so a
+  profiler capture holds them on the device trace's own clock;
 - a bounded ring of the N slowest complete traces with their stage
   breakdowns, dumped by ``GET /teku/v1/admin/traces``.
 
 Disabled mode (``--tracing off`` / ``set_enabled(False)``) compiles
 spans to a shared no-op: ``span()``/``trace()`` return singletons whose
-enter/exit do nothing, ``new_trace`` returns None, and record calls
-return immediately — no allocation, no lock, no histogram touch.
+enter/exit do nothing, ``new_trace`` returns None, ``new_marks`` /
+``current_marks`` return the shared no-op marks, and record calls
+return immediately — no allocation, no lock, no histogram touch, no
+annotation.
 """
 
 import itertools
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -43,15 +60,31 @@ from .env import env_int
 
 from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 
-# The canonical hot-path stages (bench reports percentiles for these;
-# `complete` is the root span's end-to-end total).  The old combined
-# `device_execute` span is split: `device_enqueue` covers the async
-# launch (plus XLA compile on a first shape), `device_sync` covers
-# only the blocking wait at the handle's result() — so under async
-# overlap the sync span no longer absorbs host-prep time the worker
-# spent on the NEXT batch (the PERF.md attribution fix).
-STAGES = ("queue_wait", "assembly", "dispatch", "host_prep",
-          "device_enqueue", "device_sync", "complete")
+# The canonical hot-path stages.  Per task: `queue_wait` (enqueue →
+# the drain that took it) and `assembly` (the drain), then `complete`,
+# the root span's end-to-end total.  Per dispatch, in order, the
+# phases that `DispatchMarks` tiles from the instant the service has
+# its batch to the instant its last future is settled:
+#   thread_hop      service hands over → the dispatch thread stands
+#                   before the lock (`to_thread` pool wait, thread start)
+#   lock_wait       blocked on the serving pair's device-entry lock
+#   host_prep       wire parse, key lookup, array packing
+#   device_enqueue  the async launches (plus XLA compile or program
+#                   load on a first shape)
+#   device_sync     only the blocking wait at the handle's result()
+#   return_hop      sync ended → the service runs again on the event loop
+#   settle          the verified batch's futures resolved, to the last
+# `dispatch` is the service's span around the whole thread round-trip
+# (the parent of thread_hop .. return_hop in the span tree);
+# `oracle_execute` is a guarded call the oracle served for the device.
+STAGES = ("queue_wait", "assembly", "dispatch", "thread_hop",
+          "lock_wait", "host_prep", "device_enqueue", "device_sync",
+          "return_hop", "settle", "oracle_execute", "complete")
+
+# phases that begin and end on ONE thread: entered as profiler
+# annotations too (a hop crosses threads and cannot be)
+_ANNOTATED = frozenset(("lock_wait", "host_prep", "device_enqueue",
+                        "device_sync", "settle"))
 
 _enabled = True
 
@@ -131,6 +164,14 @@ class Trace:
         with self._lock:
             self.stages.append((stage, seconds))
             self.spans.append((stage, t0, seconds))
+
+    def add_spans(self, spans: Sequence[Sequence]) -> None:
+        """Several `[stage, t_mono, seconds]` under ONE lock hold: a
+        dispatch's phases reach each of its 250 traces in one pass."""
+        with self._lock:
+            for stage, t0, seconds in spans:
+                self.stages.append((stage, seconds))
+                self.spans.append((stage, t0, seconds))
 
     @property
     def complete(self) -> bool:
@@ -258,6 +299,170 @@ def span(stage: str, traces: Optional[Sequence[Trace]] = None):
 
 
 # --------------------------------------------------------------------------
+# Dispatch marks: one dispatch's life, gap-free
+# --------------------------------------------------------------------------
+
+def _annotation(name: str):
+    """An entered `jax.profiler.TraceAnnotation`, or None in a process
+    that never imported JAX (no device, no profiler: this module must
+    import without it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class DispatchMarks:
+    """One dispatch's phases as consecutive marks on `clock.mono()`.
+
+    `mark(name)` closes the open phase AT the instant it opens `name`,
+    so the phases tile [first mark, close] with no gap and no overlap
+    whichever thread makes the mark.  No lock: a dispatch's marks come
+    one after another (the hand-overs between its threads order them);
+    only a timed-out dispatch's orphaned thread can interleave, and
+    its record is a fault's best effort.
+
+    `phases` and `lock` are handed to the dispatch's ledger record by
+    reference (`dispatchledger.open_record`), so what the service marks
+    after the provider has published the record completes it in place.
+    A closed phase reaches the stage histogram at once and the batch's
+    traces at `close()`, all phases in one pass over the traces: the
+    only per-task work, and less of it than a span a phase.
+    """
+
+    __slots__ = ("traces", "phases", "lock", "parent_seq", "record",
+                 "_open", "_t0", "_ann", "_copied")
+
+    def __init__(self, traces: Tuple[Trace, ...],
+                 parent_seq: Optional[int] = None):
+        self.traces = traces
+        self.phases: List[list] = []    # [name, t_mono, seconds]
+        self.lock: Dict[str, float] = {}    # acquired / released
+        self.parent_seq = parent_seq
+        self.record: Optional[dict] = None
+        self._open: Optional[str] = None
+        self._t0 = 0.0
+        self._ann = None
+        self._copied = 0        # phases the traces already hold
+
+    def mark(self, name: Optional[str]) -> float:
+        """Close the open phase now and open `name` (None: close
+        only).  Marking the phase that is already open changes
+        nothing.  Returns the instant, so the caller needs no clock
+        read of its own."""
+        now = clock.mono()
+        if name == self._open:
+            return now
+        if self._open is not None:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            seconds = now - self._t0
+            self.phases.append([self._open, round(self._t0, 6),
+                                round(seconds, 6)])
+            record_stage(self._open, seconds, (), t0=self._t0)
+        self._open, self._t0 = name, now
+        if name in _ANNOTATED:
+            self._ann = _annotation(name)
+        return now
+
+    def close(self) -> None:
+        """Close the open phase and hand the traces what they do not
+        hold yet (a dispatch on the async seam closes once, at its
+        end; closing again adds nothing)."""
+        self.mark(None)
+        fresh = self.phases[self._copied:]
+        if fresh:
+            self._copied = len(self.phases)
+            for trace in self.traces:
+                trace.add_spans(fresh)
+
+    def stamp_lock(self, edge: str) -> None:
+        """`acquired` / `released` of the device-entry lock."""
+        self.lock[edge] = round(clock.mono(), 6)
+
+    @property
+    def seq(self) -> Optional[int]:
+        """The ledger seq of the record these marks complete (None
+        until the provider has published it, or when no device
+        dispatch happened: breaker open, invalid wire input)."""
+        return (self.record or {}).get("seq")
+
+
+class _NoMarks:
+    """The shared no-op: tracing disabled, or no dispatch marked in
+    this context.  `mark` still returns the instant (callers use it as
+    their one clock read)."""
+
+    __slots__ = ()
+    seq = None
+
+    def __bool__(self) -> bool:
+        return False
+
+    def mark(self, name: Optional[str]) -> float:
+        return clock.mono()
+
+    def close(self) -> None:
+        pass
+
+    def stamp_lock(self, edge: str) -> None:
+        pass
+
+
+_NO_MARKS = _NoMarks()
+
+# The marks of the dispatch this context belongs to.  Travels the way
+# `_CURRENT` and `dispatchledger.annotate()` do: `asyncio.to_thread`
+# and the breaker's `copy_context()` both carry it.
+_MARKS: ContextVar = ContextVar("teku_tpu_dispatch_marks",
+                                default=_NO_MARKS)
+
+
+def new_marks(traces: Sequence[Optional[Trace]] = (),
+              parent_seq: Optional[int] = None):
+    """Marks for a dispatch whose life outlives one lexical scope (the
+    batching service: bind with `attach(traces, marks)` around the
+    hand-over, `mark` on the way, `close` after the last future is
+    settled).  `parent_seq`: the ledger seq of the failed batch this
+    dispatch bisects.  The shared no-op when tracing is disabled."""
+    if not _enabled:
+        return _NO_MARKS
+    return DispatchMarks(tuple(t for t in traces if t is not None),
+                         parent_seq)
+
+
+def current_marks():
+    """The context's dispatch marks (the shared no-op when none)."""
+    return _MARKS.get()
+
+
+@contextmanager
+def dispatch_marks(first: str):
+    """The bundled form, for a layer that may or may not sit under a
+    marked dispatch: inside one, move it on to `first` and leave its
+    closing to the layer that opened it; otherwise open marks of this
+    block's own (over the context's current traces), closed at its
+    end — so a direct caller of the provider still gets the
+    provider's phases."""
+    marks = _MARKS.get()
+    if marks or not _enabled:
+        marks.mark(first)
+        yield marks
+        return
+    marks = DispatchMarks(_CURRENT.get())
+    marks.mark(first)
+    token = _MARKS.set(marks)
+    try:
+        yield marks
+    finally:
+        _MARKS.reset(token)
+        marks.close()
+
+
+# --------------------------------------------------------------------------
 # Root traces
 # --------------------------------------------------------------------------
 
@@ -272,19 +477,21 @@ def new_trace(name: str, **labels) -> Optional[Trace]:
 
 
 @contextmanager
-def attach(traces: Sequence[Optional[Trace]]):
+def attach(traces: Sequence[Optional[Trace]], marks=None):
     """Bind `traces` (Nones filtered) as the context's current traces
-    for the duration of the block.  `asyncio.to_thread` copies the
-    context, so spans inside a worker thread attribute correctly."""
+    for the duration of the block, and `marks` (`new_marks`) as its
+    dispatch marks.  `asyncio.to_thread` copies the context, so spans
+    and marks inside a worker thread attribute correctly."""
     live = tuple(t for t in traces if t is not None)
-    if not live:
-        yield
-        return
-    token = _CURRENT.set(live)
+    t_token = _CURRENT.set(live) if live else None
+    m_token = _MARKS.set(marks) if marks else None
     try:
         yield
     finally:
-        _CURRENT.reset(token)
+        if m_token is not None:
+            _MARKS.reset(m_token)
+        if t_token is not None:
+            _CURRENT.reset(t_token)
 
 
 def current_traces() -> Tuple[Trace, ...]:

@@ -67,8 +67,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from ..crypto import bls
 from ..infra import (capacity, dispatchledger, faults, flightrecorder,
                      timeline, tracing)
-from ..infra.metrics import (GLOBAL_REGISTRY, LATENCY_BUCKETS_S,
-                             MetricsRegistry)
+from ..infra.metrics import GLOBAL_REGISTRY, MetricsRegistry
 from ..infra.env import env_bool, env_float
 from .admission import (AdmissionController, BatchPlan, SHEDDABLE,
                         VerifyClass, class_deadline_s)
@@ -412,12 +411,8 @@ class AggregatingSignatureVerificationService:
         self._m_batch_size = registry.histogram(
             f"{name}_batch_size", "signatures per dispatched batch",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512))
-        # batch LATENCY next to batch size: a regressed p50 with a flat
-        # size distribution points at the dispatch, not the batching
-        self._m_batch_duration = registry.histogram(
-            f"{name}_batch_duration_seconds",
-            "wall seconds per batch dispatch (device call inclusive)",
-            buckets=LATENCY_BUCKETS_S)
+        # (batch LATENCY is `verify_stage_duration_seconds{stage=
+        # "dispatch"}`, split by phase: infra/tracing.py)
         # first-try vs bisect-recursion dispatches: the failure path
         # amplifies one bad batch into O(log n) extra device calls, and
         # that amplification used to be invisible
@@ -789,9 +784,9 @@ class AggregatingSignatureVerificationService:
                 vip_streak = all(t.cls is VerifyClass.VIP
                                  for t in tasks)
                 try:
-                    handle = t0 = None
+                    handle = marks = None
                     if self.overlap and bls.supports_async_verify():
-                        handle, t0 = await self._begin(
+                        handle, marks = await self._begin(
                             tasks, plan, failsafe_fired)
                     if handle is None:
                         # sync path: implementation has no async seam
@@ -802,7 +797,7 @@ class AggregatingSignatureVerificationService:
                             tasks, plan=plan,
                             flush_failsafe=failsafe_fired)
                     else:
-                        prev, inflight = inflight, (tasks, handle, t0)
+                        prev, inflight = inflight, (tasks, handle, marks)
                         if prev is not None:
                             await self._retire(*prev)
                 except asyncio.CancelledError:
@@ -996,32 +991,35 @@ class AggregatingSignatureVerificationService:
                      plan: Optional[BatchPlan] = None,
                      flush_failsafe: bool = False):
         """Async-dispatch a batch: host_prep + device enqueue on a
-        worker thread.  Returns (handle, t0); handle is None when the
-        active implementation has no async path."""
+        worker thread.  Returns (handle, marks); handle is None when
+        the active implementation has no async path."""
         triples = [tr for t in tasks for tr in t.triples]
-        t0 = time.perf_counter()
-        with tracing.attach([t.trace for t in tasks]), \
+        traces = [t.trace for t in tasks]
+        marks = tracing.new_marks(traces)
+        with tracing.attach(traces, marks), \
                 dispatchledger.annotate(
                     **self._dispatch_annotations(
                         tasks, plan, flush_failsafe)):
             with tracing.span("dispatch"):
+                marks.mark("thread_hop")
                 handle = await self._dispatch_in_thread(
                     bls.begin_batch_verify, triples)
         if handle is None:
-            return None, t0
+            return None, marks
         self._m_batches.inc()
         self._m_batch_size.observe(len(triples))
         self._m_dispatches.labels(kind="first_try").inc()
-        return handle, t0
+        return handle, marks
 
-    async def _retire(self, tasks: List[_Task], handle, t0) -> None:
+    async def _retire(self, tasks: List[_Task], handle, marks) -> None:
         """Synchronize an in-flight dispatch and settle its tasks
         (bisecting failures through the sync path)."""
         try:
-            # the handle records the device_enqueue/device_sync spans
-            # itself (it
-            # captured the batch's traces at dispatch time)
+            # the handle marks device_sync and the way back itself (it
+            # captured the batch's marks at dispatch time)
+            marks.mark("thread_hop")
             ok = await self._dispatch_in_thread(handle.result)
+            marks.mark("settle")
         except asyncio.CancelledError:
             raise
         except Exception as exc:
@@ -1029,9 +1027,9 @@ class AggregatingSignatureVerificationService:
             for t in tasks:
                 self._drop_pending(t)
                 t.settle(exc=exc)
+            marks.close()
             return
-        self._m_batch_duration.observe(time.perf_counter() - t0)
-        await self._resolve_batch(tasks, ok)
+        await self._resolve_batch(tasks, ok, marks)
 
     def _drop_cancelled(self, tasks: List[_Task]) -> List[_Task]:
         """Filter cancelled tasks, releasing their pending-map entries.
@@ -1064,7 +1062,8 @@ class AggregatingSignatureVerificationService:
     async def _verify_batch(self, tasks: List[_Task],
                             first_try: bool = True,
                             plan: Optional[BatchPlan] = None,
-                            flush_failsafe: bool = False) -> None:
+                            flush_failsafe: bool = False,
+                            parent_seq: Optional[int] = None) -> None:
         tasks = self._drop_cancelled(tasks)
         if not tasks:
             return
@@ -1073,38 +1072,58 @@ class AggregatingSignatureVerificationService:
         self._m_batch_size.observe(len(triples))
         self._m_dispatches.labels(
             kind="first_try" if first_try else "bisect").inc()
-        # the dispatch runs with the whole batch's traces bound to the
-        # context: asyncio.to_thread copies it, so the provider's
-        # host_prep/device_enqueue/device_sync spans attribute to
-        # every trace
-        t0 = time.perf_counter()
-        with tracing.attach([t.trace for t in tasks]), \
-                dispatchledger.annotate(
-                    **self._dispatch_annotations(
-                        tasks, plan, flush_failsafe)):
-            with tracing.span("dispatch"):
-                ok = await self._dispatch_in_thread(
-                    bls.batch_verify, triples)
-        self._m_batch_duration.observe(time.perf_counter() - t0)
-        await self._resolve_batch(tasks, ok)
+        # the dispatch runs with the whole batch's traces and its
+        # phase marks bound to the context: asyncio.to_thread copies
+        # it, so the guard's and the provider's phases attribute to
+        # every trace and land in ONE ledger record.  `thread_hop`
+        # opens inside the `dispatch` span, its parent in the span tree
+        traces = [t.trace for t in tasks]
+        marks = tracing.new_marks(traces, parent_seq)
+        try:
+            with tracing.attach(traces, marks), \
+                    dispatchledger.annotate(
+                        **self._dispatch_annotations(
+                            tasks, plan, flush_failsafe)):
+                with tracing.span("dispatch"):
+                    marks.mark("thread_hop")
+                    ok = await self._dispatch_in_thread(
+                        bls.batch_verify, triples)
+        except BaseException:
+            # the dispatch that raised is the one to look at: its
+            # traces get the phases it reached
+            marks.close()
+            raise
+        # after the span has closed: `settle` is its sibling, never a
+        # child that a late mark would nest under it
+        marks.mark("settle")
+        await self._resolve_batch(tasks, ok, marks)
 
-    async def _resolve_batch(self, tasks: List[_Task], ok: bool) -> None:
+    async def _resolve_batch(self, tasks: List[_Task], ok: bool,
+                             marks) -> None:
         """Post-dispatch settlement: complete on success, bisect on
-        failure (shared by the sync and the async-overlap paths)."""
-        if ok:
+        failure (shared by the sync and the async-overlap paths).
+        `marks` (the dispatch's, in `settle` by now) close with the
+        last future settled; a failed batch settles nothing, and its
+        bisection's dispatches have marks of their own that point at
+        this one's ledger record."""
+        settled = ok or len(tasks) == 1
+        if settled:
             for t in tasks:
-                self._complete(t, True)
+                self._complete(t, ok)
+        marks.close()
+        if settled:
             return
-        if len(tasks) == 1:
-            self._complete(tasks[0], False)
-            return
+        parent = marks.seq
         if len(tasks) >= self.split_threshold:
             half = len(tasks) // 2
-            await self._verify_batch(tasks[:half], first_try=False)
-            await self._verify_batch(tasks[half:], first_try=False)
+            await self._verify_batch(tasks[:half], first_try=False,
+                                     parent_seq=parent)
+            await self._verify_batch(tasks[half:], first_try=False,
+                                     parent_seq=parent)
         else:
             for t in tasks:
-                await self._verify_batch([t], first_try=False)
+                await self._verify_batch([t], first_try=False,
+                                         parent_seq=parent)
 
     def _drop_pending(self, task: _Task) -> None:
         if task.key is not None and self._pending.get(task.key) is task:

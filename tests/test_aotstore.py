@@ -302,3 +302,88 @@ def test_entry_key_is_filename_safe_and_stable():
     assert all(c.isalnum() or c in "._-" for c in key), key
     assert key != aotstore.entry_key("mesh:4:dp:ladder:vpu:deadbeef",
                                      sig)
+
+
+# --------------------------------------------------------------------------
+# One load record per resolved program
+# --------------------------------------------------------------------------
+
+def _records_of(kernel):
+    return [r for r in aotstore.load_records() if r["kernel"] == kernel]
+
+
+def test_load_records_for_a_miss_then_a_load(aot_dir):
+    """A miss compiles, saves and leaves ONE record with the compile's
+    and the write's seconds; the next process (the memo dropped) loads
+    and leaves one with the read's and the deserialize's.  Each holds
+    the first call, recorded once per signature."""
+    x = jnp.arange(8, dtype=jnp.int32)
+    disp = aotstore.wrap("test:records", jax.jit(_oracle))
+    t_before = aotstore.clock.mono()
+    np.asarray(disp(x))
+    np.asarray(disp(x))                 # memoized: no second record
+    (miss,) = _records_of("test:records")
+    assert miss["outcome"] in ("compile", "cache_load")
+    assert miss["compile_s"] > 0 and miss["save_s"] > 0
+    assert miss["read_s"] == 0 and miss["deserialize_s"] == 0
+    assert miss["first_call_s"] > 0
+    assert miss["bytes"] == os.path.getsize(
+        os.path.join(aot_dir, os.listdir(aot_dir)[0]))
+    assert miss["t_mono"] >= t_before
+
+    _reload(disp)
+    np.asarray(disp(x))
+    np.asarray(disp(x))
+    miss_again, load = _records_of("test:records")
+    assert miss_again == miss
+    assert load["outcome"] == "aot_load"
+    assert load["deserialize_s"] > 0 and load["read_s"] > 0
+    assert 0 < load["decode_s"] <= load["deserialize_s"]
+    assert miss["decode_s"] == 0
+    assert load["compile_s"] == 0 and load["save_s"] == 0
+    assert load["first_call_s"] > 0
+    assert load["bytes"] == miss["bytes"]
+    # another signature of the same kernel is a program of its own
+    np.asarray(disp(jnp.arange(4, dtype=jnp.int32)))
+    assert len(_records_of("test:records")) == 3
+    # `since` cuts on the t_mono axis, and the copies are the caller's
+    later = aotstore.load_records(since=load["t_mono"])
+    assert [r["kernel"] for r in later
+            if r["kernel"] == "test:records"] == ["test:records"] * 2
+    later[0]["kernel"] = "scribbled"
+    assert not _records_of("scribbled")
+
+
+def test_load_record_with_the_store_off_is_the_jits(monkeypatch):
+    monkeypatch.setenv(aotstore.ENV_ON, "0")
+    disp = aotstore.wrap("test:records_off", jax.jit(_oracle))
+    disp(jnp.arange(8, dtype=jnp.int32))
+    disp(jnp.arange(8, dtype=jnp.int32))
+    (rec,) = _records_of("test:records_off")
+    assert rec["outcome"] == "jit"
+    assert rec["first_call_s"] > 0
+    assert rec["bytes"] == 0 and rec["compile_s"] == 0
+
+
+def test_load_record_of_a_failed_first_call_names_the_jit(aot_dir):
+    """A loaded program that fails its first call is served by the jit
+    from then on: the record says so, and its first call holds both."""
+    x = jnp.arange(8, dtype=jnp.int32)
+    disp = aotstore.wrap("test:records_fail", jax.jit(_oracle))
+    np.asarray(disp(x))
+    _reload(disp)
+
+    def broken(*args):
+        raise RuntimeError("runtime rejects the executable")
+
+    real_load = aotstore.load
+    try:
+        aotstore.load = lambda kernel, sig, record=None: (
+            real_load(kernel, sig, record) and broken)
+        out = np.asarray(disp(x))
+    finally:
+        aotstore.load = real_load
+    np.testing.assert_array_equal(out, _oracle(np.arange(8)))
+    _miss, failed = _records_of("test:records_fail")
+    assert failed["outcome"] == "jit"
+    assert failed["deserialize_s"] > 0 and failed["first_call_s"] > 0

@@ -366,7 +366,8 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     fields to all-hits/zero-bucket."""
     pure, sks, pks = keys
     triples = _grid_batch(pure, sks, pks)
-    before = (PV._M_LANES_REAL.value, PV._M_LANES_PADDED.value,
+    before = (PV._M_H2C_LANES.value,
+              dispatchledger.LEDGER._padded["lane"],
               PV._M_H2C_UNIQUE.value, single_impl.h2c_dispatch_count,
               dispatchledger.LEDGER.recorded_total)
     assert single_impl.batch_verify(triples)
@@ -374,11 +375,12 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     # exactly ONE record per batch dispatch (the h2c sub-dispatch does
     # not open its own record)
     assert dispatchledger.LEDGER.recorded_total == before[4] + 1
-    # lanes real/padded == the provider counter deltas
-    assert rec["lanes"] == PV._M_LANES_REAL.value - before[0] == 16
+    # lanes real == the provider's lane counter delta, padded == what
+    # the ledger's cumulative waste gauge took in
+    assert rec["lanes"] == PV._M_H2C_LANES.value - before[0] == 16
     assert rec["waste"]["lane"]["real"] == 16
     assert rec["waste"]["lane"]["padded"] \
-        == PV._M_LANES_PADDED.value - before[1] == 16
+        == dispatchledger.LEDGER._padded["lane"] - before[1] == 16
     # unique messages == the dedup counter delta; ratio matches
     assert rec["unique_messages"] \
         == PV._M_H2C_UNIQUE.value - before[2] == 8
@@ -393,6 +395,23 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     assert rec["compile"]["outcome"] in ("compile", "cache_load",
                                          "aot_load", "cache_hit")
     assert rec["compile"]["enqueue_s"] >= 0
+    # a first dispatch lists the programs its enqueue resolved, one
+    # load record each, and their costs fit inside the enqueue
+    if rec["compile"]["outcome"] != "cache_hit":
+        programs = rec["compile"]["programs"]
+        assert {p["kernel"].split(":")[1] for p in programs} \
+            >= {"h2c", "finish"}
+        assert all(p["t_mono"] >= rec["t_mono"] - 1.0 for p in programs)
+        assert sum(p["read_s"] + p["deserialize_s"] + p["compile_s"]
+                   + p["save_s"] + p["first_call_s"] for p in programs) \
+            <= rec["compile"]["enqueue_s"] + 1e-3
+    # no service above: the provider's own phases, tiling
+    assert [n for n, _t, _s in rec["phases"]] == [
+        "host_prep", "device_enqueue", "device_sync", "return_hop"]
+    for (_a, t0, secs), (_b, t1, _s) in zip(rec["phases"],
+                                            rec["phases"][1:]):
+        assert t0 + secs == pytest.approx(t1, abs=2.5e-6)
+    assert rec["lock"] == {} and rec["parent_seq"] is None
     assert rec["verdict"] is True
     assert rec["device"]["sync_s"] >= 0
     assert rec["mesh"]["devices"] == 0
@@ -406,6 +425,7 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
                            "dispatch_bucket": 0}
     assert single_impl.h2c_dispatch_count == h2c_before
     assert warm["compile"]["outcome"] == "cache_hit"
+    assert "programs" not in warm["compile"]
 
 
 def test_tampered_batch_records_false_verdict(single_impl, keys):

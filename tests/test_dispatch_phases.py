@@ -1,0 +1,425 @@
+"""A dispatch's whole life in the program's own spans: the phase marks
+of `infra/tracing.py` tile a dispatch from the service's hand-over to
+its last settled future, across the event loop, the `to_thread` worker
+and the breaker's dispatch thread, and land once in the stage
+histogram, once in the batch's traces and once in the dispatch's ledger
+record.
+
+The device here is a fake that does what `ops/provider.py` does around
+a dispatch (scope the marks, open the ledger record, hand the real
+`_DispatchHandle` its verdict arrays) and sleeps where the provider
+works, so the service, the facade, the guard, the breaker, the handle
+and the ledger are the real ones."""
+
+import asyncio
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from teku_tpu.crypto import bls
+from teku_tpu.crypto.bls import loader
+from teku_tpu.infra import dispatchledger, timeline, tracing
+from teku_tpu.infra.metrics import GLOBAL_REGISTRY, MetricsRegistry
+from teku_tpu.infra.supervisor import CircuitBreaker
+from teku_tpu.services.signatures import (
+    AggregatingSignatureVerificationService)
+
+SERVED = ["thread_hop", "lock_wait", "host_prep", "device_enqueue",
+          "device_sync", "return_hop", "settle"]
+PK = b"\xa0" + bytes(47)
+
+
+class PhasedDevice:
+    """Sleeps `hold_s` under the guard's lock; a task whose message
+    starts with b"bad" makes its batch false."""
+
+    name = "phased-fake"
+
+    def __init__(self, hold_s: float = 0.0):
+        self.hold_s = hold_s
+
+    def _begin(self, triples):
+        from teku_tpu.ops.provider import _DispatchHandle
+        n = len(triples)
+        marks = tracing.current_marks()
+        marks.mark("host_prep")
+        time.sleep(self.hold_s / 2)
+        traces = tracing.current_traces()
+        rec = dispatchledger.open_record(
+            trace_ids=[t.trace_id for t in traces], shape=f"{n}x1",
+            lanes=n)
+        marks.mark("device_enqueue")
+        rec["compile"] = {"outcome": "cache_hit", "enqueue_s": 0.0}
+        ok = not any(msg.startswith(b"bad") for _pks, msg, _sig in triples)
+        return _DispatchHandle(
+            np.bool_(ok), np.ones(n, dtype=bool), n, traces,
+            shape=f"{n}x1", path="vpu", t_enq_end=time.perf_counter(),
+            rec=rec, marks=marks)
+
+    def batch_verify(self, triples):
+        with tracing.dispatch_marks("host_prep"):
+            handle = self._begin(triples)
+            time.sleep(self.hold_s / 2)
+            return handle.result()
+
+    def fast_aggregate_verify(self, pks, msg, sig):
+        return self.batch_verify([(pks, msg, sig)])
+
+
+class AsyncPhasedDevice(PhasedDevice):
+    """The async seam too, as the raw provider has it."""
+
+    def begin_batch_verify(self, triples):
+        handle = self._begin(triples)
+        tracing.current_marks().mark("return_hop")
+        return handle
+
+
+@pytest.fixture(autouse=True)
+def _reset():
+    tracing.set_enabled(True)
+    yield
+    tracing.set_enabled(True)
+    bls.reset_implementation()
+
+
+def _guarded(device):
+    breaker = CircuitBreaker(failure_threshold=2, deadline_s=5.0,
+                             cooldown_s=0.2, name="phases",
+                             registry=MetricsRegistry())
+    return loader.GuardedBls12381(device, breaker,
+                                  registry=MetricsRegistry())
+
+
+def _serve(impl, messages, traced=True, **service_kw):
+    """Queue one task a message in ONE event-loop turn; returns the
+    verdicts, the tasks' traces and the ledger records of the run."""
+    seq0 = dispatchledger.LEDGER.recorded_total
+    traces = []
+
+    async def main():
+        bls.set_implementation(impl)
+        svc = AggregatingSignatureVerificationService(
+            registry=MetricsRegistry(), name="phases_svc", **service_kw)
+        await svc.start()
+        futs = []
+        for msg in messages:
+            tr = tracing.new_trace("phase_task") if traced else None
+            traces.append(tr)
+            with tracing.attach([tr]):
+                futs.append(svc.verify([PK], msg, b"sig"))
+        verdicts = await asyncio.gather(*futs)
+        await svc.stop()
+        return verdicts
+
+    verdicts = asyncio.run(main())
+    for tr in traces:
+        tracing.finish(tr)
+    records = [r for r in dispatchledger.LEDGER.snapshot()
+               if r["seq"] > seq0]
+    return verdicts, traces, records
+
+
+def _assert_tiles(phases):
+    """Consecutive, no gap, no overlap (to the µs the record rounds
+    to)."""
+    for (_n0, t0, secs), (_n1, t1, _s1) in zip(phases, phases[1:]):
+        assert t0 + secs == pytest.approx(t1, abs=2.5e-6)
+    assert all(secs >= 0 for _n, _t, secs in phases)
+
+
+def _stage_counts():
+    hist = GLOBAL_REGISTRY.labeled_histogram(
+        "verify_stage_duration_seconds", labelnames=("stage",))
+    return {s: hist.labels(stage=s).snapshot()[2] for s in SERVED}
+
+
+# --------------------------------------------------------------------------
+# (a) the served path: service -> guarded facade -> device
+# --------------------------------------------------------------------------
+
+def test_served_dispatch_phases_tile_in_order():
+    before = _stage_counts()
+    verdicts, traces, records = _serve(
+        _guarded(PhasedDevice(0.02)), [b"m0", b"m1", b"m2"],
+        num_workers=1)
+    assert verdicts == [True] * 3
+    assert len(records) == 1
+    rec = records[0]
+    assert [name for name, _t, _s in rec["phases"]] == SERVED
+    _assert_tiles(rec["phases"])
+    # the lock's edges lie inside the dispatch, around the device's work
+    by_name = {name: (t0, secs) for name, t0, secs in rec["phases"]}
+    acquired, released = rec["lock"]["acquired"], rec["lock"]["released"]
+    assert by_name["lock_wait"][0] <= acquired <= by_name["host_prep"][0] \
+        + 1e-3
+    assert sum(by_name["device_sync"]) <= released + 2.5e-6
+    assert released - acquired >= 0.02
+    assert rec["parent_seq"] is None
+    # once through record_stage: one histogram sample a phase a
+    # dispatch, and every trace of the batch holds the span at its t0
+    after = _stage_counts()
+    assert {s: after[s] - before[s] for s in SERVED} \
+        == {s: 1 for s in SERVED}
+    for tr in traces:
+        spans = {stage: (t0, secs) for stage, t0, secs in tr.spans}
+        for name, t0, secs in rec["phases"]:
+            assert spans[name][0] == pytest.approx(t0, abs=1e-6)
+            assert spans[name][1] == pytest.approx(secs, abs=1e-6)
+
+
+def test_span_tree_names_the_whole_dispatch():
+    """`timeline.span_tree`, unchanged, shows the phases: `dispatch`
+    holds the hop, the wait, the device's stages and the way back with
+    no `unattributed` hole between them, and `settle` follows it."""
+    _v, traces, _r = _serve(_guarded(PhasedDevice(0.02)), [b"m0", b"m1"],
+                            num_workers=1)
+    tree = timeline.span_tree(traces[0].to_dict())
+    top = [c["phase"] for c in tree["children"]
+           if c["phase"] != "unattributed"]
+    assert top == ["queue_wait", "assembly", "dispatch", "settle"]
+    dispatch = next(c for c in tree["children"]
+                    if c["phase"] == "dispatch")
+    assert [c["phase"] for c in dispatch["children"]
+            if c["phase"] != "unattributed"] == SERVED[:-1]
+    # what no phase covers is a preempted thread at a seam, if anything
+    holes = [c["dur_ms"] for node in (tree, dispatch)
+             for c in node["children"] if c["phase"] == "unattributed"]
+    assert sum(holes) < 5.0
+
+
+def test_second_worker_waits_out_the_first_ones_hold():
+    """Two workers, two batches at once: the second one's `lock_wait`
+    is the first one's hold of the lock."""
+    hold = 0.12
+    verdicts, _t, records = _serve(
+        _guarded(PhasedDevice(hold)), [b"a0", b"a1", b"b0", b"b1"],
+        num_workers=2, max_batch_size=2)
+    assert verdicts == [True] * 4
+    assert len(records) == 2
+    first, second = sorted(records, key=lambda r: r["lock"]["acquired"])
+    for rec in records:
+        assert [n for n, _t, _s in rec["phases"]] == SERVED
+        _assert_tiles(rec["phases"])
+    waits = [dict((n, s) for n, _t, s in r["phases"])["lock_wait"]
+             for r in (first, second)]
+    held = first["lock"]["released"] - first["lock"]["acquired"]
+    assert held >= hold
+    assert waits[0] < 0.03
+    assert waits[1] == pytest.approx(held, abs=0.04)
+    # nobody held the lock between the two for longer than a hand-over
+    assert 0 <= second["lock"]["acquired"] - first["lock"]["released"] \
+        < 0.03
+
+
+def test_profiler_annotations_cover_the_single_thread_phases(monkeypatch):
+    entered, exited = [], []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __exit__(self, *exc):
+            exited.append(self.name)
+
+    def annotation(name):
+        entered.append(name)
+        return Ann(name)
+
+    monkeypatch.setattr(tracing, "_annotation", annotation)
+    _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
+    want = ["lock_wait", "host_prep", "device_enqueue", "device_sync",
+            "settle"]
+    assert entered == want and exited == want
+
+
+def test_tracing_imports_and_marks_without_jax():
+    code = (
+        "import sys\n"
+        "from teku_tpu.infra import tracing\n"
+        "m = tracing.new_marks()\n"
+        "m.mark('lock_wait'); m.mark('host_prep'); m.close()\n"
+        "assert [p[0] for p in m.phases] == ['lock_wait', 'host_prep']\n"
+        "assert 'jax' not in sys.modules\n")
+    proc = subprocess.run([sys.executable, "-c", code],
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_real_annotation_is_a_profiler_trace_annotation():
+    import jax
+    ann = tracing._annotation("host_prep")
+    assert isinstance(ann, jax.profiler.TraceAnnotation)
+    ann.__exit__(None, None, None)
+
+
+# --------------------------------------------------------------------------
+# (b) tracing off leaves nothing behind
+# --------------------------------------------------------------------------
+
+def test_disabled_tracing_leaves_no_marks_no_phases():
+    tracing.set_enabled(False)
+    assert tracing.new_marks() is tracing.current_marks()
+    assert not tracing.current_marks()
+    assert tracing.span("lock_wait") is tracing.span("settle")
+    with tracing.dispatch_marks("host_prep") as marks:
+        assert marks is tracing.new_marks()
+        assert tracing.current_marks() is marks
+    before = _stage_counts()
+    verdicts, _t, records = _serve(
+        _guarded(PhasedDevice()), [b"m0", b"m1"], traced=False,
+        num_workers=1)
+    assert verdicts == [True, True]
+    assert len(records) == 1
+    assert not {"phases", "lock", "parent_seq"} & set(records[0])
+    assert records[0]["verdict"] is True
+    assert _stage_counts() == before
+
+
+# --------------------------------------------------------------------------
+# (c) a failed batch's bisection points at its parent
+# --------------------------------------------------------------------------
+
+def test_bisect_dispatches_carry_parent_seq():
+    verdicts, _t, records = _serve(
+        _guarded(PhasedDevice()), [b"g0", b"g1", b"g2", b"bad"],
+        num_workers=1, split_threshold=2)
+    assert verdicts == [True, True, True, False]
+    # the whole, its two halves, the bad half's two single tasks
+    lanes = [r["lanes"] for r in records]
+    assert lanes == [4, 2, 2, 1, 1]
+    whole, good_half, bad_half, single_good, single_bad = records
+    assert whole["parent_seq"] is None
+    assert good_half["parent_seq"] == bad_half["parent_seq"] \
+        == whole["seq"]
+    assert single_good["parent_seq"] == single_bad["parent_seq"] \
+        == bad_half["seq"]
+    # a failed batch settles nothing: its marks close where they stand
+    for rec in (whole, bad_half):
+        assert rec["verdict"] is False
+        assert [n for n, _t, _s in rec["phases"]] == SERVED
+        _assert_tiles(rec["phases"])
+    # a single task (the facade sends it through
+    # `_guarded("fast_aggregate_verify")`) gets the same phases, and a
+    # false single IS settled
+    for rec in (single_good, single_bad):
+        assert [n for n, _t, _s in rec["phases"]] == SERVED
+        _assert_tiles(rec["phases"])
+
+
+# --------------------------------------------------------------------------
+# the async seam, and a direct caller
+# --------------------------------------------------------------------------
+
+def test_async_seam_marks_both_hand_overs():
+    """On the raw provider's async seam a dispatch crosses to a thread
+    twice (begin, then the sync); `device_sync` stays the blocking wait
+    alone."""
+    verdicts, _t, records = _serve(
+        AsyncPhasedDevice(0.02), [b"m0", b"m1"], num_workers=1,
+        overlap=True)
+    assert verdicts == [True, True]
+    assert len(records) == 1
+    names = [n for n, _t, _s in records[0]["phases"]]
+    assert names == ["thread_hop", "host_prep", "device_enqueue",
+                     "return_hop", "thread_hop", "device_sync",
+                     "return_hop", "settle"]
+    _assert_tiles(records[0]["phases"])
+    assert records[0]["lock"] == {}
+    sync = dict((n, s) for n, _t, s in records[0]["phases"])["device_sync"]
+    assert sync < 0.01
+
+
+def test_direct_caller_keeps_the_providers_phases_only():
+    """No service (a warm-up, block import): the provider's own scope
+    opens and closes the marks."""
+    seq0 = dispatchledger.LEDGER.recorded_total
+    with tracing.trace("direct") as tr:
+        assert PhasedDevice().batch_verify([([PK], b"m", b"sig")]) is True
+    assert not tracing.current_marks()
+    rec = [r for r in dispatchledger.LEDGER.snapshot()
+           if r["seq"] > seq0][-1]
+    names = [n for n, _t, _s in rec["phases"]]
+    assert names == ["host_prep", "device_enqueue", "device_sync",
+                     "return_hop"]
+    _assert_tiles(rec["phases"])
+    assert rec["lock"] == {} and rec["parent_seq"] is None
+    assert [s for s, _d in tr.stages] == names
+
+
+def test_a_raising_dispatch_leaves_the_phases_it_reached():
+    """An unguarded provider that raises fails the batch's futures; the
+    traces still say how far the dispatch came."""
+    class Raising(PhasedDevice):
+        def batch_verify(self, triples):
+            tracing.current_marks().mark("host_prep")
+            raise RuntimeError("device fault")
+
+    traces = []
+
+    async def main():
+        bls.set_implementation(Raising())
+        svc = AggregatingSignatureVerificationService(
+            num_workers=1, registry=MetricsRegistry(), name="raise_svc")
+        await svc.start()
+        futs = []
+        for msg in (b"m0", b"m1"):
+            traces.append(tracing.new_trace("phase_task"))
+            with tracing.attach(traces[-1:]):
+                futs.append(svc.verify([PK], msg, b"sig"))
+        out = await asyncio.gather(*futs, return_exceptions=True)
+        await svc.stop()
+        return out
+
+    out = asyncio.run(main())
+    assert all(isinstance(e, RuntimeError) for e in out)
+    for tr in traces:
+        assert [s for s, _d in tr.stages][-3:] == ["dispatch",
+                                                   "thread_hop",
+                                                   "host_prep"]
+
+
+def test_oracle_fallback_is_a_phase_of_the_dispatch():
+    """Breaker open: the oracle serves, and the dispatch's life is
+    still named end to end (no device record to complete)."""
+    guarded = _guarded(PhasedDevice())
+    guarded.breaker.record_failure()
+    guarded.breaker.record_failure()
+    assert guarded.serving == "oracle"
+
+    class Oracle:
+        def batch_verify(self, triples):
+            return True
+
+    guarded.oracle = Oracle()
+    verdicts, traces, records = _serve(guarded, [b"m0", b"m1"],
+                                       num_workers=1)
+    assert verdicts == [True, True] and records == []
+    stages = [s for s, _d in traces[0].stages]
+    assert stages[:2] == ["queue_wait", "assembly"]
+    assert set(stages[2:]) == {"thread_hop", "oracle_execute", "dispatch",
+                               "settle"}
+
+
+# --------------------------------------------------------------------------
+# (e) vocabulary
+# --------------------------------------------------------------------------
+
+def test_new_stage_names_are_declared_and_no_timeline_phase_is_new():
+    assert set(SERVED) | {"oracle_execute", "dispatch", "complete",
+                          "queue_wait", "assembly"} == set(tracing.STAGES)
+    assert tracing._ANNOTATED <= set(tracing.STAGES)
+    # hops cross threads: never a profiler annotation
+    assert not {"thread_hop", "return_hop"} & tracing._ANNOTATED
+    # the marks emit through tracing alone: a served dispatch puts on
+    # the timeline's ring what it did before, the device's busy
+    # interval and the queue's, and none of the new names
+    since = timeline.RING.mark()
+    _serve(_guarded(PhasedDevice()), [b"m0", b"m1"], num_workers=1)
+    emitted = {(e["track"], e["phase"])
+               for e in timeline.RING.snapshot(since_seq=since)}
+    assert emitted == {("device", "busy"), ("worker", "queue_nonempty")}
+    assert not (set(SERVED) - {"host_prep"}) & set(timeline.PHASES)
