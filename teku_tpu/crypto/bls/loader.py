@@ -164,17 +164,24 @@ class GuardedBls12381(BLS12381):
             "guarded BLS dispatches by serving backend and reason",
             labelnames=("backend", "reason"))
         # (provider, device-entry lock) as ONE atomically-swapped pair.
-        # The lock serializes device entry: a timed-out dispatch's
-        # orphaned thread may still be running (e.g. finishing a cold
-        # compile) and the provider's caches are not safe under
-        # concurrent mutation.  A later dispatch blocks there until
-        # the orphan drains; the breaker deadline bounds that wait and
-        # accounts it as a timeout, so a busy device reads as a busy
-        # device.  The mesh-reshape hot-swap replaces the PAIR in one
-        # reference assignment: dispatches that grabbed the old pair
-        # complete on the old plan (their orphans keep the old lock),
-        # new dispatches take the new provider immediately and never
-        # queue behind a wedged orphan.
+        # The lock guards what ENTERS THE DEVICE, and nothing else: a
+        # dispatch's launches and its sync, a `pk_validate` for a key
+        # the cache lacked, the H(m) arena's slots (their state must
+        # follow device order).  A provider's host half
+        # (`prepare_dispatch`: parsing, cache lookups, array packing)
+        # runs before the lock is taken, so one worker packs its batch
+        # while the other one's runs on the chip; the provider's host
+        # caches carry their own locks (`LimitedMap`).  A timed-out
+        # dispatch's orphaned thread may still be on the device (e.g.
+        # finishing a cold compile): it keeps the lock for as long, a
+        # later dispatch preps, then blocks there until the orphan
+        # drains; the breaker deadline bounds prep + wait + device and
+        # accounts the overrun as a timeout, so a busy device reads as
+        # a busy device.  The mesh-reshape hot-swap replaces the PAIR
+        # in one reference assignment: dispatches that grabbed the old
+        # pair prepare for, and complete on, the old plan (their
+        # orphans keep the old lock), new dispatches take the new
+        # provider immediately and never queue behind a wedged orphan.
         self._serving = (device, threading.Lock())
 
     @property
@@ -234,17 +241,36 @@ class GuardedBls12381(BLS12381):
         # lock stay consistent even when a reshape swaps mid-call
         device, lock = self._serving
         device_fn = getattr(device, op)
+        # the provider's own split of the verb into a host half and a
+        # device half; one that has none (the oracle family, a model)
+        # has nothing to run ahead of the lock.  A verb replaced on the
+        # instance (a fault harness wrapping `device.batch_verify`) is
+        # called as it stands: the halves would go around it
+        prepare = None
+        if op not in getattr(device, "__dict__", ()):
+            prepare = getattr(device, "prepare_dispatch", None)
 
         def locked():
             # runs on the breaker's dispatch thread: the hop to it ends
-            # here, and what follows until the lock is ours is the
-            # wait behind the other worker's whole dispatch
+            # with the first mark here.  The host half comes first, off
+            # the lock; what follows until the lock is ours is the wait
+            # behind the other worker's launches and sync
             marks = tracing.current_marks()
+            prepared = None
+            if prepare is not None:
+                marks.mark("host_prep")
+                prepared = prepare(op, *args)
+                if prepared.verdict is not None:
+                    # known on the host (malformed wire, a cached
+                    # key): nothing enters the device
+                    return prepared.verdict
             marks.mark("lock_wait")
             with lock:
                 marks.stamp_lock("acquired")
                 try:
-                    return device_fn(*args)
+                    if prepared is None:
+                        return device_fn(*args)
+                    return device.launch_dispatch(prepared).result()
                 finally:
                     marks.stamp_lock("released")
 

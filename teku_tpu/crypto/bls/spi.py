@@ -40,6 +40,22 @@ class ResolvedHandle:
         return self._verdict
 
 
+class PreparedDispatch:
+    """What a provider's host half (``prepare_dispatch(op, *args)``)
+    hands its device half (``launch_dispatch(prepared)``, which returns
+    a handle with ``.result()``).  The split is optional: a provider
+    whose verbs have no host work worth separating (the oracle, a
+    model) offers neither method, and its whole verb is the device
+    half.  ``verdict`` is not None when the host half already knows
+    the answer: nothing will enter the device, so the guarded provider
+    (crypto/bls/loader.py) does not take the device-entry lock."""
+
+    __slots__ = ("verdict",)
+
+    def __init__(self, verdict: Optional[bool] = None):
+        self.verdict = verdict
+
+
 class BLS12381(abc.ABC):
     """Provider interface: everything the node needs from a BLS library."""
 

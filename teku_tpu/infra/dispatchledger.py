@@ -12,7 +12,7 @@ logs, gauges, and WARNs.
 
 This is the ordered record: a process-global bounded ring of
 structured per-dispatch records, populated by
-``ops/provider.py:_begin_dispatch`` (decision context) and completed
+``ops/provider.py:_launch`` (decision context) and completed
 by ``_DispatchHandle.result()`` (sync duration, overlap-corrected
 device time, verdict).  Each record captures:
 
@@ -32,12 +32,18 @@ device time, verdict).  Each record captures:
   ``compile.programs``: one load record per AOT program resolved
   inside that enqueue (``infra/aotstore.py`` ``load_records``);
 - ``phases``: the dispatch's whole life as ``[name, t_mono,
-  seconds]`` in order (thread_hop, lock_wait, host_prep,
+  seconds]`` in order (thread_hop, host_prep, lock_wait,
   device_enqueue, device_sync, return_hop, settle: the marks of
   ``infra/tracing.py``, tiling first mark to last), ``lock``:
   ``{acquired, released}`` of the guarded provider's device-entry
   lock, and ``parent_seq``: the failed batch a bisect dispatch came
   from (absent with tracing off);
+- ``prep``: where the dispatch's host prep ran: ``outside_lock``
+  (all of it in the provider's host half, before the device-entry
+  lock was taken) or ``under_lock`` with ``prep_reason`` (``pk_miss``:
+  a public key the cache lacked was validated on the device;
+  ``arena``: the H(m) arena's slots follow device order), mirrored by
+  ``bls_dispatch_prep_total{prep,reason}``;
 - the admission context the service annotated (plan mode, brownout
   level, verify-class mix, flush-failsafe firing) via the
   ``annotate()`` ContextVar — ``asyncio.to_thread`` copies the
@@ -104,7 +110,7 @@ def annotate(**fields):
     """Bind dispatch-record annotations to the current context for the
     duration of the block (the batching service wraps each dispatch
     with its plan mode / brownout level / class mix; the provider's
-    ``_begin_dispatch`` merges ``current_annotations()`` into the
+    ``_launch`` merges ``current_annotations()`` into the
     record it opens).  ``asyncio.to_thread`` copies the context, so
     the worker-thread dispatch still sees the annotations."""
     token = _ANNOTATIONS.set({**_ANNOTATIONS.get(), **fields})
@@ -184,7 +190,7 @@ class DispatchLedger:
     # ------------------------------------------------------------------
     def record(self, rec: dict) -> dict:
         """Append one COMPLETED dispatch record (the provider assembles
-        it across _begin_dispatch and the handle's result()) and update
+        it across _launch and the handle's result()) and update
         the derived metrics.  Returns the record with its seq."""
         waste = rec.get("waste") or {}
         with self._lock:

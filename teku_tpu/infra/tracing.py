@@ -65,10 +65,13 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 # the root span's end-to-end total.  Per dispatch, in order, the
 # phases that `DispatchMarks` tiles from the instant the service has
 # its batch to the instant its last future is settled:
-#   thread_hop      service hands over → the dispatch thread stands
-#                   before the lock (`to_thread` pool wait, thread start)
+#   thread_hop      service hands over → the dispatch thread runs
+#                   (`to_thread` pool wait, thread start)
+#   host_prep       wire parse, key lookup, array packing: the
+#                   provider's host half, off the device-entry lock
+#                   (a second, short one under the lock where a key
+#                   had to be validated or the H(m) arena looked up)
 #   lock_wait       blocked on the serving pair's device-entry lock
-#   host_prep       wire parse, key lookup, array packing
 #   device_enqueue  the async launches (plus XLA compile or program
 #                   load on a first shape)
 #   device_sync     only the blocking wait at the handle's result()
@@ -78,7 +81,7 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 # (the parent of thread_hop .. return_hop in the span tree);
 # `oracle_execute` is a guarded call the oracle served for the device.
 STAGES = ("queue_wait", "assembly", "dispatch", "thread_hop",
-          "lock_wait", "host_prep", "device_enqueue", "device_sync",
+          "host_prep", "lock_wait", "device_enqueue", "device_sync",
           "return_hop", "settle", "oracle_execute", "complete")
 
 # phases that begin and end on ONE thread: entered as profiler

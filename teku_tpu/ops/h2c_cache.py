@@ -147,7 +147,7 @@ class H2cPointCache:
             # an over-capacity insert would evict slots assigned
             # earlier in THIS call (duplicate scatter indices — one
             # row wins) and gather wrong points; callers bypass the
-            # cache instead (provider._hm_host_plan)
+            # cache instead (provider._hm_host)
             raise ValueError(
                 f"insert of {k} points exceeds arena capacity "
                 f"{self.capacity}")

@@ -375,6 +375,17 @@ def scalar_from_uint64(vals):
     return ((vals[..., None] >> shifts) & 1).astype(jnp.int64)
 
 
+def scalar_bits_np(vals) -> np.ndarray:
+    """`scalar_from_uint64` on the host: the same (..., 64) int64 bits,
+    MSB first, with no device program and no read-back.  The provider's
+    host half draws a dispatch's multipliers with this one (it may run
+    while another dispatch owns the device, and the chip serves its
+    queue in order: a read-back would wait out that whole dispatch)."""
+    vals = np.asarray(vals, dtype=np.uint64)
+    shifts = np.arange(63, -1, -1, dtype=np.uint64)
+    return ((vals[..., None] >> shifts) & np.uint64(1)).astype(np.int64)
+
+
 # --------------------------------------------------------------------------
 # Endomorphisms + fast subgroup checks
 # --------------------------------------------------------------------------

@@ -9,12 +9,15 @@ non-batch operations (key generation, signing), mirroring how the
 reference keeps BlstLoader's graceful-degradation path.
 
 Host/device split:
-- host: wire-format parsing (flag bits, x < P), SHA-256 message
-  expansion, pubkey cache bookkeeping, random multipliers — all
-  marshaling vectorized with numpy (no per-lane Python bigint work on
-  the hot path);
-- device: pubkey decompression + subgroup checks for cache misses (one
-  batched dispatch), and the whole verification pipeline — per-lane
+- host (`prepare_dispatch`): wire-format parsing (flag bits, x < P),
+  SHA-256 message expansion, pubkey cache lookups, random multipliers —
+  all marshaling vectorized with numpy (no per-lane Python bigint work
+  on the hot path), and NOTHING of it launches a device program or
+  reads one back: the guarded provider runs this half before it takes
+  its device-entry lock, while another dispatch owns the chip;
+- device (`launch_dispatch`): pubkey decompression + subgroup checks
+  for cache misses (one batched dispatch), the H(m) arena's lookups,
+  insert and gather, and the whole verification pipeline — per-lane
   multi-key aggregation, hash-to-G2, scalar muls, Miller loops, final
   exponentiation — as a chain of staged jitted programs per padded
   batch-shape bucket.
@@ -24,9 +27,8 @@ batch's UNIQUE messages, backed by a bounded device-resident H(m)
 point cache (ops/h2c_cache.py — steady-state committee gossip pays h2c
 once per distinct AttestationData, a fully-warm batch dispatches no
 h2c at all), and the Miller loops fold to unique width via pairing
-bilinearity (ops/verify.py:stage_group).  begin_batch_verify exposes
-the async seam the batching service uses to overlap host_prep of the
-next batch with the in-flight device execute.
+bilinearity (ops/verify.py:stage_group).  begin_batch_verify is the
+unguarded provider's async seam (both halves now, the sync later).
 
 MESH: constructed with mesh=..., dispatches shard GROUP-ALIGNED
 across the chips (teku_tpu/parallel.GroupShardedVerifier): whole
@@ -46,6 +48,7 @@ import os
 import secrets
 import threading
 import time
+import types
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -62,7 +65,7 @@ from ..infra.metrics import GLOBAL_REGISTRY
 from ..crypto.bls.constants import P, R
 from ..crypto.bls.pure_impl import PureBls12381
 from ..crypto.bls.spi import (BLS12381, BatchSemiAggregate,
-                              ResolvedHandle)
+                              PreparedDispatch, ResolvedHandle)
 from . import h2c_cache as HC
 from . import limbs as fp
 from . import msm
@@ -133,6 +136,21 @@ _M_MESH_DISPATCH = GLOBAL_REGISTRY.labeled_counter(
     "verify dispatches served by the group-aligned sharded mesh "
     "kernel, by mesh device count",
     labelnames=("devices",))
+
+
+# Where a dispatch's host prep ran: all of it in the host half, which
+# the guarded provider runs BEFORE it takes the device-entry lock (the
+# other worker's dispatch runs on the chip meanwhile), or part of it in
+# the device half, under that lock, and why: a public key the cache
+# lacked (`pk_validate` enters the device) or the H(m) arena (slot
+# state follows device order).  The ledger record carries the same
+# (`prep`, `prep_reason`).
+_M_PREP = GLOBAL_REGISTRY.labeled_counter(
+    "bls_dispatch_prep_total",
+    "verify dispatches by where their host prep ran "
+    "(outside_lock|under_lock) and why it stayed under the "
+    "device-entry lock (none|pk_miss|arena|pk_miss+arena)",
+    labelnames=("prep", "reason"))
 
 
 def _dedup_ratio() -> float:
@@ -212,7 +230,7 @@ class _DispatchHandle:
     """An in-flight batch dispatch.
 
     The device work was enqueued via JAX async dispatch when this was
-    created (the `device_enqueue` span, recorded by _begin_dispatch,
+    created (the `device_enqueue` span, recorded by _launch,
     covers the launch calls plus any XLA compile a first shape pays);
     result() forces the verdict arrays (the only host/device sync
     point) — callers may do arbitrary host work (e.g. host_prep of the
@@ -243,7 +261,7 @@ class _DispatchHandle:
         # blocks: lane_sel maps original lane i -> its slot in the
         # dispatched layout, so the verdict reads the right lanes
         self._lane_sel = lane_sel
-        # the open dispatch-ledger record _begin_dispatch assembled:
+        # the open dispatch-ledger record _launch assembled:
         # result() completes it (sync duration, overlap-corrected
         # device time, verdict) and publishes it into the ring
         self._rec = rec
@@ -318,6 +336,71 @@ class _DispatchHandle:
                 dispatchledger.record(self._rec)
                 self._recorded = True
         return self._verdict
+
+
+class _Packed(types.SimpleNamespace):
+    """One dispatch's host half (`JaxBls12381._pack`): the launches'
+    arguments as numpy arrays, the shape decisions (`kmax`, `padded`,
+    rows and buckets, the mesh `plan`, `msm_path`) and what the ledger
+    record says of them.  Nothing in it has touched the device.
+    `digests` is None with the H(m) arena off or bypassed (`draws` is
+    then the padded h2c input), else the rows' SHA-256 digests beside
+    their per-row `draws`."""
+
+    def fill_keys(self, entries: dict) -> bool:
+        """Pack the keys the host half's cache lookup missed, now that
+        the device has validated them; False when one is not a valid
+        key (the batch is then false without a dispatch)."""
+        for p, j, pk in self.pk_pending:
+            entry = entries[pk]
+            if entry[0] != "ok":
+                return False
+            self.pk_xs[p, j], self.pk_ys[p, j] = entry[1], entry[2]
+        self.pk_pending = ()
+        return True
+
+
+class _Prepared(PreparedDispatch):
+    """`prepare_dispatch`'s result: a verdict the host half already
+    knows (malformed input, an empty batch, a cached key), or the
+    packed dispatches (one, unless the batch exceeds `max_batch`) with
+    `pk_miss`, the keys the cache lacked ({pk: parsed wire}); `key`
+    names the one key a `public_key_is_valid` asks about."""
+
+    __slots__ = ("packed", "pk_miss", "key")
+
+    def __init__(self, verdict: Optional[bool] = None, packed=(),
+                 pk_miss: Optional[dict] = None,
+                 key: Optional[bytes] = None):
+        super().__init__(verdict)
+        self.packed = packed
+        self.pk_miss = pk_miss or {}
+        self.key = key
+
+
+def _as_triples(op: str, args: tuple):
+    """A verify verb's arguments as `(triples, randomize)`: every verb
+    is a batch of (public keys, message, signature) lanes, random
+    multipliers for `batch_verify` alone.  `(None, False)` when the
+    arguments cannot verify."""
+    if op == "batch_verify":
+        (triples,) = args
+        return triples, True
+    if op == "verify":
+        public_key, message, signature = args
+        return [([public_key], message, signature)], False
+    if op == "fast_aggregate_verify":
+        return [args], False
+    if op == "aggregate_verify":
+        public_keys, messages, signature = args
+        if not public_keys or len(public_keys) != len(messages):
+            return None, False
+        # prod_i e(pk_i, H(m_i)) == e(g1, sig): the r=1 batch with the
+        # signature attached to lane 0 and infinity signatures elsewhere.
+        return [([pk], msg, signature if i == 0 else _G2_INF)
+                for i, (pk, msg) in enumerate(zip(public_keys,
+                                                  messages))], False
+    raise ValueError(f"not a verify verb: {op}")
 
 
 def _parse_g2_wire(sig: bytes):
@@ -403,7 +486,7 @@ class JaxBls12381(BLS12381):
         self._group_cap = env_int("TEKU_TPU_H2C_GROUP_CAP", 32, lo=1)
         # staged dispatch: small programs instead of one monolith whose
         # TPU compile is unbounded (ops/verify.py staged_jits); h2c
-        # runs separately over unique messages (see _begin_dispatch)
+        # runs separately over unique messages (see _launch)
         self._pk_validate_jit = aotstore.wrap(
             f"pk_validate:{mxu.resolve()}",
             jax.jit(self._pk_validate_kernel))
@@ -457,21 +540,12 @@ class JaxBls12381(BLS12381):
         # Z == 1 by construction: (X, Y) are already the affine coords
         return ok, fp.compress(pt[0]), fp.compress(pt[1])
 
-    def _resolve_pks(self, all_pks: Sequence[bytes]) -> dict:
-        """Resolve every requested pubkey (cache-filling, one device
-        dispatch for the misses) and return {pk: entry}.
-
-        The cache is a bounded LRU (pubkey bytes can be
-        attacker-influenced, so an unbounded cache — including "bad"
-        entries — is a slow memory-growth vector); eviction is one cold
-        entry at a time, counted in bls_cache_evictions_total{cache="pk"}.
-        Callers MUST read entries from the returned snapshot, never
-        re-read the shared cache afterwards: at the bound, this batch's
-        own inserts (or a concurrent worker's) may evict an entry
-        resolved here, and a valid signature must not verify False
-        because its pubkey went cold."""
-        resolved = {}
-        miss = {}
+    def _lookup_pks(self, all_pks: Sequence[bytes], resolved: dict,
+                    miss: dict) -> None:
+        """The host half of pubkey resolution: every requested key
+        goes into `resolved` ({pk: entry}, from the cache or rejected
+        on its wire form) or into `miss` ({pk: parsed wire}, for
+        `_validate_pks`).  Touches no device."""
         for pk in all_pks:
             if pk in resolved or pk in miss:
                 continue
@@ -485,24 +559,56 @@ class JaxBls12381(BLS12381):
                 self._pk_cache.put(pk, ("bad",))
             else:
                 miss[pk] = wire
-        miss = list(miss.items())
-        if not miss:
+
+    def _validate_pks(self, miss: dict) -> dict:
+        """The device half: ONE `pk_validate` dispatch over the keys of
+        `miss` the cache still lacks (another dispatch may have
+        validated them since the lookup), cache-filling; returns
+        {pk: entry} for every key of `miss`."""
+        resolved = {}
+        todo = []
+        for pk, wire in miss.items():
+            entry = self._pk_cache.get(pk)
+            if entry is not None:
+                resolved[pk] = entry
+            else:
+                todo.append((pk, wire))
+        if not todo:
             return resolved
         # floor of 16 keeps the validation program at very few distinct
         # shapes (same compile-cost argument as the verify min_bucket)
-        n = SS.pk_validate_bucket(len(miss))
+        n = SS.pk_validate_bucket(len(todo))
         xs = np.zeros((n, fp.L), dtype=np.int64)
         large = np.zeros(n, dtype=bool)
-        for i, (_, (x, lg, _inf)) in enumerate(miss):
+        for i, (_, (x, lg, _inf)) in enumerate(todo):
             xs[i] = fp.int_to_limbs(x)
             large[i] = lg
         ok, gx, gy = self._pk_validate_jit(xs, large)
         ok = np.asarray(ok)
         gx, gy = np.asarray(gx), np.asarray(gy)
-        for i, (pk, _) in enumerate(miss):
+        for i, (pk, _) in enumerate(todo):
             entry = ("ok", gx[i], gy[i]) if ok[i] else ("bad",)
             resolved[pk] = entry
             self._pk_cache.put(pk, entry)
+        return resolved
+
+    def _resolve_pks(self, all_pks: Sequence[bytes]) -> dict:
+        """Resolve every requested pubkey (cache-filling, one device
+        dispatch for the misses) and return {pk: entry}.
+
+        The cache is a bounded LRU (pubkey bytes can be
+        attacker-influenced, so an unbounded cache — including "bad"
+        entries — is a slow memory-growth vector); eviction is one cold
+        entry at a time, counted in bls_cache_evictions_total{cache="pk"}.
+        Callers MUST read entries from the returned snapshot, never
+        re-read the shared cache afterwards: at the bound, this batch's
+        own inserts (or a concurrent worker's) may evict an entry
+        resolved here, and a valid signature must not verify False
+        because its pubkey went cold."""
+        resolved, miss = {}, {}
+        self._lookup_pks(all_pks, resolved, miss)
+        if miss:
+            resolved.update(self._validate_pks(miss))
         return resolved
 
     def public_key_is_valid(self, public_key: bytes) -> bool:
@@ -521,79 +627,147 @@ class JaxBls12381(BLS12381):
         return hit
 
     # ------------------------------------------------------------------
-    # Verification API — everything lands in the batched kernel
+    # Verification API — everything lands in the batched kernel, in two
+    # halves: `prepare_dispatch` (host only) and `launch_dispatch`
+    # (whatever enters the device).  An unguarded caller runs them back
+    # to back; the guarded provider (crypto/bls/loader.py) takes its
+    # device-entry lock between the two, so one worker packs its batch
+    # while the other one's runs on the chip.
     # ------------------------------------------------------------------
-    def prepare_batch_verify(
-        self, triple: Tuple[Sequence[bytes], bytes, bytes]
-    ) -> Optional[BatchSemiAggregate]:
+    def _host_semi(self, triple: Tuple[Sequence[bytes], bytes, bytes],
+                   miss: dict) -> Optional[_Semi]:
+        """`prepare_batch_verify` from the pubkey cache alone: a key
+        the cache lacks stays in the semi as its BYTES, its parsed wire
+        goes into `miss`, and the device half resolves both."""
         public_keys, message, signature = triple
         if not public_keys or len(public_keys) > self.max_keys_per_lane:
             return None
-        resolved = self._resolve_pks(public_keys)
+        resolved: dict = {}
+        self._lookup_pks(public_keys, resolved, miss)
         points = []
         for pk in public_keys:
-            entry = resolved[pk]
-            if entry[0] != "ok":
+            entry = resolved.get(pk)
+            if entry is None:
+                points.append(pk)
+            elif entry[0] != "ok":
                 return None
-            points.append((entry[1], entry[2]))
+            else:
+                points.append((entry[1], entry[2]))
         sig = _parse_g2_wire(signature)
         if sig is None:
             return None
         return _Semi(points, message, *sig)
 
+    def prepare_batch_verify(
+        self, triple: Tuple[Sequence[bytes], bytes, bytes]
+    ) -> Optional[BatchSemiAggregate]:
+        miss: dict = {}
+        semi = self._host_semi(triple, miss)
+        if semi is None or not miss:
+            return semi
+        entries = self._validate_pks(miss)
+        for j, pt in enumerate(semi.pk_limbs):
+            if isinstance(pt, bytes):
+                entry = entries[pt]
+                if entry[0] != "ok":
+                    return None
+                semi.pk_limbs[j] = (entry[1], entry[2])
+        return semi
+
+    def prepare_dispatch(self, op: str, *args) -> "_Prepared":
+        """The HOST half of the verb `op`: wire parse, pubkey-cache
+        lookups, array packing, the random multipliers' bits or digits,
+        message digests and hash-to-field draws.  It launches nothing
+        on the device and reads nothing back from it, so it may run
+        while another dispatch owns the device.  What a batch needs of
+        the device before its launches (validating a key the cache
+        lacks, the H(m) arena's slots) is left to `launch_dispatch`,
+        decided by what the input shows."""
+        tracing.current_marks().mark("host_prep")
+        if op == "public_key_is_valid":
+            (public_key,) = args
+            resolved: dict = {}
+            miss: dict = {}
+            self._lookup_pks([public_key], resolved, miss)
+            if miss:
+                return _Prepared(pk_miss=miss, key=public_key)
+            return _Prepared(verdict=resolved[public_key][0] == "ok")
+        triples, randomize = _as_triples(op, args)
+        if triples is None:
+            return _Prepared(verdict=False)
+        miss = {}
+        semis = [self._host_semi(t, miss) for t in triples]
+        return self._prepare_semis(semis, miss, randomize)
+
+    def _prepare_semis(self, semis: Sequence[Optional[_Semi]],
+                       miss: dict, randomize: bool) -> "_Prepared":
+        if any(s is None for s in semis):
+            return _Prepared(verdict=False)
+        if not semis:
+            return _Prepared(verdict=True)
+        # oversized batches split; all chunks must pass
+        return _Prepared(
+            packed=[self._pack(semis[i:i + self.max_batch], randomize)
+                    for i in range(0, len(semis), self.max_batch)],
+            pk_miss=miss)
+
+    def launch_dispatch(self, prepared: "_Prepared"):
+        """The DEVICE half: finishes what `prepare_dispatch` had to
+        leave (under the guard's lock, because it enters the device or
+        must follow device order), opens the ledger record, launches
+        the staged programs and returns the handle whose `result()`
+        syncs."""
+        if prepared.verdict is not None:
+            return ResolvedHandle(prepared.verdict)
+        t_prep0 = None
+        if prepared.pk_miss:
+            # a key the cache lacked: `pk_validate` runs on the device
+            t_prep0 = tracing.current_marks().mark("host_prep")
+            entries = self._validate_pks(prepared.pk_miss)
+            if prepared.key is not None:
+                return ResolvedHandle(entries[prepared.key][0] == "ok")
+            if not all(p.fill_keys(entries) for p in prepared.packed):
+                return ResolvedHandle(False)
+        if len(prepared.packed) == 1:
+            return self._launch(prepared.packed[0], t_prep0)
+        return ResolvedHandle(all(
+            self._launch(p, t_prep0 if i == 0 else None).result()
+            for i, p in enumerate(prepared.packed)))
+
+    def _run(self, op: str, *args) -> bool:
+        # a direct caller: both halves back to back, under the
+        # service's marks when it marks one, else this block's own
+        with tracing.dispatch_marks("host_prep"):
+            return self.launch_dispatch(
+                self.prepare_dispatch(op, *args)).result()
+
     def complete_batch_verify(
         self, semi_aggregates: Sequence[Optional[BatchSemiAggregate]]
     ) -> bool:
-        if any(sa is None for sa in semi_aggregates):
-            return False
-        if not semi_aggregates:
-            return True
-        semis: List[_Semi] = list(semi_aggregates)
-        if len(semis) > self.max_batch:
-            # split oversized batches; all chunks must pass
-            return all(
-                self.complete_batch_verify(semis[i:i + self.max_batch])
-                for i in range(0, len(semis), self.max_batch))
-        return self._dispatch(semis, randomize=True)
+        with tracing.dispatch_marks("host_prep"):
+            return self.launch_dispatch(self._prepare_semis(
+                list(semi_aggregates), {}, randomize=True)).result()
 
     def batch_verify(
         self, triples: Sequence[Tuple[Sequence[bytes], bytes, bytes]],
     ) -> bool:
-        # wire parse + pk-cache resolve is host work too: `host_prep`
-        # opens here and runs on through _begin_dispatch's array packing
-        with tracing.dispatch_marks("host_prep"):
-            semis = [self.prepare_batch_verify(t) for t in triples]
-            return self.complete_batch_verify(semis)
+        return self._run("batch_verify", triples)
 
     def verify(self, public_key: bytes, message: bytes,
                signature: bytes) -> bool:
-        return self.fast_aggregate_verify([public_key], message, signature)
+        return self._run("verify", public_key, message, signature)
 
     def fast_aggregate_verify(self, public_keys: Sequence[bytes],
                               message: bytes, signature: bytes) -> bool:
         # the service's single-task dispatches (a bisection's last
         # steps) come this way: the same phases as a batch
-        with tracing.dispatch_marks("host_prep"):
-            semi = self.prepare_batch_verify(
-                (public_keys, message, signature))
-            if semi is None:
-                return False
-            return self._dispatch([semi], randomize=False)
+        return self._run("fast_aggregate_verify", public_keys, message,
+                         signature)
 
     def aggregate_verify(self, public_keys: Sequence[bytes],
                          messages: Sequence[bytes], signature: bytes) -> bool:
-        if not public_keys or len(public_keys) != len(messages):
-            return False
-        # prod_i e(pk_i, H(m_i)) == e(g1, sig): the r=1 batch with the
-        # signature attached to lane 0 and infinity signatures elsewhere.
-        semis = []
-        for i, (pk, msg) in enumerate(zip(public_keys, messages)):
-            sig = signature if i == 0 else _G2_INF
-            semi = self.prepare_batch_verify(([pk], msg, sig))
-            if semi is None:
-                return False
-            semis.append(semi)
-        return self._dispatch(semis, randomize=False)
+        return self._run("aggregate_verify", public_keys, messages,
+                         signature)
 
     # ------------------------------------------------------------------
     # Dedup-aware dispatch: h2c over unique messages + async handle
@@ -610,27 +784,27 @@ class JaxBls12381(BLS12381):
             return None
         # the caller's marks (the service's `_begin`): nothing is
         # scoped here, the handle outlives this call
-        marks = tracing.current_marks()
-        marks.mark("host_prep")
-        semis = [self.prepare_batch_verify(t) for t in triples]
-        if any(s is None for s in semis):
-            return ResolvedHandle(False)
-        if not semis:
-            return ResolvedHandle(True)
-        handle = self._begin_dispatch(semis, randomize=True)
+        handle = self.launch_dispatch(
+            self.prepare_dispatch("batch_verify", triples))
         # in flight: the caller gets its thread back and syncs later
-        marks.mark("return_hop")
+        tracing.current_marks().mark("return_hop")
         return handle
 
-    def _uniq_draws(self, msgs: List[bytes], bucket: int):
-        """Host hash_to_field draws for `msgs`, padded to `bucket`."""
+    @staticmethod
+    def _pad_draws(draws: Sequence[tuple], bucket: int):
+        """Per-message hash_to_field draws as the h2c stage's padded
+        (bucket, L) arrays."""
         u0c0 = np.zeros((bucket, fp.L), dtype=np.int64)
         u0c1 = np.zeros((bucket, fp.L), dtype=np.int64)
         u1c0 = np.zeros((bucket, fp.L), dtype=np.int64)
         u1c1 = np.zeros((bucket, fp.L), dtype=np.int64)
-        for j, m in enumerate(msgs):
-            u0c0[j], u0c1[j], u1c0[j], u1c1[j] = self._u_draws(m)
+        for j, d in enumerate(draws):
+            u0c0[j], u0c1[j], u1c0[j], u1c1[j] = d
         return (u0c0, u0c1), (u1c0, u1c1)
+
+    def _uniq_draws(self, msgs: List[bytes], bucket: int):
+        """Host hash_to_field draws for `msgs`, padded to `bucket`."""
+        return self._pad_draws([self._u_draws(m) for m in msgs], bucket)
 
     def _h2c_dispatch(self, draws):
         """ONE hash-to-curve device dispatch over precomputed draws."""
@@ -639,36 +813,42 @@ class JaxBls12381(BLS12381):
         _M_H2C_DISPATCH.inc()
         return V.staged_jits()["h2c"](u0, u1)
 
-    def _hm_host_plan(self, uniq_msgs: List[bytes], u_bucket: int):
-        """Host half of H(m) resolution — runs inside the host_prep
-        span: message digests, arena lookups, and the hash_to_field
-        draws for whatever still needs an h2c dispatch (so the SHA-256
-        and draw cost never pollutes the device-span attribution).
+    def _hm_host(self, row_msgs: List[bytes], u_bucket: int):
+        """Host half of H(m) resolution: `(digests, draws)`.
 
-        The cache is bypassed when the batch carries more unique
-        messages than the whole arena holds: inserting more rows than
-        capacity would recycle slots assigned earlier in the same call
-        and serve the wrong point."""
+        With the arena off, or bypassed because the batch carries more
+        unique messages than the whole arena holds (inserting more rows
+        than capacity would recycle slots assigned earlier in the same
+        call and serve the wrong point), `digests` is None and `draws`
+        the padded h2c input.  Otherwise the SHA-256 digests and the
+        per-row draws, for `_hm_arena_plan`: which of them need an h2c
+        dispatch is slot state, known only in device order."""
         cache = self._h2c_cache
-        if not cache.enabled or len(uniq_msgs) > cache.capacity:
-            return None, None, None, self._uniq_draws(uniq_msgs,
-                                                      u_bucket)
-        digests = [hashlib.sha256(m).digest() for m in uniq_msgs]
+        if not cache.enabled or len(row_msgs) > cache.capacity:
+            return None, self._uniq_draws(row_msgs, u_bucket)
+        return ([hashlib.sha256(m).digest() for m in row_msgs],
+                [self._u_draws(m) for m in row_msgs])
+
+    def _hm_arena_plan(self, digests, draws, u_bucket: int):
+        """The arena's lookups, in device order (the caller holds the
+        device-entry lock where there is one): a slot read here must
+        still hold its point when this dispatch's gather runs, and
+        only the dispatches launched before it may recycle slots."""
         slots = np.zeros(u_bucket, dtype=np.int64)
         missing = []
         for j, dg in enumerate(digests):
-            slot = cache.lookup(dg)
+            slot = self._h2c_cache.lookup(dg)
             if slot is None:
                 missing.append(j)
             else:
                 slots[j] = slot
-        draws = None
+        miss_draws = None
         if missing:
             mb = SS.h2c_miss_bucket(len(missing),
                                     self._h2c_min_bucket)
-            draws = self._uniq_draws([uniq_msgs[j] for j in missing],
-                                     mb)
-        return slots, missing, digests, draws
+            miss_draws = self._pad_draws([draws[j] for j in missing],
+                                         mb)
+        return slots, missing, digests, miss_draws
 
     def _hm_device(self, plan):
         """Device half of H(m) resolution for a deduped batch.
@@ -688,23 +868,11 @@ class JaxBls12381(BLS12381):
             slots[np.asarray(missing)] = new_slots
         return self._h2c_cache.gather(slots)
 
-    def _dispatch(self, semis: List[_Semi], randomize: bool) -> bool:
-        with tracing.dispatch_marks("host_prep"):
-            return self._begin_dispatch(semis, randomize).result()
-
-    def _begin_dispatch(self, semis: List[_Semi],
-                        randomize: bool) -> "_DispatchHandle":
-        # `bls.dispatch` fault site: the supervisor/breaker tests prove
-        # hang/exception containment at the REAL device-dispatch seam
-        faults.check("bls.dispatch")
+    def _pack(self, semis: Sequence[_Semi],
+              randomize: bool) -> "_Packed":
+        """One dispatch's host half: numpy and plain Python alone."""
         n = len(semis)
-        self.dispatch_count += 1
-        self.lanes_dispatched += n
-        # the dispatch's marks: the service's when it marks one (then
-        # `host_prep` has been open since the wire parse), else the
-        # scope `_dispatch` opened
-        marks = tracing.current_marks()
-        t_hp0 = marks.mark("host_prep")
+        t_hp0 = tracing.current_marks().mark("host_prep")
         kmax = SS.kmax_bucket(max(len(s.pk_limbs) for s in semis))
         # unique-message index + per-message lane groups: h2c AND
         # the Miller loops run at unique width (stage_group folds a
@@ -753,12 +921,17 @@ class JaxBls12381(BLS12381):
         s_large = np.zeros(padded, dtype=bool)
         s_inf = np.zeros(padded, dtype=bool)
         lane_valid = np.zeros(padded, dtype=bool)
+        # (lane, key slot, pk bytes) of the keys the cache lacked: the
+        # device half validates them and `fill_keys` packs them
+        pk_pending = []
         for i, s in enumerate(semis):
             p = i if lane_pos is None else int(lane_pos[i])
-            for j, (x, y) in enumerate(s.pk_limbs):
-                pk_xs[p, j] = x
-                pk_ys[p, j] = y
+            for j, pt in enumerate(s.pk_limbs):
                 pk_present[p, j] = True
+                if isinstance(pt, bytes):
+                    pk_pending.append((p, j, pt))
+                else:
+                    pk_xs[p, j], pk_ys[p, j] = pt
             sig_bytes[p] = s.sig_x_bytes
             s_large[p] = s.sig_large
             s_inf[p] = s.sig_inf
@@ -797,7 +970,6 @@ class JaxBls12381(BLS12381):
         # remains the LEGACY lane-sharded kernel's always-ladder
         # contract and is not used here
         msm_path, msm_why = msm.explain(lanes=n, rows=len(rows))
-        r_bits = glv_digits = None
         if randomize:
             # one os-entropy draw for the whole batch (the
             # reference uses SecureRandom per multiplier,
@@ -809,23 +981,66 @@ class JaxBls12381(BLS12381):
             raw = np.frombuffer(secrets.token_bytes(8 * padded),
                                 dtype=np.uint64).copy()
             if msm_path == "pippenger":
-                glv_digits = msm.glv_digits_np(
+                scalars = msm.glv_digits_np(
                     *msm.glv_sample_from_uint64(raw))
             else:
                 raw[raw == 0] = 1
-                r_bits = np.asarray(PT.scalar_from_uint64(raw))
+                scalars = PT.scalar_bits_np(raw)
         elif msm_path == "pippenger":
             # r = 1 exactly: (k1, k2) = (1, 0)
-            glv_digits = msm.glv_digits_np(
+            scalars = msm.glv_digits_np(
                 np.ones(padded, dtype=np.uint64),
                 np.zeros(padded, dtype=np.uint64))
         else:
-            r_bits = np.asarray(PT.scalar_from_uint64(
-                np.ones(padded, dtype=np.uint64)))
-        # H(m) host half (digests + cache lookups + field draws)
-        # belongs to host_prep; only the dispatch/gather below is
-        # device work
-        hm_plan = self._hm_host_plan(row_msgs, u_hm)
+            scalars = PT.scalar_bits_np(
+                np.ones(padded, dtype=np.uint64))
+        digests, draws = self._hm_host(row_msgs, u_hm)
+        # the timeline's host-prep interval: the serial host-side term
+        # host_prep_serial_share is computed from (subtracting any
+        # overlap with device-busy intervals)
+        timeline.interval(
+            "worker", "host_prep", time.perf_counter() - t_hp0,
+            t_mono=t_hp0, trace_id=tracing.current_trace_id())
+        return _Packed(
+            n=n, randomize=randomize, kmax=kmax,
+            n_unique=len(uniq_msgs), n_rows=len(rows),
+            g_bucket=g_bucket, u_hm=u_hm, plan=plan, padded=padded,
+            u_total=u_total, lane_pos=lane_pos, pk_xs=pk_xs,
+            pk_ys=pk_ys, pk_present=pk_present, pk_pending=pk_pending,
+            sx=(sx0, sx1), s_large=s_large, s_inf=s_inf,
+            lane_valid=lane_valid, group_idx=group_idx,
+            group_present=group_present, row_gather=row_gather,
+            msm_path=msm_path, msm_why=msm_why, scalars=scalars,
+            digests=digests, draws=draws)
+
+    def _launch(self, pack: "_Packed",
+                t_prep0: Optional[float] = None) -> "_DispatchHandle":
+        """One packed dispatch onto the device.  `t_prep0`: when the
+        device half's own prep began (a key validated under the lock),
+        None when the host half had done it all."""
+        # `bls.dispatch` fault site: the supervisor/breaker tests prove
+        # hang/exception containment at the REAL device-dispatch seam
+        faults.check("bls.dispatch")
+        n = pack.n
+        self.dispatch_count += 1
+        self.lanes_dispatched += n
+        # the dispatch's marks: the service's when it marks one, else
+        # the scope `_run` opened
+        marks = tracing.current_marks()
+        # where this dispatch's prep ran, for the ledger and /metrics:
+        # all of it in the host half (which the guard runs before it
+        # takes the device-entry lock), or part of it here, and why
+        reasons = ["pk_miss"] if t_prep0 is not None else []
+        if pack.digests is None:
+            hm_plan = (None, None, None, pack.draws)
+        else:
+            reasons.append("arena")
+            if t_prep0 is None:
+                t_prep0 = marks.mark("host_prep")
+            hm_plan = self._hm_arena_plan(pack.digests, pack.draws, pack.u_hm)
+        prep = "under_lock" if reasons else "outside_lock"
+        _M_PREP.labels(prep=prep,
+                       reason="+".join(reasons) or "none").inc()
         # per-dispatch H(m) arena accounting for the ledger: a
         # bypassed/disabled cache means every row pays h2c at the
         # canonical unique bucket; otherwise misses pay at the
@@ -836,21 +1051,15 @@ class JaxBls12381(BLS12381):
         # change to the plan's bucket rule can't skew the ledger
         h2c_bucket = (plan_draws[0][0].shape[0]
                       if plan_draws is not None else 0)
-        if plan_slots is None:
-            h2c_stats = {"cache_hits": 0,
-                         "cache_misses": len(row_msgs),
-                         "dispatch_bucket": h2c_bucket}
-        else:
-            misses = len(plan_missing)
-            h2c_stats = {"cache_hits": len(row_msgs) - misses,
-                         "cache_misses": misses,
-                         "dispatch_bucket": h2c_bucket}
-        # the timeline's host-prep interval: the serial host-side term
-        # host_prep_serial_share is computed from (subtracting any
-        # overlap with device-busy intervals)
-        timeline.interval(
-            "worker", "host_prep", time.perf_counter() - t_hp0,
-            t_mono=t_hp0, trace_id=tracing.current_trace_id())
+        misses = pack.n_rows if plan_slots is None else len(plan_missing)
+        h2c_stats = {"cache_hits": pack.n_rows - misses,
+                     "cache_misses": misses,
+                     "dispatch_bucket": h2c_bucket}
+        if t_prep0 is not None:
+            timeline.interval(
+                "worker", "host_prep", time.perf_counter() - t_prep0,
+                t_mono=t_prep0, trace_id=tracing.current_trace_id())
+        plan, padded, msm_path = pack.plan, pack.padded, pack.msm_path
         mesh_n = (self._sharded.n_devices
                   if self._sharded is not None else 0)
         # mesh dispatches get their own shape family (the capacity
@@ -858,7 +1067,7 @@ class JaxBls12381(BLS12381):
         # the single-device one; latency_for_lanes prefix-matches
         # "{lanes}x" so the admission planner still sees mesh-shaped
         # device latencies for its batch sizing)
-        shape = SS.shape_label(padded, kmax, mesh_n)
+        shape = SS.shape_label(padded, pack.kmax, mesh_n)
         # the staged jits are module-level (shared across providers)
         # and the sharded kernels are process-memoized by (device set,
         # axis, msm path) — key the seen-set on the kernel identity
@@ -878,7 +1087,7 @@ class JaxBls12381(BLS12381):
         cache_before = compilecache.stats() if first else None
         aot_before = aotstore.stats() if first else None
         _M_H2C_LANES.inc(n)
-        _M_H2C_UNIQUE.inc(len(uniq_msgs))
+        _M_H2C_UNIQUE.inc(pack.n_unique)
         _M_MSM.labels(path=msm_path).inc()
         _M_MSM_LANES.labels(path=msm_path).inc(n)
         self.msm_dispatches[msm_path] += 1
@@ -893,7 +1102,8 @@ class JaxBls12381(BLS12381):
         # dispatch, completed by the handle's result().  open_record()
         # also merges the batching service's context annotations (plan
         # mode, brownout level, class mix) — asyncio.to_thread copied
-        # them into this worker thread.
+        # them into this worker thread.  Opened here, in the device
+        # half, so the ledger's order is the device's.
         if plan is not None:
             # `devices` + `epoch` stamp the LIVE device set serving
             # this dispatch: after a self-healing reshape the ledger
@@ -911,16 +1121,18 @@ class JaxBls12381(BLS12381):
             mesh_block = {"devices": 0, "epoch": self.mesh_epoch}
         rec = dispatchledger.open_record(
             trace_ids=[t.trace_id for t in traces],
-            shape=shape, mont_path=mont_path, randomized=randomize,
-            lanes=n, kmax=kmax,
-            unique_messages=len(uniq_msgs), rows=len(rows),
-            group_bucket=g_bucket,
-            dedup_ratio=round((n - len(uniq_msgs)) / n, 4),
+            shape=shape, mont_path=mont_path, randomized=pack.randomize,
+            lanes=n, kmax=pack.kmax,
+            unique_messages=pack.n_unique, rows=pack.n_rows,
+            group_bucket=pack.g_bucket,
+            dedup_ratio=round((n - pack.n_unique) / n, 4),
             waste={"lane": {"real": n, "padded": padded},
-                   "h2c": {"real": len(rows), "padded": u_total}},
+                   "h2c": {"real": pack.n_rows, "padded": pack.u_total}},
             h2c=h2c_stats,
-            msm={"path": msm_path, "why": msm_why},
-            mesh=mesh_block)
+            msm={"path": msm_path, "why": pack.msm_why},
+            mesh=mesh_block, prep=prep)
+        if reasons:
+            rec["prep_reason"] = "+".join(reasons)
         t_dev0 = marks.mark("device_enqueue")
         outcome = "cache_hit"
         enqueued = False
@@ -940,23 +1152,18 @@ class JaxBls12381(BLS12381):
                 # layout with one gather, then the group-aligned
                 # kernel runs the full dedup pipeline per shard
                 hm_rows = V.staged_jits()["gather"](
-                    hm_uniq, jnp.asarray(row_gather))
-                scalars = (glv_digits if msm_path == "pippenger"
-                           else r_bits)
-                ok, lane_ok = self._sharded.kernel(msm_path)(
-                    pk_xs, pk_ys, pk_present, hm_rows, group_idx,
-                    group_present, (sx0, sx1), s_large, s_inf,
-                    scalars, lane_valid)
-            elif msm_path == "pippenger":
-                ok, lane_ok = V.verify_staged_pippenger(
-                    pk_xs, pk_ys, pk_present, hm_uniq, group_idx,
-                    group_present, (sx0, sx1), s_large, s_inf,
-                    glv_digits, lane_valid)
+                    hm_uniq, jnp.asarray(pack.row_gather))
+                kernel = self._sharded.kernel(msm_path)
+                hm_in = hm_rows
             else:
-                ok, lane_ok = V.verify_staged_grouped(
-                    pk_xs, pk_ys, pk_present, hm_uniq, group_idx,
-                    group_present, (sx0, sx1), s_large, s_inf,
-                    r_bits, lane_valid)
+                kernel = (V.verify_staged_pippenger
+                          if msm_path == "pippenger"
+                          else V.verify_staged_grouped)
+                hm_in = hm_uniq
+            ok, lane_ok = kernel(
+                pack.pk_xs, pack.pk_ys, pack.pk_present, hm_in,
+                pack.group_idx, pack.group_present, pack.sx, pack.s_large,
+                pack.s_inf, pack.scalars, pack.lane_valid)
             enqueued = True
         finally:
             if first:
@@ -996,4 +1203,5 @@ class JaxBls12381(BLS12381):
                     else f"{mont_path}+pip")
         return _DispatchHandle(ok, lane_ok, n, traces, shape,
                                lat_path, t_enq_end,
-                               lane_sel=lane_pos, rec=rec, marks=marks)
+                               lane_sel=pack.lane_pos, rec=rec,
+                               marks=marks)

@@ -61,7 +61,7 @@ def group_rows(groups: Sequence, group_cap: int) -> List[Tuple[int,
     larger than the group cap split across rows (a message may own
     several rows backed by the same H(m) point).  Each group is a
     lane COUNT (registry enumeration) or a lane-index list (provider
-    dispatch — this is the split rule `_begin_dispatch` runs); rows
+    dispatch — this is the split rule `_pack` runs); rows
     keep the caller's form: [(unique index, count-or-chunk)]."""
     rows: List[Tuple[int, object]] = []
     for u, g in enumerate(groups):
@@ -109,7 +109,7 @@ def batch_plan(lane_groups: Sequence[int], *, min_bucket: int,
                mesh_devices: int = 0,
                h2c_missing: Optional[int] = None) -> dict:
     """The full bucket decision for one batch profile, exactly as
-    ``provider._begin_dispatch`` makes it.  ``lane_groups`` is the
+    ``provider._pack`` makes it.  ``lane_groups`` is the
     batch's lanes-per-unique-message profile (``[1]*256`` = all
     unique, ``[8]*32`` = committee-duplicated); ``h2c_missing`` is how
     many unique messages miss the H(m) arena (default: all — the
@@ -219,8 +219,7 @@ def _scalars_aval(padded: int, msm_path: str):
                                   np.zeros(1, dtype=np.uint64))
     else:
         from . import points as PT
-        probe = np.asarray(PT.scalar_from_uint64(
-            np.ones(1, dtype=np.uint64)))
+        probe = PT.scalar_bits_np(np.ones(1, dtype=np.uint64))
     return _sds((padded,) + probe.shape[1:], probe.dtype)
 
 

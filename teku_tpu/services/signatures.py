@@ -964,7 +964,7 @@ class AggregatingSignatureVerificationService:
         never admitted under), the batch's verify-class mix, and
         whether the real-time flush failsafe ended the fill hold.
         Bound via dispatchledger.annotate() so asyncio.to_thread
-        carries it into the provider's _begin_dispatch.  Bisect
+        carries it into the provider's _launch.  Bisect
         re-dispatches carry no governing plan and fall back to a
         passive last_plan() read (no tick side effects)."""
         mix: Dict[str, int] = {}

@@ -6,10 +6,11 @@ histogram, once in the batch's traces and once in the dispatch's ledger
 record.
 
 The device here is a fake that does what `ops/provider.py` does around
-a dispatch (scope the marks, open the ledger record, hand the real
-`_DispatchHandle` its verdict arrays) and sleeps where the provider
-works, so the service, the facade, the guard, the breaker, the handle
-and the ledger are the real ones."""
+a dispatch (a host half that marks `host_prep`, a device half that
+opens the ledger record and hands the real `_DispatchHandle` its
+verdict arrays) and sleeps where the provider works, so the service,
+the facade, the guard, the breaker, the handle and the ledger are the
+real ones."""
 
 import asyncio
 import subprocess
@@ -21,19 +22,25 @@ import pytest
 
 from teku_tpu.crypto import bls
 from teku_tpu.crypto.bls import loader
+from teku_tpu.crypto.bls.spi import PreparedDispatch
 from teku_tpu.infra import dispatchledger, timeline, tracing
 from teku_tpu.infra.metrics import GLOBAL_REGISTRY, MetricsRegistry
 from teku_tpu.infra.supervisor import CircuitBreaker
 from teku_tpu.services.signatures import (
     AggregatingSignatureVerificationService)
 
-SERVED = ["thread_hop", "lock_wait", "host_prep", "device_enqueue",
+SERVED = ["thread_hop", "host_prep", "lock_wait", "device_enqueue",
           "device_sync", "return_hop", "settle"]
 PK = b"\xa0" + bytes(47)
 
 
+class _Prepared(PreparedDispatch):
+    __slots__ = ("triples",)
+
+
 class PhasedDevice:
-    """Sleeps `hold_s` under the guard's lock; a task whose message
+    """Packs for `hold_s / 2` in its host half and sleeps `hold_s` in
+    its device half (under the guard's lock); a task whose message
     starts with b"bad" makes its batch false."""
 
     name = "phased-fake"
@@ -41,17 +48,25 @@ class PhasedDevice:
     def __init__(self, hold_s: float = 0.0):
         self.hold_s = hold_s
 
-    def _begin(self, triples):
+    def prepare_dispatch(self, op, *args):
+        tracing.current_marks().mark("host_prep")
+        time.sleep(self.hold_s / 2)
+        triples = args[0] if op == "batch_verify" else [args]
+        prepared = _Prepared()
+        prepared.triples = triples
+        return prepared
+
+    def launch_dispatch(self, prepared):
         from teku_tpu.ops.provider import _DispatchHandle
+        triples = prepared.triples
         n = len(triples)
         marks = tracing.current_marks()
-        marks.mark("host_prep")
-        time.sleep(self.hold_s / 2)
         traces = tracing.current_traces()
         rec = dispatchledger.open_record(
             trace_ids=[t.trace_id for t in traces], shape=f"{n}x1",
-            lanes=n)
+            lanes=n, prep="outside_lock")
         marks.mark("device_enqueue")
+        time.sleep(self.hold_s)
         rec["compile"] = {"outcome": "cache_hit", "enqueue_s": 0.0}
         ok = not any(msg.startswith(b"bad") for _pks, msg, _sig in triples)
         return _DispatchHandle(
@@ -59,21 +74,24 @@ class PhasedDevice:
             shape=f"{n}x1", path="vpu", t_enq_end=time.perf_counter(),
             rec=rec, marks=marks)
 
-    def batch_verify(self, triples):
+    def _run(self, op, *args):
         with tracing.dispatch_marks("host_prep"):
-            handle = self._begin(triples)
-            time.sleep(self.hold_s / 2)
-            return handle.result()
+            return self.launch_dispatch(
+                self.prepare_dispatch(op, *args)).result()
+
+    def batch_verify(self, triples):
+        return self._run("batch_verify", triples)
 
     def fast_aggregate_verify(self, pks, msg, sig):
-        return self.batch_verify([(pks, msg, sig)])
+        return self._run("fast_aggregate_verify", pks, msg, sig)
 
 
 class AsyncPhasedDevice(PhasedDevice):
     """The async seam too, as the raw provider has it."""
 
     def begin_batch_verify(self, triples):
-        handle = self._begin(triples)
+        handle = self.launch_dispatch(
+            self.prepare_dispatch("batch_verify", triples))
         tracing.current_marks().mark("return_hop")
         return handle
 
@@ -152,10 +170,12 @@ def test_served_dispatch_phases_tile_in_order():
     assert [name for name, _t, _s in rec["phases"]] == SERVED
     _assert_tiles(rec["phases"])
     # the lock's edges lie inside the dispatch, around the device's work
+    # alone: taken once the host half is done, at `lock_wait`'s end
     by_name = {name: (t0, secs) for name, t0, secs in rec["phases"]}
     acquired, released = rec["lock"]["acquired"], rec["lock"]["released"]
-    assert by_name["lock_wait"][0] <= acquired <= by_name["host_prep"][0] \
-        + 1e-3
+    assert sum(by_name["host_prep"]) <= by_name["lock_wait"][0] + 2.5e-6
+    assert by_name["lock_wait"][0] <= acquired \
+        <= by_name["device_enqueue"][0] + 1e-3
     assert sum(by_name["device_sync"]) <= released + 2.5e-6
     assert released - acquired >= 0.02
     assert rec["parent_seq"] is None
@@ -192,8 +212,9 @@ def test_span_tree_names_the_whole_dispatch():
 
 
 def test_second_worker_waits_out_the_first_ones_hold():
-    """Two workers, two batches at once: the second one's `lock_wait`
-    is the first one's hold of the lock."""
+    """Two workers, two batches at once: both pack side by side, then
+    the second one's `lock_wait` runs from its host half's end to the
+    first one's release of the lock."""
     hold = 0.12
     verdicts, _t, records = _serve(
         _guarded(PhasedDevice(hold)), [b"a0", b"a1", b"b0", b"b1"],
@@ -204,15 +225,41 @@ def test_second_worker_waits_out_the_first_ones_hold():
     for rec in records:
         assert [n for n, _t, _s in rec["phases"]] == SERVED
         _assert_tiles(rec["phases"])
-    waits = [dict((n, s) for n, _t, s in r["phases"])["lock_wait"]
-             for r in (first, second)]
+    by_name = [dict((n, (t, s)) for n, t, s in r["phases"])
+               for r in (first, second)]
+    waits = [phases["lock_wait"][1] for phases in by_name]
     held = first["lock"]["released"] - first["lock"]["acquired"]
     assert held >= hold
     assert waits[0] < 0.03
-    assert waits[1] == pytest.approx(held, abs=0.04)
+    # the second one's host half ended, and its wait began, while the
+    # first one still held the lock
+    assert first["lock"]["acquired"] < by_name[1]["lock_wait"][0] \
+        < first["lock"]["released"]
+    assert waits[1] == pytest.approx(
+        first["lock"]["released"] - by_name[1]["lock_wait"][0], abs=0.03)
     # nobody held the lock between the two for longer than a hand-over
     assert 0 <= second["lock"]["acquired"] - first["lock"]["released"] \
         < 0.03
+
+
+def test_lock_is_taken_after_host_prep_never_over_it():
+    """Whatever the interleaving of two workers' dispatches: no
+    dispatch holds the device-entry lock during its `host_prep`, and
+    the record says where its prep ran."""
+    _v, _t, records = _serve(
+        _guarded(PhasedDevice(0.04)), [b"m%d" % i for i in range(8)],
+        num_workers=2, max_batch_size=2)
+    assert len(records) == 4
+    for rec in records:
+        for name, t0, secs in rec["phases"]:
+            if name == "host_prep":
+                assert t0 + secs <= rec["lock"]["acquired"] + 2.5e-6
+        assert rec["prep"] == "outside_lock"
+    # the holds do not overlap: the lock still serialises the device
+    holds = sorted((r["lock"]["acquired"], r["lock"]["released"])
+                   for r in records)
+    for (_a0, r0), (a1, _r1) in zip(holds, holds[1:]):
+        assert r0 <= a1 + 2.5e-6
 
 
 def test_profiler_annotations_cover_the_single_thread_phases(monkeypatch):
@@ -231,7 +278,7 @@ def test_profiler_annotations_cover_the_single_thread_phases(monkeypatch):
 
     monkeypatch.setattr(tracing, "_annotation", annotation)
     _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
-    want = ["lock_wait", "host_prep", "device_enqueue", "device_sync",
+    want = ["host_prep", "lock_wait", "device_enqueue", "device_sync",
             "settle"]
     assert entered == want and exited == want
 
@@ -241,8 +288,8 @@ def test_tracing_imports_and_marks_without_jax():
         "import sys\n"
         "from teku_tpu.infra import tracing\n"
         "m = tracing.new_marks()\n"
-        "m.mark('lock_wait'); m.mark('host_prep'); m.close()\n"
-        "assert [p[0] for p in m.phases] == ['lock_wait', 'host_prep']\n"
+        "m.mark('host_prep'); m.mark('lock_wait'); m.close()\n"
+        "assert [p[0] for p in m.phases] == ['host_prep', 'lock_wait']\n"
         "assert 'jax' not in sys.modules\n")
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=60)

@@ -1,7 +1,7 @@
 """Shape-set registry: anti-drift pins against the dispatch path.
 
 `ops/shapeset.py` is only useful if it CANNOT diverge from what
-`provider._begin_dispatch` actually dispatches — a registry that
+`provider._pack` actually dispatches — a registry that
 enumerates yesterday's buckets precompiles the wrong programs and the
 compile wall comes back silently.  These tests pin the sharing:
 
