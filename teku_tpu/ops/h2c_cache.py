@@ -11,9 +11,13 @@ one gather out of the arena.
 Layout: a fixed-capacity arena of four (capacity, L) limb arrays (the
 affine Fq2 x and y coordinate components, Montgomery form) that lives
 on the device; the host side keeps an LRU index of message digest →
-arena slot.  Inserts are one batched scatter (``.at[slots].set``),
-lookups one batched gather — no per-point host/device round trips, and
-the point data never leaves the device.
+arena slot.  An insert is ONE scatter program over the whole h2c
+output bucket (rows past the digests carry an out-of-range slot and
+are dropped), a gather ONE gather program — no per-point host/device
+round trips, the point data never leaves the device, and no program's
+shape depends on how many messages missed: one scatter per h2c miss
+bucket, one gather per row bucket, each compiled by the first batch
+that needs it.
 
 Poison defense (fault site ``h2c.cache``): every slot records the
 digest it was computed for, and a hit is RE-VERIFIED BY KEY — the slot's
@@ -33,6 +37,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..infra import faults
@@ -62,6 +67,21 @@ def evictions_counter(cache: str):
     """The shared eviction family, bound to one cache label (the
     provider wires its pk/u caches through this too)."""
     return _M_EVICTIONS.labels(cache=cache)
+
+
+@jax.jit
+def _scatter(arena, idx, hm_bucket):
+    """Rows of an h2c output bucket into their arena slots; a row whose
+    slot is out of range (the bucket's padding) is dropped."""
+    (x0, x1), (y0, y1) = hm_bucket
+    return tuple(a.at[idx].set(rows, mode="drop")
+                 for a, rows in zip(arena, (x0, x1, y0, y1)))
+
+
+@jax.jit
+def _gather(arena, idx):
+    x0, x1, y0, y1 = (a[idx] for a in arena)
+    return ((x0, x1), (y0, y1))
 
 
 def configured_capacity() -> int:
@@ -140,8 +160,11 @@ class H2cPointCache:
 
         `hm_bucket` is stage_h2c's affine tree ((x0, x1), (y0, y1)) of
         (B, L) device arrays with B >= len(digests).  Returns the (k,)
-        array of assigned slots.  One batched scatter; LRU entries are
-        evicted as needed."""
+        array of assigned slots.  ONE scatter at the bucket's own width
+        B: the rows past k are given the slot `capacity`, which is out
+        of range and dropped, so the program launched depends on the
+        miss bucket `stage_h2c` ran at and never on the miss count k.
+        LRU entries are evicted as needed."""
         k = len(digests)
         if k > self.capacity:
             # an over-capacity insert would evict slots assigned
@@ -151,13 +174,15 @@ class H2cPointCache:
             raise ValueError(
                 f"insert of {k} points exceeds arena capacity "
                 f"{self.capacity}")
-        slots = np.zeros(k, dtype=np.int64)
+        bucket = hm_bucket[0][0].shape[0]
+        idx = np.full(bucket, self.capacity, dtype=np.int64)
         with self._lock:
             for i, dg in enumerate(digests):
                 existing = self._index.get(dg)
                 if existing is not None:
-                    # concurrent insert of the same message: reuse slot
-                    slots[i] = existing
+                    # the same message twice in one call, or inserted
+                    # by a concurrent dispatch: reuse its slot
+                    idx[i] = existing
                     self._index.move_to_end(dg)
                     continue
                 if not self._free:
@@ -169,30 +194,23 @@ class H2cPointCache:
                 slot = self._free.pop()
                 self._index[dg] = slot
                 self._slot_digest[slot] = dg
-                slots[i] = slot
-            (x0, x1), (y0, y1) = hm_bucket
-            idx = jnp.asarray(slots)
+                idx[i] = slot
             if self._arena is None:
                 shape = (self.capacity, fp.L)
                 self._arena = tuple(
                     jnp.zeros(shape, dtype=jnp.int64) for _ in range(4))
-            ax0, ax1, ay0, ay1 = self._arena
-            self._arena = (ax0.at[idx].set(x0[:k]),
-                           ax1.at[idx].set(x1[:k]),
-                           ay0.at[idx].set(y0[:k]),
-                           ay1.at[idx].set(y1[:k]))
-        return slots
+            self._arena = _scatter(self._arena, idx, hm_bucket)
+        return idx[:k]
 
     # ------------------------------------------------------------------
     def gather(self, lane_slots: np.ndarray):
-        """Per-lane H(m) affine tree from the arena: one device gather
-        per coordinate array."""
+        """H(m) affine tree of the given slots (one a Miller row) from
+        the arena: one gather program over the four coordinate
+        arrays."""
         with self._lock:
             arena = self._arena
         assert arena is not None, "gather before any insert"
-        idx = jnp.asarray(lane_slots)
-        x0, x1, y0, y1 = (a[idx] for a in arena)
-        return ((x0, x1), (y0, y1))
+        return _gather(arena, np.asarray(lane_slots, dtype=np.int64))
 
     # ------------------------------------------------------------------
     def stats(self) -> dict:

@@ -343,9 +343,11 @@ class _Packed(types.SimpleNamespace):
     arguments as numpy arrays, the shape decisions (`kmax`, `padded`,
     rows and buckets, the mesh `plan`, `msm_path`) and what the ledger
     record says of them.  Nothing in it has touched the device.
-    `digests` is None with the H(m) arena off or bypassed (`draws` is
-    then the padded h2c input), else the rows' SHA-256 digests beside
-    their per-row `draws`."""
+    H(m) is resolved once a distinct MESSAGE: `digests` is None with
+    the arena off or bypassed (`draws` is then the padded h2c input
+    over the messages), else the messages' SHA-256 digests beside their
+    `draws`; `row_msg` maps each Miller row of the row bucket `u_hm`
+    to its message."""
 
     def fill_keys(self, entries: dict) -> bool:
         """Pack the keys the host half's cache lookup missed, now that
@@ -813,28 +815,36 @@ class JaxBls12381(BLS12381):
         _M_H2C_DISPATCH.inc()
         return V.staged_jits()["h2c"](u0, u1)
 
-    def _hm_host(self, row_msgs: List[bytes], u_bucket: int):
-        """Host half of H(m) resolution: `(digests, draws)`.
+    def _hm_host(self, uniq_msgs: List[bytes]):
+        """Host half of H(m) resolution, once a MESSAGE: `(digests,
+        draws)` over the batch's distinct messages, however many Miller
+        rows each of them owns.
 
         With the arena off, or bypassed because the batch carries more
-        unique messages than the whole arena holds (inserting more rows
-        than capacity would recycle slots assigned earlier in the same
-        call and serve the wrong point), `digests` is None and `draws`
-        the padded h2c input.  Otherwise the SHA-256 digests and the
-        per-row draws, for `_hm_arena_plan`: which of them need an h2c
-        dispatch is slot state, known only in device order."""
+        distinct messages than the whole arena holds (inserting more
+        points than capacity would recycle slots assigned earlier in
+        the same call and serve the wrong point), `digests` is None and
+        `draws` the padded h2c input at the messages' bucket.
+        Otherwise the SHA-256 digests and the per-message draws, for
+        `_hm_arena_plan`: which of them need an h2c dispatch is slot
+        state, known only in device order."""
         cache = self._h2c_cache
-        if not cache.enabled or len(row_msgs) > cache.capacity:
-            return None, self._uniq_draws(row_msgs, u_bucket)
-        return ([hashlib.sha256(m).digest() for m in row_msgs],
-                [self._u_draws(m) for m in row_msgs])
+        if not cache.enabled or len(uniq_msgs) > cache.capacity:
+            return None, self._uniq_draws(
+                uniq_msgs,
+                SS.unique_bucket(len(uniq_msgs), self._h2c_min_bucket))
+        return ([hashlib.sha256(m).digest() for m in uniq_msgs],
+                [self._u_draws(m) for m in uniq_msgs])
 
-    def _hm_arena_plan(self, digests, draws, u_bucket: int):
-        """The arena's lookups, in device order (the caller holds the
-        device-entry lock where there is one): a slot read here must
-        still hold its point when this dispatch's gather runs, and
-        only the dispatches launched before it may recycle slots."""
-        slots = np.zeros(u_bucket, dtype=np.int64)
+    def _hm_arena_plan(self, digests, draws):
+        """The arena's lookups, one a distinct message, in device order
+        (the caller holds the device-entry lock where there is one): a
+        slot read here must still hold its point when this dispatch's
+        gather runs, and only the dispatches launched before it may
+        recycle slots.  Returns `(slots, missing, digests, miss_draws)`
+        by message; `miss_draws` is the h2c input over the missing
+        messages at their miss bucket, None when every message hit."""
+        slots = np.zeros(len(digests), dtype=np.int64)
         missing = []
         for j, dg in enumerate(digests):
             slot = self._h2c_cache.lookup(dg)
@@ -850,23 +860,33 @@ class JaxBls12381(BLS12381):
                                          mb)
         return slots, missing, digests, miss_draws
 
-    def _hm_device(self, plan):
-        """Device half of H(m) resolution for a deduped batch.
+    def _hm_device(self, plan, pack: "_Packed"):
+        """Device half of H(m) resolution for a deduped batch: the
+        H(m) tree with one entry a Miller ROW, from points resolved
+        once a MESSAGE (`pack.row_msg` maps each row of the row bucket
+        to its message).
 
         Arena hits cost one gather; misses pay ONE h2c dispatch over
-        the missing-message bucket and land in the arena; a fully-warm
-        batch performs ZERO h2c dispatches.  Padding rows (>= the
-        unique count) carry arbitrary points — group_present masks
-        them downstream."""
+        the missing-message bucket and land in the arena by one
+        scatter at that bucket; a fully-warm batch performs ZERO h2c
+        dispatches.  With the arena off or bypassed the distinct
+        messages are hashed and, where a committee was split over
+        several rows, gathered to them.  Padding rows (>= the row
+        count) carry arbitrary points — group_present masks them
+        downstream."""
         slots, missing, digests, draws = plan
         if slots is None:   # cache disabled/bypassed: plain unique h2c
-            return self._h2c_dispatch(draws)
+            hm_msgs = self._h2c_dispatch(draws)
+            if pack.n_rows == pack.n_unique:    # a row a message
+                return hm_msgs
+            return V.staged_jits()["gather"](
+                hm_msgs, jnp.asarray(pack.row_msg))
         if missing:
             hm_bucket = self._h2c_dispatch(draws)
             new_slots = self._h2c_cache.insert(
                 [digests[j] for j in missing], hm_bucket)
             slots[np.asarray(missing)] = new_slots
-        return self._h2c_cache.gather(slots)
+        return self._h2c_cache.gather(slots[pack.row_msg])
 
     def _pack(self, semis: Sequence[_Semi],
               randomize: bool) -> "_Packed":
@@ -893,14 +913,18 @@ class JaxBls12381(BLS12381):
         # several Miller rows backed by the SAME H(m) point
         rows: List[Tuple[int, List[int]]] = SS.group_rows(
             groups, self._group_cap)
-        row_msgs = [uniq_msgs[u] for u, _ in rows]
         g_bucket = SS.group_bucket(rows)
-        # canonical unique bucket: the h2c dispatch / H(m) arena
-        # width.  Computed from the batch alone — IDENTICAL for
+        # canonical row bucket: the width of the H(m) tree the Miller
+        # loops read.  Computed from the batch alone — IDENTICAL for
         # single-device and mesh dispatch of the same batch, so
         # the dedup counters and h2c dispatch count cannot depend
         # on the mesh (pinned in tests/test_mesh_grouped.py)
         u_hm = SS.unique_bucket(len(rows), self._h2c_min_bucket)
+        # row -> message: H(m) is resolved once a message, and the
+        # rows of a split committee share its point through this index
+        # (padding rows read message 0 — masked)
+        row_msg = np.zeros(u_hm, dtype=np.int32)
+        row_msg[:len(rows)] = [u for u, _ in rows]
         if self._sharded is not None:
             # group-aligned shard layout: whole rows per shard,
             # lanes permuted into each shard's contiguous block
@@ -994,7 +1018,7 @@ class JaxBls12381(BLS12381):
         else:
             scalars = PT.scalar_bits_np(
                 np.ones(padded, dtype=np.uint64))
-        digests, draws = self._hm_host(row_msgs, u_hm)
+        digests, draws = self._hm_host(uniq_msgs)
         # the timeline's host-prep interval: the serial host-side term
         # host_prep_serial_share is computed from (subtracting any
         # overlap with device-busy intervals)
@@ -1010,6 +1034,7 @@ class JaxBls12381(BLS12381):
             sx=(sx0, sx1), s_large=s_large, s_inf=s_inf,
             lane_valid=lane_valid, group_idx=group_idx,
             group_present=group_present, row_gather=row_gather,
+            row_msg=row_msg,
             msm_path=msm_path, msm_why=msm_why, scalars=scalars,
             digests=digests, draws=draws)
 
@@ -1037,22 +1062,23 @@ class JaxBls12381(BLS12381):
             reasons.append("arena")
             if t_prep0 is None:
                 t_prep0 = marks.mark("host_prep")
-            hm_plan = self._hm_arena_plan(pack.digests, pack.draws, pack.u_hm)
+            hm_plan = self._hm_arena_plan(pack.digests, pack.draws)
         prep = "under_lock" if reasons else "outside_lock"
         _M_PREP.labels(prep=prep,
                        reason="+".join(reasons) or "none").inc()
-        # per-dispatch H(m) arena accounting for the ledger: a
-        # bypassed/disabled cache means every row pays h2c at the
-        # canonical unique bucket; otherwise misses pay at the
-        # missing-message bucket and hits cost one gather
+        # per-dispatch H(m) arena accounting for the ledger, by
+        # MESSAGE: a bypassed/disabled cache means every distinct
+        # message pays h2c at the messages' bucket; otherwise misses
+        # pay at the missing-message bucket and hits cost one gather
         plan_slots, plan_missing, _, plan_draws = hm_plan
         # the bucket actually dispatched is read off the plan's
         # own padded draws (first dim) — never re-derived, so a
         # change to the plan's bucket rule can't skew the ledger
         h2c_bucket = (plan_draws[0][0].shape[0]
                       if plan_draws is not None else 0)
-        misses = pack.n_rows if plan_slots is None else len(plan_missing)
-        h2c_stats = {"cache_hits": pack.n_rows - misses,
+        misses = (pack.n_unique if plan_slots is None
+                  else len(plan_missing))
+        h2c_stats = {"cache_hits": pack.n_unique - misses,
                      "cache_misses": misses,
                      "dispatch_bucket": h2c_bucket}
         if t_prep0 is not None:
@@ -1137,7 +1163,7 @@ class JaxBls12381(BLS12381):
         outcome = "cache_hit"
         enqueued = False
         try:
-            hm_uniq = self._hm_device(hm_plan)
+            hm_uniq = self._hm_device(hm_plan, pack)
             if self._sharded is not None:
                 # `bls.mesh_shard` fault site: a wedged SHARD wedges
                 # the whole mesh dispatch.  The LIVE device names ride

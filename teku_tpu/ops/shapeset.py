@@ -59,7 +59,8 @@ def group_rows(groups: Sequence, group_cap: int) -> List[Tuple[int,
                                                                object]]:
     """Miller rows for per-unique-message lane groups: committees
     larger than the group cap split across rows (a message may own
-    several rows backed by the same H(m) point).  Each group is a
+    several rows; the provider resolves H(m) once a message and hands
+    every one of its rows the same point).  Each group is a
     lane COUNT (registry enumeration) or a lane-index list (provider
     dispatch — this is the split rule `_pack` runs); rows
     keep the caller's form: [(unique index, count-or-chunk)]."""
@@ -80,14 +81,18 @@ def group_bucket(rows: Sequence[Tuple[int, object]]) -> int:
 
 
 def unique_bucket(n_rows: int, h2c_min_bucket: int) -> int:
-    """The canonical unique bucket: H(m) arena / h2c dispatch width.
-    Computed from the batch alone — identical for single-device and
-    mesh dispatch of the same batch."""
+    """The canonical unique bucket: the width of the H(m) tree the
+    Miller loops read (one entry a row) and, with the arena off, of
+    the h2c dispatch over the batch's distinct messages.  Computed
+    from the batch alone — identical for single-device and mesh
+    dispatch of the same batch."""
     return max(next_pow2(n_rows), h2c_min_bucket)
 
 
 def h2c_miss_bucket(n_missing: int, h2c_min_bucket: int) -> int:
-    """Width of the h2c dispatch serving a batch's arena misses."""
+    """Width of the h2c dispatch serving a batch's arena misses, and
+    of the arena's insert behind it: `n_missing` counts distinct
+    MESSAGES, never the rows a split committee owns."""
     return max(next_pow2(n_missing), h2c_min_bucket)
 
 
@@ -111,9 +116,13 @@ def batch_plan(lane_groups: Sequence[int], *, min_bucket: int,
     """The full bucket decision for one batch profile, exactly as
     ``provider._pack`` makes it.  ``lane_groups`` is the
     batch's lanes-per-unique-message profile (``[1]*256`` = all
-    unique, ``[8]*32`` = committee-duplicated); ``h2c_missing`` is how
-    many unique messages miss the H(m) arena (default: all — the
-    cold-boot case; 0 = fully warm, no h2c program)."""
+    unique, ``[8]*32`` = committee-duplicated, ``[250]`` = one
+    committee over 8 rows of 32); ``h2c_missing`` is how many distinct
+    MESSAGES miss the H(m) arena (default: all — the cold-boot case;
+    0 = fully warm, no h2c program).  H(m) is resolved once a message,
+    so ``h2c_missing`` and ``h2c_bucket`` (the width of ``stage_h2c``
+    and of the arena's insert) count messages, as the ledger record's
+    ``h2c`` block does, while ``rows`` / ``u_hm`` count Miller rows."""
     lanes = sum(lane_groups)
     rows = group_rows(lane_groups, group_cap)
     g_bucket = group_bucket(rows)
@@ -133,11 +142,12 @@ def batch_plan(lane_groups: Sequence[int], *, min_bucket: int,
         padded = lane_bucket(lanes, min_bucket)
         u_total = u_hm
         lanes_per_shard = rows_per_shard = None
-    missing = len(rows) if h2c_missing is None else h2c_missing
+    missing = len(lane_groups) if h2c_missing is None else h2c_missing
     from . import msm
     msm_path, _why = msm.explain(lanes=lanes, rows=len(rows))
     return {
         "lanes": lanes, "kmax": kmax, "rows": len(rows),
+        "messages": len(lane_groups), "h2c_missing": missing,
         "group_bucket": g_bucket, "u_hm": u_hm, "padded": padded,
         "u_total": u_total, "msm_path": msm_path,
         "mesh_devices": mesh_devices if mesh_devices >= 2 else 0,
