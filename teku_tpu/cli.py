@@ -160,10 +160,10 @@ def _configure_kernel(args, yaml_cfg):
       digit-split MXU path its place) — resolved by ops/mxu.py at
       trace time in the probe/dispatch threads;
     - the scalars-stage MSM path (`--msm-path` / TEKU_TPU_MSM: ladder
-      | pippenger | auto; auto = the GLV+Pippenger bucketed MSM
-      exactly when the dispatch device is a TPU and the batch clears
-      the duplication crossover) — resolved by ops/msm.py per
-      dispatch;
+      | pippenger | auto; auto = what the chip measured: on a TPU the
+      ladder at every served shape, the GLV+Pippenger bucketed MSM
+      only above 2048 lanes at 8 or more lanes a Miller row) —
+      resolved by ops/msm.py per dispatch;
     - the persistent XLA compile cache (TEKU_TPU_XLA_CACHE_DIR, ON by
       default; =off disables) so warm boots load the multi-minute
       per-shape kernel compiles from disk instead of repaying them.
@@ -1001,7 +1001,8 @@ def cmd_doctor(args) -> int:
     """Explainability engine over the dispatch decision ledger: WHY is
     the latency budget being spent the way it is — cold compiles per
     shape, mesh shard makespan skew, padding waste per lane bucket,
-    H(m) cache coldness, msm auto-demotions, brownouts/sheds/SLO
+    H(m) cache coldness, an MSM path configured on a TPU against the
+    chip's reading, brownouts/sheds/SLO
     burn — ranked, with every finding citing its evidence (dispatch
     records by seq + trace id, flight-recorder events).  Reads a live
     node via --url, or (default) runs a short live in-process devnet
@@ -1287,11 +1288,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "multiplier folds: ladder (per-lane windowed "
                         "double-and-add), pippenger (GLV half-scalar "
                         "split + windowed bucket MSM, one doubling "
-                        "chain per message group), auto (default: "
-                        "pippenger exactly when the dispatch device "
-                        "is a TPU and the batch clears the "
-                        "duplication crossover; see PERF.md).  Env: "
-                        "TEKU_TPU_MSM")
+                        "chain per message group), auto (default: what "
+                        "the chip measured; on a TPU the ladder, 90 "
+                        "against 191 ms a dispatch at the mainnet "
+                        "committee shape, and pippenger only above "
+                        "2048 lanes at 8 or more a Miller row; off a "
+                        "TPU the ladder; see PERF.md section 6, PR "
+                        "29).  Env: TEKU_TPU_MSM")
     n.add_argument("--mesh", default=None, metavar="{off,auto,N}",
                    help="multi-chip verify mesh: off (default, "
                         "single-device dispatch), auto (largest pow-2 "

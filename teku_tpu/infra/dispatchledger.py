@@ -7,7 +7,7 @@ plan, pow-2 bucket padding, and the compile-vs-cache outcome — but
 until this module nothing tied them together: when the
 ``attestation_verify_p50`` budget burns, the SLO engine blames a trace
 id while the REASONS (a new shape compiled cold, a shard's makespan
-skewed, padding waste spiked, msm auto demoted) were scattered across
+skewed, padding waste spiked, which MSM path ran) were scattered across
 logs, gauges, and WARNs.
 
 This is the ordered record: a process-global bounded ring of

@@ -237,38 +237,33 @@ def _h2c_findings(records: List[dict], summary: dict) -> List[dict]:
 
 
 def _msm_findings(records: List[dict]) -> List[dict]:
-    demoted = []
+    """On a TPU `msm.explain()` records the path the chip `measured`
+    faster at the dispatch's lane count, and `auto` takes it: a
+    dispatch on the other path was configured there by hand."""
+    against = []
     for r in records:
         msm = r.get("msm") or {}
-        why = msm.get("why") or {}
-        if msm.get("path") != "ladder" \
-                or not str(why.get("rule", "")).startswith("auto:"):
-            continue
-        dup = why.get("dup")
-        min_dup = why.get("auto_min_dup", 2.0)
-        if why.get("tpu") is False or (
-                isinstance(dup, (int, float)) and dup >= min_dup):
-            demoted.append(r)
-    if not demoted:
+        measured = (msm.get("why") or {}).get("measured")
+        if measured and msm.get("path") != measured:
+            against.append(r)
+    if not against:
         return []
-    why = (demoted[-1].get("msm") or {}).get("why") or {}
-    sev = 25 + min(len(demoted), 15)
-    if why.get("tpu") is False:
-        # the finding's own detail calls this the TUNED default off
-        # TPU — it must inform, never flip the diagnosis unhealthy
-        sev = min(sev, ATTENTION_SEVERITY - 1)
+    msm = against[-1].get("msm") or {}
+    why = msm.get("why") or {}
     return [_finding(
-        "msm_auto_demotion", sev,
-        f"msm auto resolved to the ladder on {len(demoted)} "
-        f"dispatch(es) ({why.get('rule')})",
-        "the GLV+Pippenger bucketed MSM was measured ~1.8x faster on "
-        "the scalars stage at committee shapes, but the auto rule "
-        "declined it — on non-TPU devices that is the tuned default "
-        "(bucket-select memory traffic), on TPU it means the batches "
-        "are below the lanes/duplication crossover "
-        "(TEKU_TPU_MSM_AUTO_MIN_LANES / _MIN_DUP)",
-        evidence=[_cite(r) for r in demoted[:3]],
-        metrics={"dispatches": len(demoted), "why": why})]
+        "msm_path_against_measurement",
+        ATTENTION_SEVERITY + min(len(against), 15),
+        f"msm path {msm.get('path')} on {len(against)} TPU dispatch(es) "
+        f"where the chip measured {why.get('measured')} faster "
+        f"({why.get('rule')})",
+        "on the TPU the ladder measured about twice as fast as the "
+        "GLV+Pippenger bucketed MSM at every served shape (90 against "
+        "191 ms a dispatch at the mainnet committee shape) and slower "
+        "only far above them (PERF.md §6, PR 29; the table in "
+        "ops/msm.py); `--msm-path auto` follows that measurement, "
+        "`--msm-path` / TEKU_TPU_MSM set to a path overrides it",
+        evidence=[_cite(r) for r in against[:3]],
+        metrics={"dispatches": len(against), "why": why})]
 
 
 def _mesh_health_findings(events: List[dict],

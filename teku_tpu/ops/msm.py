@@ -38,25 +38,35 @@ mont_mul (ops/mxu.py) does every inner field op:
 
 Path selection mirrors ops/mxu.py: process-global config (CLI
 ``--msm-path`` / env ``TEKU_TPU_MSM`` / ``set_path()``), resolved per
-DISPATCH (the crossover is shape-dependent):
+DISPATCH:
 
 - ``ladder``    — the per-lane windowed ladder + stage_group fold
   (the bit-identical parity oracle; scalar_mul_bits);
 - ``pippenger`` — the bucketed MSM path on any device (CPU A/B and
   the bench gate use this explicitly);
-- ``auto``      — pippenger exactly when the dispatch device is a TPU
-  AND the batch clears the measured crossover (lanes >=
-  TEKU_TPU_MSM_AUTO_MIN_LANES and lanes/group-rows >=
-  TEKU_TPU_MSM_AUTO_MIN_DUP); everything else stays on the ladder so
-  small/all-unique dispatches never pay the per-group bucket
-  overhead.  Why auto resolves this way is measured + documented in
-  PERF.md.
+- ``auto``      — on a TPU, what the chip measured (PERF.md §6, PR 29;
+  the table at AUTO_TPU_LADDER_MAX_LANES below): the bucketed path
+  only above that many lanes AND at AUTO_TPU_PIPPENGER_MIN_DUP or
+  more lanes a Miller row, the ladder everywhere else.  These kernels
+  are latency-bound chains of sequential point operations, and the
+  ladder's chain (~62 adds + 120 doublings for G1 and G2 together) is
+  shorter than the bucketed path's column scans, bucket reduce and
+  window combine (~174 adds + 56 doublings at the committee shape), so
+  every shape the served path produces (<= 256 lanes) takes the
+  ladder, at about half the device time; the ladder's width only
+  starts to cost past a thousand lanes, where its time grows with the
+  lanes, while the bucketed path's grows with the rows.  The CPU's
+  throughput-bound crossover (lanes >= 32 and lanes/rows >= 2,
+  BENCH_r08) that the rule used to carry held nowhere on the chip.
+  Off a TPU `auto` keeps the long-validated ladder as it always has;
+  ``pippenger`` is an explicit choice there (the CPU A/B and the
+  parity suite make it).
 
 The LEGACY lane-sharded kernel always takes the ladder (bucketing is a
 per-message-group operation and raw lane shards split groups) —
 ``resolve(sharded=True)`` keeps that contract.  The production
 GROUP-ALIGNED mesh kernel (verify_kernel_sharded_grouped) keeps whole
-groups per shard, so its dispatches resolve by shape like any other.
+groups per shard, so its dispatches resolve like any other.
 """
 
 import logging
@@ -69,7 +79,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..crypto.bls.constants import R, X_ABS
-from ..infra.env import env_float, env_int, env_str
+from ..infra.env import env_str
 from . import points as PT
 
 _LOG = logging.getLogger(__name__)
@@ -78,8 +88,47 @@ PATHS = ("ladder", "pippenger", "auto")
 ENV_VAR = "TEKU_TPU_MSM"
 ENV_WINDOW = "TEKU_TPU_MSM_WINDOW"
 ENV_SEG = "TEKU_TPU_MSM_SEG"
-ENV_AUTO_MIN_LANES = "TEKU_TPU_MSM_AUTO_MIN_LANES"
-ENV_AUTO_MIN_DUP = "TEKU_TPU_MSM_AUTO_MIN_DUP"
+
+# `auto` on a TPU: the bucketed path above AUTO_TPU_LADDER_MAX_LANES
+# lanes at AUTO_TPU_PIPPENGER_MIN_DUP or more lanes a Miller row, the
+# ladder everywhere else.  One v5e chip, ms of the scalars-stage
+# programs alone (`stage_scalars` + `stage_group` against
+# `stage_scalars_pippenger`; PERF.md §6, PR 29):
+#
+#   lanes x (rows x a row)     ladder           pippenger
+#    256 x (8 x 32)             48.7 + 43.0       238.9
+#    256 x (16 x 32)            48.7 + 44.4       192.8   mainnet committee
+#    256 x (64 x 4)             48.7 + 41.7       164.4
+#   1024 x (32 x 32)           169.3 + 47.1       561.3
+#   2048 x (64 x 32)           377.1 + 44.3       579.0
+#   2048 x (2048 x 1)          377.1 + 42.6       884.1
+#   4096 x (128 x 32)         1008.8 + 44.5       884.5   pippenger ahead
+#   4096 x (512 x 8)          1008.8 + 44.4       920.1   pippenger ahead
+#   4096 x (4096 x 1)         1008.8 + 44.1      1779.1
+#
+# Unread: 4096 lanes at 2 and 4 a row (a line through the readings at
+# 8 and 1 puts 4 at a tie and 2 with the ladder), more than 4096 lanes.
+AUTO_TPU_LADDER_MAX_LANES = 2048
+AUTO_TPU_PIPPENGER_MIN_DUP = 8
+
+_WHERE = (f"above {AUTO_TPU_LADDER_MAX_LANES} lanes at "
+          f"{AUTO_TPU_PIPPENGER_MIN_DUP} or more a row (PERF.md §6, PR 29)")
+AUTO_RULE_TPU = {
+    "ladder": "auto: on the TPU the bucketed MSM measured faster only "
+              + _WHERE,
+    "pippenger": "auto: on the TPU the bucketed MSM measured faster "
+                 + _WHERE}
+AUTO_RULE_NOT_TPU = "auto: dispatch device is not a TPU"
+
+
+def _measured_on_tpu(lanes, rows) -> str:
+    """The path the chip's table gives this shape (the exact ratio:
+    rounding lanes/rows first would move the boundary)."""
+    if (lanes and rows and lanes > AUTO_TPU_LADDER_MAX_LANES
+            and lanes >= AUTO_TPU_PIPPENGER_MIN_DUP * rows):
+        return "pippenger"
+    return "ladder"
+
 
 # half-scalar width: multipliers are sampled as (k1, k2) in [0, 2^32)^2
 GLV_BITS = 32
@@ -137,50 +186,33 @@ def _device_is_tpu() -> bool:
 def explain(lanes=None, rows=None, sharded: bool = False):
     """``resolve`` plus WHY: ``(path, why)`` where ``why`` is the
     JSON-able decision context the dispatch ledger records — the
-    configured path, the auto rule's inputs (device, lane count,
-    duplication factor, thresholds), and the rule that fired.  The
-    doctor engine cites this verbatim when it explains an msm
-    auto-demotion."""
+    configured path, the dispatch's shape and device, on a TPU the
+    path the chip ``measured`` faster at this shape, and the rule
+    that fired.  The doctor engine reports a dispatch whose path is
+    not the measured one."""
     why = {"configured": get_path(), "lanes": lanes, "rows": rows}
     if sharded:
         why["rule"] = "legacy lane-sharded kernel always ladders"
         return "ladder", why     # lane shards split message groups
+    why["tpu"] = _device_is_tpu()
+    if why["tpu"]:
+        why["measured"] = _measured_on_tpu(lanes, rows)
     configured = why["configured"]
     if configured in ("ladder", "pippenger"):
         why["rule"] = "explicitly configured"
         return configured, why
-    # auto: the bucketed path wins when the per-group overhead
-    # (2^w - 1 buckets reduced per window) amortizes over enough
-    # duplicated lanes AND the device is the one it was tuned for
-    why["tpu"] = _device_is_tpu()
     if not why["tpu"]:
-        why["rule"] = "auto: dispatch device is not a TPU"
+        why["rule"] = AUTO_RULE_NOT_TPU
         return "ladder", why
-    if not lanes or not rows:
-        why["rule"] = "auto: no shape context"
-        return "ladder", why
-    # shared degrade-never-fail env readers: resolve() sits on the
-    # live dispatch path, so a typo'd threshold must fall back to the
-    # default, not fail every verification
-    why["auto_min_lanes"] = env_int(ENV_AUTO_MIN_LANES, 32)
-    why["auto_min_dup"] = env_float(ENV_AUTO_MIN_DUP, 2.0)
-    # the rule compares the EXACT ratio (rounding first would flip the
-    # decision at the crossover boundary); the record stores it rounded
-    dup = lanes / rows
-    why["dup"] = round(dup, 3)
-    if lanes >= why["auto_min_lanes"] and dup >= why["auto_min_dup"]:
-        why["rule"] = "auto: lanes and duplication clear the crossover"
-        return "pippenger", why
-    why["rule"] = "auto: below the lanes/duplication crossover"
-    return "ladder", why
+    why["rule"] = AUTO_RULE_TPU[why["measured"]]
+    return why["measured"], why
 
 
 def resolve(lanes=None, rows=None, sharded: bool = False) -> str:
     """The EFFECTIVE path for one dispatch: 'ladder' or 'pippenger'.
 
     `lanes`/`rows` are the dispatch's real lane count and Miller-row
-    count (their ratio is the duplication factor the crossover model
-    keys on); `auto` without shape context resolves to the ladder.
+    count; on a TPU `auto` goes by both (explain()).
     `sharded=True` means the LEGACY lane-sharded kernel (always
     ladders — raw lane shards split message groups); the group-aligned
     mesh path resolves with sharded=False."""

@@ -988,9 +988,10 @@ class JaxBls12381(BLS12381):
         # scalars-stage path: the per-lane windowed ladder (64-bit
         # multipliers) or the GLV+Pippenger bucketed MSM (32-bit
         # half-scalar pairs, ops/msm.py).  Resolved per dispatch —
-        # `auto` keys on the duplication factor (lanes per Miller
-        # row).  The GROUP-ALIGNED mesh kernel supports both
-        # (groups never cross shards); msm.resolve(sharded=True)
+        # `auto` goes by the device, the lanes and the lanes a row,
+        # as the chip measured.  The GROUP-ALIGNED mesh kernel
+        # supports both (groups never cross shards);
+        # msm.resolve(sharded=True)
         # remains the LEGACY lane-sharded kernel's always-ladder
         # contract and is not used here
         msm_path, msm_why = msm.explain(lanes=n, rows=len(rows))
@@ -1219,9 +1220,9 @@ class JaxBls12381(BLS12381):
                 rec["verdict"] = None
                 dispatchledger.record(rec)
         # the capacity model's per-(shape, path) latency series must
-        # distinguish the scalars engine: under msm auto, SAME-shape
-        # dispatches can run ladder or pippenger (resolve() keys on
-        # real lanes/rows), and blending two ~1.8x-apart programs into
+        # distinguish the scalars engine: SAME-shape dispatches can
+        # run ladder or pippenger (the path is a run-time choice,
+        # `msm.set_path`), and blending two programs ~2x apart into
         # one series would mis-model device time for the admission
         # controller's batch planner.  The jit metric above keeps the
         # plain mont vocabulary (its label contract is linted).

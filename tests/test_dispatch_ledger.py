@@ -28,6 +28,7 @@ from teku_tpu.crypto.bls.pure_impl import PureBls12381
 from teku_tpu.infra import dispatchledger, doctor, tracing
 from teku_tpu.infra.flightrecorder import FlightRecorder
 from teku_tpu.infra.metrics import MetricsRegistry
+from teku_tpu.ops import msm
 from teku_tpu.ops import provider as PV
 from teku_tpu.ops.provider import JaxBls12381
 from teku_tpu.services.admission import BatchPlan, VerifyClass
@@ -135,9 +136,9 @@ def test_doctor_ranks_findings_and_cites_records():
                    "h2c": {"real": 300, "padded": 512}},
          "h2c": {"cache_hits": 0, "cache_misses": 300},
          "msm": {"path": "ladder",
-                 "why": {"rule": "auto: dispatch device is not a TPU",
-                         "tpu": False, "dup": 4.0,
-                         "auto_min_dup": 2.0}},
+                 "why": {"configured": "auto", "lanes": 300,
+                         "rows": 75, "tpu": False,
+                         "rule": msm.AUTO_RULE_NOT_TPU}},
          "mesh": {"devices": 0},
          "admission": {},
          "compile": {"outcome": "compile", "enqueue_s": 41.0}},
@@ -193,6 +194,41 @@ def test_doctor_ranks_findings_and_cites_records():
     assert "aa-000007" in text and "512x8" in text
     # a clean ledger renders healthy
     assert doctor.diagnose([])["healthy"]
+
+
+@pytest.mark.parametrize("configured,tpu,lanes,rows,finds", [
+    # `auto` takes what the chip measured: nothing to report
+    ("auto", True, 250, 8, False), ("auto", True, 4096, 128, False),
+    ("auto", True, 4096, 4096, False), ("auto", False, 250, 8, False),
+    # a path by hand: reported where the chip measured the other one
+    # faster, the CPU A/B's own business off a TPU
+    ("pippenger", True, 250, 8, True), ("ladder", True, 4096, 128, True),
+    ("pippenger", True, 4096, 4096, True),
+    ("ladder", True, 250, 8, False), ("pippenger", True, 4096, 512, False),
+    ("pippenger", False, 250, 8, False),
+])
+def test_doctor_reports_a_path_against_the_measurement(
+        monkeypatch, configured, tpu, lanes, rows, finds):
+    """The doctor's msm finding says what `msm.explain()` says: the
+    records it reads carry explain()'s own `why`."""
+    monkeypatch.setattr(msm, "_device_is_tpu", lambda: tpu)
+    with msm.force(configured):
+        path, why = msm.explain(lanes=lanes, rows=rows)
+    rec = _compile_rec(21, "256x1", "cache_hit")
+    rec["msm"] = {"path": path, "why": why}
+    diagnosis = doctor.diagnose([rec])
+    found = [f for f in diagnosis["findings"]
+             if f["kind"].startswith("msm")]
+    assert bool(found) is finds, found
+    if finds:
+        f = found[0]
+        assert f["kind"] == "msm_path_against_measurement"
+        assert f["severity"] >= doctor.ATTENTION_SEVERITY
+        assert f"msm path {path}" in f["title"]
+        assert "explicitly configured" in f["title"]
+        assert f["evidence"][0]["seq"] == 21
+        assert f["metrics"]["why"] == why
+        assert not diagnosis["healthy"]
 
 
 def _compile_rec(seq, shape, outcome, enqueue_s=30.0):

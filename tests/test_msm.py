@@ -361,32 +361,75 @@ def test_aggregate_verify_r1_on_pippenger():
 # Path resolution + metrics
 # --------------------------------------------------------------------------
 
-def test_resolve_auto_rules(monkeypatch):
-    with msm.force("ladder"):
-        assert msm.resolve(lanes=4096, rows=1) == "ladder"
-    with msm.force("pippenger"):
-        assert msm.resolve(lanes=1, rows=1) == "pippenger"
-        # the sharded kernel always ladders (groups cross shards)
-        assert msm.resolve(lanes=4096, rows=1, sharded=True) == "ladder"
-    with msm.force("auto"):
-        # CPU dispatch device: auto keeps the long-validated ladder
-        assert msm.resolve(lanes=4096, rows=16) == "ladder"
-        monkeypatch.setattr(msm, "_device_is_tpu", lambda: True)
-        assert msm.resolve(lanes=256, rows=32) == "pippenger"
-        assert msm.resolve(lanes=256, rows=256) == "ladder"  # dup 1
-        assert msm.resolve(lanes=8, rows=2) == "ladder"      # tiny
-        assert msm.resolve(lanes=None, rows=None) == "ladder"
-        # crossover boundary compares the EXACT ratio: dup 1.9996
-        # must stay below the 2.0 threshold even though the ledger
-        # record's rounded why["dup"] reads 2.0
-        path, why = msm.explain(lanes=4999, rows=2500)
-        assert path == "ladder"
-        assert why["dup"] == 2.0                   # rounded for record
-        assert msm.resolve(lanes=5000, rows=2500) == "pippenger"
-    # invalid env value degrades to auto with one warning
+_EXPLICIT = "explicitly configured"
+_SHARDED = "legacy lane-sharded kernel always ladders"
+_MAX, _DUP = msm.AUTO_TPU_LADDER_MAX_LANES, msm.AUTO_TPU_PIPPENGER_MIN_DUP
+
+
+@pytest.mark.parametrize("configured,tpu,shape,path,rule", [
+    # mainnet-subnet-gossip's drains: 250 lanes inside one message
+    # (8 rows of 32) or straddling two (9): the CPU's crossover sent
+    # them to pippenger, the chip's reading sends them to the ladder
+    ("auto", True, dict(lanes=250, rows=8), "ladder", "ladder"),
+    ("auto", True, dict(lanes=250, rows=9), "ladder", "ladder"),
+    # its probe's bisection halves, and backfill-unique's row a lane
+    ("auto", True, dict(lanes=125, rows=4), "ladder", "ladder"),
+    ("auto", True, dict(lanes=250, rows=250), "ladder", "ladder"),
+    # the wider shapes read with the ladder ahead, and no shape at all
+    ("auto", True, dict(lanes=1024, rows=32), "ladder", "ladder"),
+    ("auto", True, dict(lanes=2048, rows=64), "ladder", "ladder"),
+    ("auto", True, dict(lanes=4096, rows=4096), "ladder", "ladder"),
+    ("auto", True, dict(), "ladder", "ladder"),
+    ("auto", True, dict(lanes=4096), "ladder", "ladder"),
+    # the two readings with pippenger ahead: the provider's widest
+    # batch at 32 and at 8 lanes a row
+    ("auto", True, dict(lanes=4096, rows=128), "pippenger", "pippenger"),
+    ("auto", True, dict(lanes=4096, rows=512), "pippenger", "pippenger"),
+    # both constants' two sides, by the exact ratio
+    ("auto", True, dict(lanes=_MAX, rows=_MAX // 32), "ladder", "ladder"),
+    ("auto", True, dict(lanes=_MAX + 1, rows=(_MAX + 1) // _DUP),
+     "pippenger", "pippenger"),
+    ("auto", True, dict(lanes=4096, rows=4096 // _DUP + 1), "ladder",
+     "ladder"),
+    # off a TPU auto keeps the long-validated ladder, and says so
+    ("auto", False, dict(lanes=4096, rows=16), "ladder",
+     msm.AUTO_RULE_NOT_TPU),
+    # an explicit choice wins on either device
+    ("pippenger", True, dict(lanes=250, rows=8), "pippenger", _EXPLICIT),
+    ("pippenger", False, dict(lanes=1, rows=1), "pippenger", _EXPLICIT),
+    ("ladder", True, dict(lanes=4096, rows=1), "ladder", _EXPLICIT),
+    # the legacy lane-sharded kernel always ladders (groups cross shards)
+    ("pippenger", True, dict(lanes=4096, rows=1, sharded=True), "ladder",
+     _SHARDED),
+])
+def test_resolve_auto_rules(monkeypatch, configured, tpu, shape, path,
+                            rule):
+    monkeypatch.setattr(msm, "_device_is_tpu", lambda: tpu)
+    with msm.force(configured):
+        got, why = msm.explain(**shape)
+        assert msm.resolve(**shape) == got == path
+    assert why["rule"] == msm.AUTO_RULE_TPU.get(rule, rule)
+    assert why["configured"] == configured
+    lanes, rows = shape.get("lanes"), shape.get("rows")
+    assert (why["lanes"], why["rows"]) == (lanes, rows)
+    # the record names the device wherever the device had a say, and
+    # on a TPU what the chip measured at this shape: what `auto`
+    # takes, and what the doctor holds an explicit choice against
+    assert why.get("tpu") == (None if rule == _SHARDED else tpu)
+    wide_and_shared = bool(lanes and rows and lanes > _MAX
+                           and lanes >= _DUP * rows)
+    assert why.get("measured") == (
+        None if rule == _SHARDED or not tpu
+        else "pippenger" if wide_and_shared else "ladder")
+    # nothing of the CPU-era crossover is left in the record
+    assert not {"dup", "auto_min_lanes", "auto_min_dup"} & set(why)
+
+
+def test_invalid_env_path_degrades_to_auto(monkeypatch):
     monkeypatch.setenv(msm.ENV_VAR, "bogus")
     msm.set_path(None)
     assert msm.get_path() == "auto"
+    assert msm.resolve(lanes=250, rows=8) == "ladder"
 
 
 def test_msm_dispatch_metrics_move():
@@ -437,12 +480,6 @@ def test_tuning_knobs_degrade_not_raise(monkeypatch):
     monkeypatch.setattr(msm, "_seg_cache", [])
     monkeypatch.setenv(msm.ENV_SEG, "8")
     assert msm._seg_len() == 8
-    # the auto-crossover thresholds sit on the live dispatch path too
-    monkeypatch.setattr(msm, "_device_is_tpu", lambda: True)
-    monkeypatch.setenv(msm.ENV_AUTO_MIN_LANES, "thirtytwo")
-    monkeypatch.setenv(msm.ENV_AUTO_MIN_DUP, "")
-    with msm.force("auto"):
-        assert msm.resolve(lanes=256, rows=32) == "pippenger"
     # the seg choice is process-pinned (g2_msm only runs under jit:
     # a per-call env read would silently stop mattering after the
     # first trace anyway — see msm._seg_len)
@@ -451,7 +488,7 @@ def test_tuning_knobs_degrade_not_raise(monkeypatch):
 
 
 def test_capacity_latency_series_split_by_msm_path():
-    """Under msm auto, same-padded-shape dispatches can run EITHER
+    """Same-padded-shape dispatches can run EITHER
     scalars program; the capacity model's per-(shape, path) latency
     series must not blend them (the admission controller plans
     batches from these p50s)."""

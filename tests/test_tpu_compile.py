@@ -157,11 +157,12 @@ _STAGE_FNS = {"prepare": V.stage_prepare, "scalars": V.stage_scalars,
 def test_staged_program_compiles(one_chip, stage, monkeypatch):
     """Every staged program of the smoke's shape set (max_batch 256,
     min_bucket 256, unique bucket 256) with the paths a TPU picks: vpu,
-    and the msm auto rule's TPU branch (default_backend() still says
-    cpu here)."""
+    and msm auto (the ladder; default_backend() still says cpu here).
+    `scalars_pip` is the explicit `--msm-path pippenger` choice."""
     from teku_tpu.ops import msm
     monkeypatch.setattr(msm, "_device_is_tpu", lambda: True)
-    with mxu.force("vpu"):
+    path = "pippenger" if stage == "scalars_pip" else "auto"
+    with mxu.force("vpu"), msm.force(path):
         programs = [
             (avals, meta) for _, avals, meta in
             shapeset.enumerate_programs(max_batch=LANES,
