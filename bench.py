@@ -598,124 +598,6 @@ def _mont_phase(jax, deadline):
     _beat("mont_phase_done")
 
 
-def _msm_phase(jax, deadline):
-    """Scalars-stage A/B microbench: the per-lane windowed ladder
-    (stage_scalars + stage_group) vs the GLV+Pippenger bucketed MSM
-    (stage_scalars_pippenger, ops/msm.py) on IDENTICAL inputs at the
-    committee-duplicated shape the grouped pipeline serves — plus the
-    G1 (grouped fold) and G2 (whole-batch signature fold) sides
-    measured separately.  The stage-profile `scalars` p50 delta lands
-    in OUT["msm"] and tools/bench_diff.py gates pippenger >= 1.3x at
-    batch >= 256."""
-    import secrets as _secrets
-
-    from teku_tpu.crypto.bls import curve as CC
-    from teku_tpu.ops import limbs as fp
-    from teku_tpu.ops import msm as MS
-    from teku_tpu.ops import points as PTT
-    from teku_tpu.ops import verify as VV
-
-    batches = [int(b) for b in os.environ.get(
-        "BENCH_MSM_BATCHES", "256,4096").split(",")]
-    dup = int(os.environ.get("BENCH_MSM_DUP", "8"))
-    iters = int(os.environ.get("BENCH_MSM_ITERS", "9"))
-    out: dict = {"window": MS.window_env(), "dup": dup,
-                 "unit": "stage p50 ms"}
-    OUT["msm"] = out
-    _beat("msm_phase_start", batches=batches, dup=dup)
-
-    # 8 distinct subgroup points tiled over lanes (host oracle math —
-    # the scalars stage is the only compiled program under test)
-    g1aff = [CC.to_affine(CC.FQ_OPS, CC.point_mul(
-        CC.FQ_OPS, 0x1111 + 7 * i, CC.G1_GENERATOR)) for i in range(8)]
-    g2aff = [CC.to_affine(CC.FQ2_OPS, CC.point_mul(
-        CC.FQ2_OPS, 0x2222 + 9 * i, CC.G2_GENERATOR))
-        for i in range(8)]
-    g1x = np.stack([fp.int_to_mont(a[0]) for a in g1aff])
-    g1y = np.stack([fp.int_to_mont(a[1]) for a in g1aff])
-    g2x = [np.stack([fp.int_to_mont(a[0][c]) for a in g2aff])
-           for c in (0, 1)]
-    g2y = [np.stack([fp.int_to_mont(a[1][c]) for a in g2aff])
-           for c in (0, 1)]
-
-    def p50(thunk):
-        jax.block_until_ready(thunk())       # warm/compile
-        ts = []
-        for _ in range(iters):
-            t0 = time.time()
-            jax.block_until_ready(thunk())
-            ts.append(time.time() - t0)
-        ts.sort()
-        return round(ts[len(ts) // 2] * 1e3, 2)
-
-    jits = VV.staged_jits()
-    g1_lad = jax.jit(lambda pk, rb, mm, gi, gp: VV.stage_group(
-        PTT.scalar_mul_bits(PTT.G1_KIT, rb, pk), mm, gi, gp))
-    g1_pip = jax.jit(MS.g1_grouped_msm)
-    g2_lad = jax.jit(lambda sig, rb: PTT.point_batch_sum(
-        PTT.G2_KIT, PTT.scalar_mul_bits(PTT.G2_KIT, rb, sig)))
-    g2_pip = jax.jit(MS.g2_msm)
-
-    for n in batches:
-        if time.time() > deadline - 120 and any(
-                k.isdigit() for k in out):
-            out[str(n)] = "skipped: budget"
-            continue
-        try:
-            WD.arm(max(deadline - time.time(), 60) + 600,
-                   f"msm batch {n}")
-            rows = max(n // dup, 1)
-            idx = np.arange(n) % 8
-            one = np.tile(np.asarray(fp.ONE_MONT), (n, 1))
-            zero = np.zeros((n, fp.L), dtype=np.int64)
-            pk_jac = (g1x[idx], g1y[idx], one)
-            sig_jac = ((g2x[0][idx], g2x[1][idx]),
-                       (g2y[0][idx], g2y[1][idx]),
-                       (one, zero))
-            raw = np.frombuffer(_secrets.token_bytes(8 * n),
-                                dtype=np.uint64).copy()
-            raw[raw == 0] = 1
-            r_bits = np.asarray(PTT.scalar_from_uint64(raw))
-            digits = MS.glv_digits_np(*MS.glv_sample_from_uint64(raw))
-            mm = np.ones(n, dtype=bool)
-            gi = np.arange(n, dtype=np.int32).reshape(rows, -1)
-            gp = np.ones((rows, n // rows), dtype=bool)
-
-            def lad_stage():
-                pk_r, wsig = jits["scalars"](pk_jac, sig_jac, r_bits)
-                return jits["group"](pk_r, mm, gi, gp) + (wsig,)
-
-            def pip_stage():
-                return jits["scalars_pip"](pk_jac, sig_jac, digits,
-                                           gi, gp, mm)
-
-            entry: dict = {}
-            for name, lad, pip in (
-                    ("g1", lambda: g1_lad(pk_jac, r_bits, mm, gi, gp),
-                     lambda: g1_pip(pk_jac, digits, gi, gp, mm)),
-                    ("g2", lambda: g2_lad(sig_jac, r_bits),
-                     lambda: g2_pip(sig_jac, digits)),
-                    ("scalars", lad_stage, pip_stage)):
-                lad_ms = p50(lad)
-                pip_ms = p50(pip)
-                entry[name] = {
-                    "ladder_p50_ms": lad_ms,
-                    "pippenger_p50_ms": pip_ms,
-                    "speedup": round(lad_ms / pip_ms, 3)
-                    if pip_ms else None}
-            WD.disarm()
-            out[str(n)] = entry
-            _beat("msm_batch_done", batch=n,
-                  scalars_speedup=entry["scalars"]["speedup"],
-                  g1=entry["g1"]["speedup"],
-                  g2=entry["g2"]["speedup"])
-        except Exception as exc:
-            out[str(n)] = {"error": f"{type(exc).__name__}: {exc}"}
-    out["active_path"] = MS.resolve(lanes=batches[0],
-                                    rows=max(batches[0] // dup, 1))
-    _beat("msm_phase_done")
-
-
 def _dedup_phase(jax, deadline):
     """Duplication sweep: fixed batch, dup factor 1x/8x/64x — the
     committee-gossip shape ("Performance of EdDSA and BLS Signatures in
@@ -1451,7 +1333,7 @@ def append_trajectory(out: dict, path: str = _TRAJECTORY_PATH,
 # and chaos ride on BENCH_THROUGHPUT): with any of them on, the run
 # needs a TPU
 _DEVICE_PHASES = ("BENCH_THROUGHPUT", "BENCH_P50", "BENCH_MONT",
-                  "BENCH_MSM", "BENCH_DEDUP", "BENCH_KZG")
+                  "BENCH_DEDUP", "BENCH_KZG")
 
 
 def _on(var: str) -> bool:
@@ -1515,8 +1397,6 @@ def main() -> int:
         phase("p50", _latency_phase, jax, deadline)
     if _on("BENCH_MONT") and time.time() < deadline:
         phase("mont", _mont_phase, jax, deadline)
-    if _on("BENCH_MSM") and time.time() < deadline:
-        phase("msm", _msm_phase, jax, deadline)
     if _on("BENCH_DEDUP") and time.time() < deadline:
         phase("dedup", _dedup_phase, jax, deadline)
     if _on("BENCH_MESH") and run_throughput and time.time() < deadline:

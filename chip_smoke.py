@@ -308,7 +308,7 @@ async def boot(seed: int, warm_boot: bool) -> dict:
     from teku_tpu.crypto import bls
     from teku_tpu.crypto.bls import loader
     from teku_tpu.infra import aotstore, compilecache
-    from teku_tpu.ops import msm, mxu
+    from teku_tpu.ops import mxu
     from teku_tpu.services.signatures import (
         AggregatingSignatureVerificationService)
 
@@ -336,8 +336,7 @@ async def boot(seed: int, warm_boot: bool) -> dict:
     require(ready and snap["state"] == "ready", "supervisor READY")
     require(snap["warmup_cache"].get("finished") is True,
             "READY with warmup FINISHED (no overrun, no failure)")
-    log(f"paths: mont_mul {mxu.resolve()}, msm "
-        f"{msm.explain(lanes=SERVICE_BATCH, rows=32)}")
+    log(f"paths: mont_mul {mxu.resolve()}")
     log(f"compilecache {compilecache.stats()}")
     log(f"aotstore {aotstore.stats()}")
     log(f"first dispatches: {series('bls_jit_dispatch_total')}")

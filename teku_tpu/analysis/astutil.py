@@ -2,7 +2,7 @@
 
 One parse per file, one ModuleIndex per module, and the handful of
 resolution helpers every checker needs: module-level string constants
-(``ENV_VAR = "TEKU_TPU_MSM"`` — the idiom the knob modules use, which a
+(``ENV_VAR = "TEKU_TPU_MESH"`` — the idiom the knob modules use, which a
 literal-only scanner would miss), import maps including relative
 imports (``from ..infra.env import env_float``), dotted call chains,
 and a scope model precise enough to resolve a bare-name call inside a

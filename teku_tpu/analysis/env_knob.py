@@ -13,7 +13,7 @@ also extracts (the input to the ``knob-doc`` drift checker and
 ``cli lint --knobs``).
 
 The checker resolves key expressions through module-level string
-constants (``ENV_VAR = "TEKU_TPU_MSM"``), f-strings, and ``+``
+constants (``ENV_VAR = "TEKU_TPU_MESH"``), f-strings, and ``+``
 concatenation, so neither the knob-module idiom nor a dynamically
 assembled prefix read can hide a raw access.
 """

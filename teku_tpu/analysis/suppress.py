@@ -67,8 +67,8 @@ def apply(findings: List[Finding], entries: List[Dict[str, str]]
         for i, entry in enumerate(entries):
             # EXACT key equality: `checker:match` == the finding key.
             # Substring matching would let one justified entry
-            # silently swallow every future finding sharing a prefix
-            # (e.g. a TEKU_TPU_MSM entry absorbing TEKU_TPU_MSM_SEG).
+            # silently swallow every future finding sharing a prefix (e.g.
+            # a TEKU_TPU_MESH entry absorbing TEKU_TPU_MESH_SELF_HEAL).
             if finding.key == f"{entry['checker']}:{entry['match']}":
                 finding.suppressed = True
                 finding.justification = entry["justification"]

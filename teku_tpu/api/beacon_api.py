@@ -315,10 +315,10 @@ class BeaconRestApi(RestApi):
         """The dispatch decision ledger (infra/dispatchledger.py):
         bounded structured per-dispatch records — batch plan mode and
         brownout level, real vs padded lanes and unique counts (waste
-        split by stage bucket), H(m) cache hits/misses, resolved msm
-        path + why, mesh shard plan + makespan ratio, compile outcome
-        with duration, device sync/busy spans, verdict — each stamped
-        with its originating trace ids.  ``?last=N`` tails,
+        split by stage bucket), H(m) cache hits/misses, mesh shard
+        plan + makespan ratio, compile outcome with duration, device
+        sync/busy spans, verdict — each stamped with its originating
+        trace ids.  ``?last=N`` tails,
         ``?trace_id=X`` filters to the record serving that trace (the
         slow-trace ring's join key), ``?slow=1`` filters to records
         linked to the current slow-trace ring."""

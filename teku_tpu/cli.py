@@ -121,12 +121,10 @@ def _configure_overload(args, yaml_cfg) -> str:
     return choice
 
 
-# mirrors of ops/mxu.py and ops/msm.py PATHS, spelled locally so the
-# boot path never imports the ops package (whose __init__ imports jax)
-# on the main thread — the env vars are how the choices reach the
-# kernel layer
+# mirror of ops/mxu.py PATHS, spelled locally so the boot path never
+# imports the ops package (whose __init__ imports jax) on the main
+# thread — the env var is how the choice reaches the kernel layer
 _MONT_PATHS = ("vpu", "mxu", "auto", "mxu-force")
-_MSM_PATHS = ("ladder", "pippenger", "auto")
 
 
 def _validate_mesh(choice: str) -> str:
@@ -159,16 +157,11 @@ def _configure_kernel(args, yaml_cfg):
       mxu | auto; auto = vpu until a chip measurement earns the int8
       digit-split MXU path its place) — resolved by ops/mxu.py at
       trace time in the probe/dispatch threads;
-    - the scalars-stage MSM path (`--msm-path` / TEKU_TPU_MSM: ladder
-      | pippenger | auto; auto = what the chip measured: on a TPU the
-      ladder at every served shape, the GLV+Pippenger bucketed MSM
-      only above 2048 lanes at 8 or more lanes a Miller row) —
-      resolved by ops/msm.py per dispatch;
     - the persistent XLA compile cache (TEKU_TPU_XLA_CACHE_DIR, ON by
       default; =off disables) so warm boots load the multi-minute
       per-shape kernel compiles from disk instead of repaying them.
 
-    Returns (mont_path, msm_path).
+    Returns (mont_path, mesh).
     """
     from .infra import compilecache
 
@@ -179,13 +172,6 @@ def _configure_kernel(args, yaml_cfg):
         raise SystemExit(f"invalid --mont-path {choice!r} (use one of "
                          f"{'/'.join(_MONT_PATHS)})")
     os.environ["TEKU_TPU_MONT_MUL"] = choice
-    msm_choice = str(layered_value(
-        "msm-path", getattr(args, "msm_path", None), yaml_cfg,
-        "auto")).lower()
-    if msm_choice not in _MSM_PATHS:
-        raise SystemExit(f"invalid --msm-path {msm_choice!r} (use one "
-                         f"of {'/'.join(_MSM_PATHS)})")
-    os.environ["TEKU_TPU_MSM"] = msm_choice
     # multi-chip mesh (`--mesh {off,auto,N}` / TEKU_TPU_MESH): resolved
     # to a device mesh by the loader's probe (teku_tpu/parallel — auto
     # takes the largest pow-2 <= available devices, a non-pow-2 or
@@ -201,11 +187,11 @@ def _configure_kernel(args, yaml_cfg):
         from .infra.env import ensure_virtual_devices
         ensure_virtual_devices(int(mesh_choice))
     compilecache.configure()
-    return choice, msm_choice, mesh_choice
+    return choice, mesh_choice
 
 
 def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
-                   mont_path=None, msm_path=None, mesh=None):
+                   mont_path=None, mesh=None):
     """Choose the BLS bring-up shape BEFORE any service starts.
 
     ``auto`` (the default) and ``supervised`` boot the node immediately
@@ -221,7 +207,6 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     if choice in ("auto", "supervised") and supervise:
         loader.configure("supervised")      # oracle serves from slot 0
         supervisor = loader.make_supervisor(mont_path=mont_path,
-                                            msm_path=msm_path,
                                             mesh=mesh)
         print("BLS implementation: pure (supervised device bring-up "
               "in background)")
@@ -229,7 +214,7 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     try:
         name = loader.configure("pure" if choice == "supervised"
                                 else choice, mont_path=mont_path,
-                                msm_path=msm_path, mesh=mesh)
+                                mesh=mesh)
     except loader.BlsLoadError as exc:
         raise SystemExit(f"BLS preflight failed: {exc}")
     where = "" if name == "pure" else f" on {loader.device_label()}"
@@ -256,10 +241,9 @@ def cmd_node(args) -> int:
     # + flight-recorder JSONL dump on fatal crash (infra/flightrecorder)
     from .infra import flightrecorder
     flightrecorder.install_crash_hooks()
-    mont_path, msm_path, mesh = _configure_kernel(args, yaml_cfg)
+    mont_path, mesh = _configure_kernel(args, yaml_cfg)
     _, bls_supervisor = _configure_bls(args, yaml_cfg,
-                                       mont_path=mont_path,
-                                       msm_path=msm_path, mesh=mesh)
+                                       mont_path=mont_path, mesh=mesh)
     network = layered_value("network", args.network, yaml_cfg, "minimal")
     port = int(layered_value("p2p-port", args.p2p_port, yaml_cfg, 0, int))
     rest_port = int(layered_value("rest-port", args.rest_port, yaml_cfg,
@@ -509,9 +493,9 @@ def cmd_devnet(args) -> int:
     _configure_log_format(args, {})
     _configure_tracing(args, {})
     _configure_overload(args, {})
-    mont_path, msm_path, mesh = _configure_kernel(args, {})
+    mont_path, mesh = _configure_kernel(args, {})
     _, bls_supervisor = _configure_bls(args, {}, mont_path=mont_path,
-                                       msm_path=msm_path, mesh=mesh)
+                                       mesh=mesh)
 
     async def run():
         net = Devnet(n_nodes=args.nodes, n_validators=args.validators)
@@ -808,9 +792,9 @@ def cmd_validator_client(args) -> int:
     # the VC's hot path is signing (host-side); no background bring-up
     _configure_log_format(args, {})
     _configure_tracing(args, {})
-    mont_path, msm_path, mesh = _configure_kernel(args, {})
+    mont_path, mesh = _configure_kernel(args, {})
     _configure_bls(args, {}, supervise=False, mont_path=mont_path,
-                   msm_path=msm_path, mesh=mesh)
+                   mesh=mesh)
     spec = create_spec(args.network or "minimal")
     remote = RemoteValidatorApi(spec, args.beacon_node)
     genesis = remote._get_json("/eth/v1/beacon/genesis")["data"]
@@ -962,10 +946,10 @@ def _doctor_probe_devnet(args) -> dict:
     from .infra import capacity as cap
     from .infra import dispatchledger, flightrecorder, timeline, tracing
 
-    mont_path, msm_path, mesh = _configure_kernel(args, {})
+    mont_path, mesh = _configure_kernel(args, {})
     try:
         loader.configure(args.bls_impl or "jax", mont_path=mont_path,
-                         msm_path=msm_path, mesh=mesh)
+                         mesh=mesh)
     except loader.BlsLoadError as exc:
         raise SystemExit(f"doctor probe: BLS preflight failed: {exc}")
 
@@ -1001,12 +985,11 @@ def cmd_doctor(args) -> int:
     """Explainability engine over the dispatch decision ledger: WHY is
     the latency budget being spent the way it is — cold compiles per
     shape, mesh shard makespan skew, padding waste per lane bucket,
-    H(m) cache coldness, an MSM path configured on a TPU against the
-    chip's reading, brownouts/sheds/SLO
-    burn — ranked, with every finding citing its evidence (dispatch
-    records by seq + trace id, flight-recorder events).  Reads a live
-    node via --url, or (default) runs a short live in-process devnet
-    on the real device provider and diagnoses it."""
+    H(m) cache coldness, brownouts/sheds/SLO burn — ranked, with every
+    finding citing its evidence (dispatch records by seq + trace id,
+    flight-recorder events).  Reads a live node via --url, or (default)
+    runs a short live in-process devnet on the real device provider and
+    diagnoses it."""
     from .infra import doctor
 
     _configure_log_format(args, {})
@@ -1120,11 +1103,6 @@ def cmd_precompile(args) -> int:
         raise SystemExit(f"invalid --mont-path {mont!r} (use one of "
                          f"{'/'.join(_MONT_PATHS)})")
     os.environ["TEKU_TPU_MONT_MUL"] = mont
-    msm_choice = str(args.msm_path).lower()
-    if msm_choice not in _MSM_PATHS:
-        raise SystemExit(f"invalid --msm-path {msm_choice!r} (use one "
-                         f"of {'/'.join(_MSM_PATHS)})")
-    os.environ["TEKU_TPU_MSM"] = msm_choice
     mesh_choice = _validate_mesh(str(args.mesh).lower())
     os.environ["TEKU_TPU_MESH"] = mesh_choice
     mesh_n = (int(mesh_choice)
@@ -1149,7 +1127,7 @@ def cmd_precompile(args) -> int:
     min_bucket = args.min_bucket or shapeset.SERVICE_MIN_BUCKET
     # constructing the provider registers the pk_validate dispatcher;
     # staged_jits() registers the stage dispatchers; the mesh kernel
-    # registers per msm path below
+    # registers below
     impl = JaxBls12381(max_batch=max_batch,
                        min_bucket=min_bucket, mesh=mesh_obj)
     V.staged_jits()
@@ -1163,7 +1141,7 @@ def cmd_precompile(args) -> int:
     t_all = _time.monotonic()
     for kernel, avals, meta in programs:
         if meta.get("stage") == "mesh_kernel":
-            impl._sharded.kernel(meta["msm_path"])
+            impl._sharded.kernel()
         disp = aotstore.dispatchers().get(kernel)
         if disp is None:
             print(f"  SKIP {kernel}: no registered dispatcher "
@@ -1282,19 +1260,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "earns mxu its place).  mxu on a non-TPU device "
                         "falls back to vpu with one warning.  Env: "
                         "TEKU_TPU_MONT_MUL")
-    n.add_argument("--msm-path", default=None,
-                   choices=["ladder", "pippenger", "auto"],
-                   help="scalars-stage engine for the batch-verify "
-                        "multiplier folds: ladder (per-lane windowed "
-                        "double-and-add), pippenger (GLV half-scalar "
-                        "split + windowed bucket MSM, one doubling "
-                        "chain per message group), auto (default: what "
-                        "the chip measured; on a TPU the ladder, 90 "
-                        "against 191 ms a dispatch at the mainnet "
-                        "committee shape, and pippenger only above "
-                        "2048 lanes at 8 or more a Miller row; off a "
-                        "TPU the ladder; see PERF.md section 6, PR "
-                        "29).  Env: TEKU_TPU_MSM")
     n.add_argument("--mesh", default=None, metavar="{off,auto,N}",
                    help="multi-chip verify mesh: off (default, "
                         "single-device dispatch), auto (largest pow-2 "
@@ -1332,8 +1297,6 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["auto", "supervised", "jax", "pure"])
     d.add_argument("--mont-path", default=None,
                    choices=["vpu", "mxu", "auto"])
-    d.add_argument("--msm-path", default=None,
-                   choices=["ladder", "pippenger", "auto"])
     d.add_argument("--mesh", default=None, metavar="{off,auto,N}")
     d.add_argument("--tracing", default=None, choices=["on", "off"])
     d.add_argument("--overload-control", default=None,
@@ -1390,8 +1353,6 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["auto", "supervised", "jax", "pure"])
     vc.add_argument("--mont-path", default=None,
                     choices=["vpu", "mxu", "auto"])
-    vc.add_argument("--msm-path", default=None,
-                    choices=["ladder", "pippenger", "auto"])
     vc.add_argument("--mesh", default=None, metavar="{off,auto,N}")
     vc.add_argument("--tracing", default=None, choices=["on", "off"])
     vc.add_argument("--log-format", default=None,
@@ -1451,8 +1412,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "device dispatch path)")
     dr.add_argument("--mont-path", default=None,
                     choices=list(_MONT_PATHS))
-    dr.add_argument("--msm-path", default=None,
-                    choices=list(_MSM_PATHS))
     dr.add_argument("--mesh", default=None,
                     help="probe devnet mesh spec (off, auto, or N)")
     dr.add_argument("--log-format", default=None,
@@ -1491,8 +1450,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="probe devnet BLS implementation")
     tl.add_argument("--mont-path", default=None,
                     choices=list(_MONT_PATHS))
-    tl.add_argument("--msm-path", default=None,
-                    choices=list(_MSM_PATHS))
     tl.add_argument("--mesh", default=None,
                     help="probe devnet mesh spec (off, auto, or N)")
     tl.add_argument("--log-format", default=None,
@@ -1540,9 +1497,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="mesh width to precompile for (off or N; "
                          "forces N virtual devices on CPU like `node "
                          "--mesh N`)")
-    pc.add_argument("--msm-path", default="auto", dest="msm_path",
-                    help="scalar-multiplication engine "
-                         f"({'/'.join(_MSM_PATHS)})")
     pc.add_argument("--mont-path", default="auto", dest="mont_path",
                     help="mont_mul engine "
                          f"({'/'.join(_MONT_PATHS)})")

@@ -59,30 +59,25 @@ class BlsLoadError(RuntimeError):
 
 
 def _probe_jax(max_batch: int, min_bucket: int, mont_path=None,
-               msm_path=None, mesh=None):
+               mesh=None):
     """Instantiate the device provider and prove the backend executes:
     one pubkey-validation dispatch (the small program; the five staged
     verify programs compile lazily on first real batch).
 
     `mont_path` installs the process-global mont_mul engine choice
-    (vpu | mxu | auto, ops/mxu.py) and `msm_path` the scalars-stage
-    choice (ladder | pippenger | auto, ops/msm.py) BEFORE any kernel
-    traces — the seams the CLI's `--mont-path`/`--msm-path` thread
-    through.  `mesh` (off | auto | N, CLI `--mesh` / TEKU_TPU_MESH;
-    None reads the env) resolves to the largest pow-2 device count
+    (vpu | mxu | auto, ops/mxu.py) BEFORE any kernel traces — the
+    seam the CLI's `--mont-path` threads through.  `mesh` (off | auto |
+    N, CLI `--mesh` / TEKU_TPU_MESH; None reads the env) resolves to the largest pow-2 device count
     available (teku_tpu/parallel.resolve_mesh_devices — an
     over-ambitious N demotes with one WARN, never fails bring-up) and
     constructs JaxBls12381(mesh=...) so production dispatches shard
     group-aligned across the chips.  The warmup batches downstream
-    then compile the resolved (mesh x scalars-path) shape set off the
-    gossip path."""
-    from ...ops import msm, mxu
+    then compile the resolved shape set off the gossip path."""
+    from ...ops import mxu
     from ...ops.provider import JaxBls12381
 
     if mont_path is not None:
         mxu.set_path(mont_path)
-    if msm_path is not None:
-        msm.set_path(msm_path)
     if mesh is None:
         mesh = env_str("TEKU_TPU_MESH", "off")
     from ... import parallel
@@ -563,7 +558,6 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
                     registry: MetricsRegistry = GLOBAL_REGISTRY,
                     breaker: Optional[CircuitBreaker] = None,
                     warm: bool = True, mont_path: Optional[str] = None,
-                    msm_path: Optional[str] = None,
                     mesh: Optional[str] = None,
                     **supervisor_kw) -> BackendSupervisor:
     """Build the production BackendSupervisor: boot-on-oracle now,
@@ -597,7 +591,7 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
 
     def probe():
         return _probe_jax(max_batch, min_bucket, mont_path=mont_path,
-                          msm_path=msm_path, mesh=mesh)
+                          mesh=mesh)
 
     def warmup(backend):
         if not warm:
@@ -773,7 +767,6 @@ def configure(choice: str = "auto", *, max_batch: int = 256,
               min_bucket: int = 16,
               probe_timeout_s: Optional[float] = None,
               mont_path: Optional[str] = None,
-              msm_path: Optional[str] = None,
               mesh: Optional[str] = None) -> str:
     """Install the BLS provider for this process; returns its name.
 
@@ -802,8 +795,7 @@ def configure(choice: str = "auto", *, max_batch: int = 256,
     def run():
         try:
             result["ok"] = _probe_jax(max_batch, min_bucket,
-                                      mont_path=mont_path,
-                                      msm_path=msm_path, mesh=mesh)
+                                      mont_path=mont_path, mesh=mesh)
         except BaseException as exc:  # noqa: BLE001 - report any failure
             result["err"] = exc
 

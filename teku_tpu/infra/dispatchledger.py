@@ -2,13 +2,12 @@
 
 Every verify dispatch is the product of a stack of runtime decisions —
 the admission controller's batch plan and brownout level, the dedup
-grouping and H(m) cache state, the MSM path resolution, the mesh shard
-plan, pow-2 bucket padding, and the compile-vs-cache outcome — but
-until this module nothing tied them together: when the
-``attestation_verify_p50`` budget burns, the SLO engine blames a trace
-id while the REASONS (a new shape compiled cold, a shard's makespan
-skewed, padding waste spiked, which MSM path ran) were scattered across
-logs, gauges, and WARNs.
+grouping and H(m) cache state, the mesh shard plan, pow-2 bucket
+padding, and the compile-vs-cache outcome — but until this module
+nothing tied them together: when the ``attestation_verify_p50`` budget
+burns, the SLO engine blames a trace id while the REASONS (a new shape
+compiled cold, a shard's makespan skewed, padding waste spiked) were
+scattered across logs, gauges, and WARNs.
 
 This is the ordered record: a process-global bounded ring of
 structured per-dispatch records, populated by
@@ -23,8 +22,6 @@ device time, verdict).  Each record captures:
   unique-h2c/Miller row bucket the dedup pipeline pays) plus the
   per-dispatch dedup ratio;
 - H(m) arena hits/misses and the h2c dispatch bucket actually paid;
-- the resolved msm path AND why (``ops/msm.py:explain`` — the auto
-  rule's inputs);
 - the resolved mesh plan (device count, per-shard row/lane loads,
   makespan ratio = max shard lane load / mean);
 - the compile outcome (compile | cache_load | aot_load | cache_hit)
@@ -56,10 +53,9 @@ Derived bounded-label metrics (linted in test_metrics_exposition):
   the lane series is the pre-PR-13 unlabeled gauge's semantics);
 - ``bls_mesh_shard_imbalance_ratio`` — the most recent mesh
   dispatch's makespan ratio (1.0 = perfectly balanced shards);
-- ``bls_dispatch_decision_total{msm_path,mesh,plan_mode}`` — the
-  decision histogram (closed vocabularies: {ladder, pippenger} x
-  {0, pow-2 device counts} x {none, latency, throughput, brownout1,
-  brownout2}).
+- ``bls_dispatch_decision_total{mesh,plan_mode}`` — the decision
+  histogram (closed vocabularies: {0, pow-2 device counts} x {none,
+  latency, throughput, brownout1, brownout2}).
 
 The ring is served by ``GET /teku/v1/admin/dispatches`` (``?last=N``,
 ``?trace_id=``, ``?slow=1``), summarized per bench phase into
@@ -136,14 +132,13 @@ def plan_mode_label(mode: Optional[str], brownout_level) -> str:
     return mode if mode in ("latency", "throughput") else "none"
 
 
-def decision_key(rec: dict) -> Tuple[str, str, str]:
-    """ONE definition of a record's (msm_path, mesh devices,
-    plan_mode) decision tuple — the bls_dispatch_decision_total label
+def decision_key(rec: dict) -> Tuple[str, str]:
+    """ONE definition of a record's (mesh devices, plan_mode)
+    decision tuple — the bls_dispatch_decision_total label
     set AND the summarize() decisions histogram key; a second
     hand-rolled copy would let the Prometheus series and the
     endpoint/bench histograms silently diverge."""
-    return (str((rec.get("msm") or {}).get("path", "ladder")),
-            str((rec.get("mesh") or {}).get("devices", 0) or 0),
+    return (str((rec.get("mesh") or {}).get("devices", 0) or 0),
             plan_mode_label(
                 (rec.get("admission") or {}).get("plan_mode"),
                 (rec.get("admission") or {}).get("brownout_level")))
@@ -183,9 +178,9 @@ class DispatchLedger:
             "dispatch yet", supplier=lambda: self._last_imbalance)
         self._m_decision = registry.labeled_counter(
             "bls_dispatch_decision_total",
-            "verify dispatches by resolved decision tuple: scalars "
-            "path x mesh device count x admission plan mode",
-            labelnames=("msm_path", "mesh", "plan_mode"))
+            "verify dispatches by resolved decision tuple: mesh "
+            "device count x admission plan mode",
+            labelnames=("mesh", "plan_mode"))
 
     # ------------------------------------------------------------------
     def record(self, rec: dict) -> dict:
@@ -213,10 +208,9 @@ class DispatchLedger:
                 ratio = mesh.get("makespan_ratio")
                 if isinstance(ratio, (int, float)) and ratio > 0:
                     self._last_imbalance = float(ratio)
-        msm_path, mesh_devices, plan_mode = decision_key(rec)
+        mesh_devices, plan_mode = decision_key(rec)
         self._m_decision.labels(
-            msm_path=msm_path, mesh=mesh_devices,
-            plan_mode=plan_mode).inc()
+            mesh=mesh_devices, plan_mode=plan_mode).inc()
         return rec
 
     @property

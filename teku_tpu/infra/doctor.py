@@ -236,36 +236,6 @@ def _h2c_findings(records: List[dict], summary: dict) -> List[dict]:
                  "dedup_ratio": dedup})]
 
 
-def _msm_findings(records: List[dict]) -> List[dict]:
-    """On a TPU `msm.explain()` records the path the chip `measured`
-    faster at the dispatch's lane count, and `auto` takes it: a
-    dispatch on the other path was configured there by hand."""
-    against = []
-    for r in records:
-        msm = r.get("msm") or {}
-        measured = (msm.get("why") or {}).get("measured")
-        if measured and msm.get("path") != measured:
-            against.append(r)
-    if not against:
-        return []
-    msm = against[-1].get("msm") or {}
-    why = msm.get("why") or {}
-    return [_finding(
-        "msm_path_against_measurement",
-        ATTENTION_SEVERITY + min(len(against), 15),
-        f"msm path {msm.get('path')} on {len(against)} TPU dispatch(es) "
-        f"where the chip measured {why.get('measured')} faster "
-        f"({why.get('rule')})",
-        "on the TPU the ladder measured about twice as fast as the "
-        "GLV+Pippenger bucketed MSM at every served shape (90 against "
-        "191 ms a dispatch at the mainnet committee shape) and slower "
-        "only far above them (PERF.md §6, PR 29; the table in "
-        "ops/msm.py); `--msm-path auto` follows that measurement, "
-        "`--msm-path` / TEKU_TPU_MSM set to a path overrides it",
-        evidence=[_cite(r) for r in against[:3]],
-        metrics={"dispatches": len(against), "why": why})]
-
-
 def _mesh_health_findings(events: List[dict],
                           records: List[dict],
                           mesh: Optional[dict] = None) -> List[dict]:
@@ -627,7 +597,6 @@ def diagnose(records: List[dict],
     findings += _imbalance_findings(records)
     findings += _padding_findings(records, summary)
     findings += _h2c_findings(records, summary)
-    findings += _msm_findings(records)
     findings += _mesh_health_findings(flight_events or [], records,
                                       mesh=mesh)
     findings += _flight_findings(flight_events or [], records)

@@ -45,7 +45,8 @@ device time on any host.
 
 import asyncio
 import time
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 from ..crypto import bls, kzg
 from ..crypto.bls.loader import GuardedBls12381
@@ -164,6 +165,39 @@ class MeshModelDevice(DedupAwareDevice):
         return super().batch_verify(triples)
 
 
+def _settle(done: Callable[[], bool], what: str,
+            timeout_s: float = 5.0) -> None:
+    """Wait, in REAL time and with the virtual clock standing, for the
+    healer's threads to finish a step the model counts as instant.
+    The healer runs on the wall clock (its probes are a fault-site
+    check each, its reprobe interval 50 ms) while the traffic runs on
+    the virtual one; left to race, the number of dispatches that meet
+    the wedged mesh, and the virtual instant the mesh grows back, go
+    by how fast this machine schedules those threads, and every count
+    of the report moves with the load on the box."""
+    deadline = time.monotonic() + timeout_s
+    while not done():
+        if time.monotonic() > deadline:
+            raise RuntimeError(f"loadgen chaos: {what} did not finish "
+                               f"within {timeout_s:.0f}s of wall time")
+        time.sleep(0.001)
+
+
+class _FallbackOracle(DedupAwareDevice):
+    """The oracle a failed mesh dispatch falls to mid-heal.  Its
+    modeled cost is twenty times the mesh's, a heal sweep's a few
+    fault-site checks: the sweep is over when the oracle answers, so
+    exactly the dispatches the fault schedule fails pay the cliff."""
+
+    healer = None
+
+    def batch_verify(self, triples) -> bool:
+        ok = super().batch_verify(triples)
+        if self.healer is not None:
+            _settle(lambda: not self.healer.healing, "the heal sweep")
+        return ok
+
+
 class ModelKzgBackend:
     """Stand-in KZG device: one virtual-time dispatch per blob batch,
     fed through the REAL ``crypto/kzg.py`` facade so its arrival
@@ -246,7 +280,7 @@ async def _run_scenario(scenario: Scenario, seed: int, slots: int,
         # the last-resort cliff a wedged dispatch falls to mid-heal:
         # same verdict rule, oracle (~CPU) speed — the very cliff
         # self-healing exists to avoid paying for the whole mesh
-        oracle = DedupAwareDevice(
+        oracle = _FallbackOracle(
             clock, telemetry, lane_sigs_per_sec=base_lane / 20,
             h2c_msgs_per_sec=base_h2c / 20, completed_at=completed_at)
         breaker = CircuitBreaker(
@@ -270,7 +304,7 @@ async def _run_scenario(scenario: Scenario, seed: int, slots: int,
             make_backend=make_backend, install=heal_install,
             trip_threshold=1, probe_deadline_s=1.0, reprobe_s=0.05,
             registry=registry, recorder=recorder)
-        guarded.healer = healer
+        guarded.healer = oracle.healer = healer
         impl = guarded
     else:
         device = DedupAwareDevice(
@@ -338,6 +372,12 @@ async def _run_scenario(scenario: Scenario, seed: int, slots: int,
                     times=ce.times, key=f"vdev{ce.device}"))
             else:
                 faults.clear(selfheal.FAULT_SITE)
+                # re-admission is one reprobe interval away: instant
+                # on the scenario's scale, so the mesh is whole again
+                # before virtual time moves on
+                _settle(lambda: not healer.ledger.ejected()
+                        and len(healer.live_devices)
+                        == scenario.mesh_devices, "the mesh's regrowth")
             chaos_log.append({"t": round(clock() - t_start, 3),
                               "action": ce.action,
                               "device": ce.device})
@@ -469,20 +509,6 @@ async def _run_scenario(scenario: Scenario, seed: int, slots: int,
             clock.advance(max(telemetry.window_s / 4,
                               controller.tick_s))
             controller.tick()
-        if healer is not None and chaos_idx >= len(chaos):
-            # the schedule cleared its faults: give the background
-            # reprobe (real time) a bounded window to readmit and grow
-            # the mesh back, so the report shows the full cycle.  The
-            # gate is the LIVE width (the grow INSTALL), not the
-            # ledger — readmit precedes the grow reshape in the
-            # reprobe loop, and exiting between the two would build
-            # the report with reshapes.grow still 0
-            total = scenario.mesh_devices
-            t_wait = time.monotonic() + 5.0
-            while (healer.ledger.ejected()
-                   or len(healer.live_devices) < total) \
-                    and time.monotonic() < t_wait:
-                await asyncio.sleep(0.02)
         await svc.stop()
     finally:
         if scenario.chaos:

@@ -363,7 +363,7 @@ def scalar_mul_static(k: FieldKit, e: int, p):
 
 def point_batch_sum(k: FieldKit, p):
     """Sum points over the leading batch axis via log-depth pairwise
-    adds.  (Lives here so the MSM kernels (ops/msm.py) and the verify
+    adds.  (Lives here so the KZG kernels (ops/kzg.py) and the verify
     pipeline (ops/verify.py) share one reduction.)"""
     return T.tree_fold_pairs(lambda a, b: point_add(k, a, b), p)
 

@@ -33,10 +33,10 @@ unguarded provider's async seam (both halves now, the sync later).
 MESH: constructed with mesh=..., dispatches shard GROUP-ALIGNED
 across the chips (teku_tpu/parallel.GroupShardedVerifier): whole
 message-group rows per shard, lanes permuted to follow their rows, so
-the dedup pipeline (unique-message h2c, grouped Miller rows, the
-Pippenger MSM) survives the mesh; one all_gather of per-device
-partials crosses the ICI and the verdict contract is unchanged
-(lane_ok un-permutes at the sync point).
+the dedup pipeline (unique-message h2c, grouped Miller rows)
+survives the mesh; one all_gather of per-device partials crosses the
+ICI and the verdict contract is unchanged (lane_ok un-permutes at the
+sync point).
 
 Batch sizes (and the per-lane key-count axis) are padded to powers of
 two so the jit cache stays small and shapes stay static (XLA recompiles
@@ -68,7 +68,6 @@ from ..crypto.bls.spi import (BLS12381, BatchSemiAggregate,
                               PreparedDispatch, ResolvedHandle)
 from . import h2c_cache as HC
 from . import limbs as fp
-from . import msm
 from . import mxu
 from . import points as PT
 from . import verify as V
@@ -111,21 +110,6 @@ _M_H2C_UNIQUE = GLOBAL_REGISTRY.counter(
 _M_H2C_DISPATCH = GLOBAL_REGISTRY.counter(
     "bls_h2c_dispatch_total",
     "hash-to-curve device dispatches (0 growth = H(m) cache warm)")
-
-# MSM scalars-stage path observability: every verify dispatch resolves
-# to the per-lane windowed ladder or the GLV+Pippenger bucketed MSM
-# (ops/msm.py resolve(); `auto` is shape-aware), and capacity planning
-# needs the lane split, not just the dispatch split — the closed
-# {ladder, pippenger} vocabulary is linted in test_metrics_exposition
-_M_MSM = GLOBAL_REGISTRY.labeled_counter(
-    "bls_msm_dispatch_total",
-    "verify dispatches by resolved scalars-stage path "
-    "(ladder|pippenger, ops/msm.py)",
-    labelnames=("path",))
-_M_MSM_LANES = GLOBAL_REGISTRY.labeled_counter(
-    "bls_msm_lanes_total",
-    "real lanes dispatched by resolved scalars-stage path",
-    labelnames=("path",))
 
 # Mesh observability: sharded dispatches labeled by device count (a
 # closed pow-2 vocabulary — the resolver only ever yields pow-2 mesh
@@ -341,7 +325,7 @@ class _DispatchHandle:
 class _Packed(types.SimpleNamespace):
     """One dispatch's host half (`JaxBls12381._pack`): the launches'
     arguments as numpy arrays, the shape decisions (`kmax`, `padded`,
-    rows and buckets, the mesh `plan`, `msm_path`) and what the ledger
+    rows and buckets, the mesh `plan`) and what the ledger
     record says of them.  Nothing in it has touched the device.
     H(m) is resolved once a distinct MESSAGE: `digests` is None with
     the arena off or bypassed (`draws` is then the padded h2c input
@@ -450,9 +434,9 @@ class JaxBls12381(BLS12381):
         self.max_batch = max_batch
         # optional multi-chip dispatch: GROUP-ALIGNED sharding over the
         # mesh's dp axis — every shard owns whole message-group rows,
-        # so the dedup pipeline (unique-message Miller grouping, the
-        # Pippenger MSM) survives the mesh; partial products ride one
-        # all_gather (teku_tpu/parallel.GroupShardedVerifier)
+        # so the dedup pipeline (unique-message Miller grouping)
+        # survives the mesh; partial products ride one all_gather
+        # (teku_tpu/parallel.GroupShardedVerifier)
         self._sharded = None
         self.mesh_info = None
         if mesh is not None:
@@ -510,9 +494,6 @@ class JaxBls12381(BLS12381):
         # the dispatch metric labels with this, not a re-resolution
         # (a mid-process set_path() affects only not-yet-traced shapes)
         self.mont_path = mxu.resolve()
-        # per-provider MSM path evidence (the parity/auto tests read
-        # this; the global bls_msm_* counters serve dashboards)
-        self.msm_dispatches = {"ladder": 0, "pippenger": 0}
 
     # ------------------------------------------------------------------
     # Host-side SPI ops delegated to the oracle (rare, non-batch paths)
@@ -985,40 +966,17 @@ class JaxBls12381(BLS12381):
                 row_gather[pos] = r
         sx1 = bytes_to_limbs_np(sig_bytes[:, 0])
         sx0 = bytes_to_limbs_np(sig_bytes[:, 1])
-        # scalars-stage path: the per-lane windowed ladder (64-bit
-        # multipliers) or the GLV+Pippenger bucketed MSM (32-bit
-        # half-scalar pairs, ops/msm.py).  Resolved per dispatch —
-        # `auto` goes by the device, the lanes and the lanes a row,
-        # as the chip measured.  The GROUP-ALIGNED mesh kernel
-        # supports both (groups never cross shards);
-        # msm.resolve(sharded=True)
-        # remains the LEGACY lane-sharded kernel's always-ladder
-        # contract and is not used here
-        msm_path, msm_why = msm.explain(lanes=n, rows=len(rows))
         if randomize:
             # one os-entropy draw for the whole batch (the
             # reference uses SecureRandom per multiplier,
             # BlstBLS12381.java:191-195); zero multipliers are
-            # nudged to 1 (2^-64 bias, negligible) — on the
-            # pippenger path the same 64 bits split into the
-            # (k1, k2) half-scalars whose effective multiplier
-            # k1 + k2*lambda ranges over 2^64 - 1 values
+            # nudged to 1 (2^-64 bias, negligible)
             raw = np.frombuffer(secrets.token_bytes(8 * padded),
                                 dtype=np.uint64).copy()
-            if msm_path == "pippenger":
-                scalars = msm.glv_digits_np(
-                    *msm.glv_sample_from_uint64(raw))
-            else:
-                raw[raw == 0] = 1
-                scalars = PT.scalar_bits_np(raw)
-        elif msm_path == "pippenger":
-            # r = 1 exactly: (k1, k2) = (1, 0)
-            scalars = msm.glv_digits_np(
-                np.ones(padded, dtype=np.uint64),
-                np.zeros(padded, dtype=np.uint64))
+            raw[raw == 0] = 1
         else:
-            scalars = PT.scalar_bits_np(
-                np.ones(padded, dtype=np.uint64))
+            raw = np.ones(padded, dtype=np.uint64)
+        r_bits = PT.scalar_bits_np(raw)
         digests, draws = self._hm_host(uniq_msgs)
         # the timeline's host-prep interval: the serial host-side term
         # host_prep_serial_share is computed from (subtracting any
@@ -1035,8 +993,7 @@ class JaxBls12381(BLS12381):
             sx=(sx0, sx1), s_large=s_large, s_inf=s_inf,
             lane_valid=lane_valid, group_idx=group_idx,
             group_present=group_present, row_gather=row_gather,
-            row_msg=row_msg,
-            msm_path=msm_path, msm_why=msm_why, scalars=scalars,
+            row_msg=row_msg, r_bits=r_bits,
             digests=digests, draws=draws)
 
     def _launch(self, pack: "_Packed",
@@ -1086,7 +1043,7 @@ class JaxBls12381(BLS12381):
             timeline.interval(
                 "worker", "host_prep", time.perf_counter() - t_prep0,
                 t_mono=t_prep0, trace_id=tracing.current_trace_id())
-        plan, padded, msm_path = pack.plan, pack.padded, pack.msm_path
+        plan, padded = pack.plan, pack.padded
         mesh_n = (self._sharded.n_devices
                   if self._sharded is not None else 0)
         # mesh dispatches get their own shape family (the capacity
@@ -1097,12 +1054,11 @@ class JaxBls12381(BLS12381):
         shape = SS.shape_label(padded, pack.kmax, mesh_n)
         # the staged jits are module-level (shared across providers)
         # and the sharded kernels are process-memoized by (device set,
-        # axis, msm path) — key the seen-set on the kernel identity
-        # that will actually serve the dispatch, so a reshaped
-        # provider over known devices reads cache_hit, not compile
-        cache_key = (self._sharded.kernel_key(msm_path)
-                     if self._sharded is not None else 0,
-                     shape, msm_path)
+        # axis) — key the seen-set on the kernel identity that will
+        # actually serve the dispatch, so a reshaped provider over
+        # known devices reads cache_hit, not compile
+        cache_key = (self._sharded.kernel_key()
+                     if self._sharded is not None else 0, shape)
         with _SEEN_LOCK:
             first = cache_key not in _SEEN_SHAPES
             _SEEN_SHAPES.add(cache_key)
@@ -1115,9 +1071,6 @@ class JaxBls12381(BLS12381):
         aot_before = aotstore.stats() if first else None
         _M_H2C_LANES.inc(n)
         _M_H2C_UNIQUE.inc(pack.n_unique)
-        _M_MSM.labels(path=msm_path).inc()
-        _M_MSM_LANES.labels(path=msm_path).inc(n)
-        self.msm_dispatches[msm_path] += 1
         if mesh_n:
             _M_MESH_DISPATCH.labels(devices=str(mesh_n)).inc()
         # device section: every launch below is async (XLA compiles
@@ -1156,7 +1109,9 @@ class JaxBls12381(BLS12381):
             waste={"lane": {"real": n, "padded": padded},
                    "h2c": {"real": pack.n_rows, "padded": pack.u_total}},
             h2c=h2c_stats,
-            msm={"path": msm_path, "why": pack.msm_why},
+            # one scalars stage; the field stays because the
+            # benchmark's set-up log reads it (ROADMAP C15)
+            msm={"path": "ladder"},
             mesh=mesh_block, prep=prep)
         if reasons:
             rec["prep_reason"] = "+".join(reasons)
@@ -1180,17 +1135,15 @@ class JaxBls12381(BLS12381):
                 # kernel runs the full dedup pipeline per shard
                 hm_rows = V.staged_jits()["gather"](
                     hm_uniq, jnp.asarray(pack.row_gather))
-                kernel = self._sharded.kernel(msm_path)
+                kernel = self._sharded.kernel()
                 hm_in = hm_rows
             else:
-                kernel = (V.verify_staged_pippenger
-                          if msm_path == "pippenger"
-                          else V.verify_staged_grouped)
+                kernel = V.verify_staged_grouped
                 hm_in = hm_uniq
             ok, lane_ok = kernel(
                 pack.pk_xs, pack.pk_ys, pack.pk_present, hm_in,
                 pack.group_idx, pack.group_present, pack.sx, pack.s_large,
-                pack.s_inf, pack.scalars, pack.lane_valid)
+                pack.s_inf, pack.r_bits, pack.lane_valid)
             enqueued = True
         finally:
             if first:
@@ -1219,16 +1172,7 @@ class JaxBls12381(BLS12381):
                 rec["device"] = {"enqueue_error": True}
                 rec["verdict"] = None
                 dispatchledger.record(rec)
-        # the capacity model's per-(shape, path) latency series must
-        # distinguish the scalars engine: SAME-shape dispatches can
-        # run ladder or pippenger (the path is a run-time choice,
-        # `msm.set_path`), and blending two programs ~2x apart into
-        # one series would mis-model device time for the admission
-        # controller's batch planner.  The jit metric above keeps the
-        # plain mont vocabulary (its label contract is linted).
-        lat_path = (mont_path if msm_path == "ladder"
-                    else f"{mont_path}+pip")
         return _DispatchHandle(ok, lane_ok, n, traces, shape,
-                               lat_path, t_enq_end,
+                               mont_path, t_enq_end,
                                lane_sel=pack.lane_pos, rec=rec,
                                marks=marks)

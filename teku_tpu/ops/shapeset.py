@@ -5,7 +5,7 @@ Every observability layer points at the compile wall (PERF.md:
 cache-load finding) — and the fix requires knowing EXACTLY which
 programs serving will dispatch.  This module is that registry: the
 pow-2 bucket policy (lane bucket x unique-h2c bucket x group cap x
-msm path x mont path x mesh width) as pure functions, plus the
+mont path x mesh width) as pure functions, plus the
 enumeration of (kernel, argument avals) pairs the warmup/serving path
 traces — the input to ``cli precompile`` and the coverage oracle for
 the doctor's ``cold_compile_on_hot_path`` finding.
@@ -143,13 +143,14 @@ def batch_plan(lane_groups: Sequence[int], *, min_bucket: int,
         u_total = u_hm
         lanes_per_shard = rows_per_shard = None
     missing = len(lane_groups) if h2c_missing is None else h2c_missing
-    from . import msm
-    msm_path, _why = msm.explain(lanes=lanes, rows=len(rows))
     return {
         "lanes": lanes, "kmax": kmax, "rows": len(rows),
         "messages": len(lane_groups), "h2c_missing": missing,
         "group_bucket": g_bucket, "u_hm": u_hm, "padded": padded,
-        "u_total": u_total, "msm_path": msm_path,
+        "u_total": u_total,
+        # one scalars stage; the key stays because the benchmark's
+        # shape signature reads it (ROADMAP C15)
+        "msm_path": "ladder",
         "mesh_devices": mesh_devices if mesh_devices >= 2 else 0,
         "lanes_per_shard": lanes_per_shard,
         "rows_per_shard": rows_per_shard,
@@ -218,21 +219,6 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
 
 
-def _scalars_aval(padded: int, msm_path: str):
-    """The scalars-stage argument aval: r_bits on the ladder path,
-    GLV digit arrays on the pippenger path — derived from the real
-    converters so a digit-layout change cannot drift the registry."""
-    import numpy as np
-    if msm_path == "pippenger":
-        from . import msm
-        probe = msm.glv_digits_np(np.ones(1, dtype=np.uint64),
-                                  np.zeros(1, dtype=np.uint64))
-    else:
-        from . import points as PT
-        probe = PT.scalar_bits_np(np.ones(1, dtype=np.uint64))
-    return _sds((padded,) + probe.shape[1:], probe.dtype)
-
-
 def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
                        min_bucket: int = SERVICE_MIN_BUCKET,
                        kmax: int = 1,
@@ -294,7 +280,7 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
                           mesh_devices=mesh_devices,
                           h2c_missing=h2c_missing)
         meta = {"profile": name, "shape": plan["shape"],
-                "msm_path": plan["msm_path"], "mont_path": mont}
+                "mont_path": mont}
         P, K, U, G = (plan["padded"], plan["kmax"], plan["u_total"],
                       plan["group_bucket"])
         # the h2c program over this profile's arena misses
@@ -315,7 +301,7 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
             _sds((P, K), b_),
             (_sds((P, fp.L), i64), _sds((P, fp.L), i64)),
             _sds((P,), b_), _sds((P,), b_), _sds((P,), b_))
-        scalars = _scalars_aval(P, plan["msm_path"])
+        r_bits = _sds((P, 64), i64)
         group_idx = _sds((U, G), i32)
         group_present = _sds((U, G), b_)
         if mesh_devices >= 2:
@@ -330,13 +316,12 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
                 yield out
             from .. import parallel
             kern = parallel.kernel_store_name(
-                [str(d) for d in np.ravel(mesh.devices)], axis,
-                plan["msm_path"])
+                [str(d) for d in np.ravel(mesh.devices)], axis)
             sig_x = (_sds((P, fp.L), i64), _sds((P, fp.L), i64))
             out = emit(kern, (
                 prepare_in[0], prepare_in[1], prepare_in[2], hm_rows,
                 group_idx, group_present, sig_x, _sds((P,), b_),
-                _sds((P,), b_), scalars, _sds((P,), b_)),
+                _sds((P,), b_), r_bits, _sds((P,), b_)),
                 {**meta, "stage": "mesh_kernel", "axis": axis,
                  "devices": mesh_devices})
             if out:
@@ -348,28 +333,18 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
             yield out
         prep_out = jax.eval_shape(V.stage_prepare, *prepare_in)
         pk_jac, sig_jac, _lane_ok, miller_mask = prep_out
-        if plan["msm_path"] == "pippenger":
-            pip_in = (pk_jac, sig_jac, scalars, group_idx,
-                      group_present, miller_mask)
-            out = emit(stage_name("scalars_pip"), pip_in,
-                       {**meta, "stage": "scalars_pip"})
-            if out:
-                yield out
-            agg_aff, u_mask, wsig = jax.eval_shape(
-                V.stage_scalars_pippenger, *pip_in)
-        else:
-            sc_in = (pk_jac, sig_jac, scalars)
-            out = emit(stage_name("scalars"), sc_in,
-                       {**meta, "stage": "scalars"})
-            if out:
-                yield out
-            pk_r_jac, wsig = jax.eval_shape(V.stage_scalars, *sc_in)
-            grp_in = (pk_r_jac, miller_mask, group_idx, group_present)
-            out = emit(stage_name("group"), grp_in,
-                       {**meta, "stage": "group"})
-            if out:
-                yield out
-            agg_aff, u_mask = jax.eval_shape(V.stage_group, *grp_in)
+        sc_in = (pk_jac, sig_jac, r_bits)
+        out = emit(stage_name("scalars"), sc_in,
+                   {**meta, "stage": "scalars"})
+        if out:
+            yield out
+        pk_r_jac, wsig = jax.eval_shape(V.stage_scalars, *sc_in)
+        grp_in = (pk_r_jac, miller_mask, group_idx, group_present)
+        out = emit(stage_name("group"), grp_in,
+                   {**meta, "stage": "group"})
+        if out:
+            yield out
+        agg_aff, u_mask = jax.eval_shape(V.stage_group, *grp_in)
         mil_in = (agg_aff, hm_uniq, u_mask)
         out = emit(stage_name("miller"), mil_in,
                    {**meta, "stage": "miller"})

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from ..crypto.bls import curve as C
 from . import h2c
 from . import limbs as fp
-from . import msm as MSM
 from . import pairing as PR
 from . import points as PT
 from . import towers as T
@@ -49,7 +48,7 @@ _NEG_G1_X = np.asarray(fp.int_to_mont(_NEG_G1[0]))
 _NEG_G1_Y = np.asarray(fp.int_to_mont(_NEG_G1[1]))
 
 
-# shared with the MSM kernels; re-exported for the KZG/parallel callers
+# re-exported for the KZG/parallel callers
 point_batch_sum = PT.point_batch_sum
 
 
@@ -121,49 +120,12 @@ def _finish(ml_prod, s_sum):
     return PR.pairing_check(f)
 
 
-def verify_kernel(pk_xs, pk_ys, pk_present, u0, u1, group_idx,
-                  group_present, sig_x_plain, sig_large, sig_inf,
-                  r_bits, lane_valid):
-    """The batched verification dispatch (single device), dedup-aware.
-
-    pk_xs/pk_ys: (N, K, L) Montgomery limbs — per-triple pubkeys, each
-        already validated (subgroup, non-infinity) by the caller's
-        cache, padded to K along axis 1; aggregation happens in-kernel.
-    u0/u1: Fq2 draws of the batch's UNIQUE messages' hash_to_field
-        (host SHA-256), padded to a pow-2 bucket U <= N — h2c runs at
-        unique width, not lane width.
-    group_idx/group_present: (U, G) lane indices/mask of each unique
-        message's lanes (stage_group: bilinearity folds those lanes
-        into one Miller loop per unique).
-    pk_present: (N, K) — False for key-padding slots.
-    sig_x_plain: ((N, L), (N, L)) plain-form Fq2 x of each signature;
-    sig_large: (N,) wire sign bit; sig_inf: (N,) infinity-signature mask.
-    r_bits: (N, 64) bits of the nonzero random multipliers, MSB first.
-    lane_valid: (N,) — False for padding lanes.
-
-    Returns (ok, lane_ok): ok is the whole-batch pairing verdict;
-    lane_ok flags lanes whose signature failed decompression/subgroup
-    checks or whose keys aggregated to infinity (the caller must AND
-    `ok` with all valid lanes' lane_ok).
-    """
-    hm_uniq = stage_h2c(u0, u1)
-    pk_jac, sig_jac, lane_ok, miller_mask = stage_prepare(
-        pk_xs, pk_ys, pk_present, sig_x_plain, sig_large, sig_inf,
-        lane_valid)
-    pk_r_jac, wsig = stage_scalars(pk_jac, sig_jac, r_bits)
-    agg_aff, u_mask = stage_group(pk_r_jac, miller_mask, group_idx,
-                                  group_present)
-    ml = stage_miller(agg_aff, hm_uniq, u_mask)
-    ok = _finish(PR.batch_product(ml), point_batch_sum(PT.G2_KIT, wsig))
-    return ok, lane_ok
-
-
 # --------------------------------------------------------------------------
-# Staged variant: the SAME math as verify_kernel, split into five
-# separately-jitted programs.  The monolithic kernel's TPU-XLA compile is
-# unbounded in practice (>60 min observed on v5e); each stage compiles in
-# minutes, caches independently in the persistent compile cache, and the
-# chain keeps all intermediates on device.
+# The staged programs: the verification split into separately-jitted
+# stages.  One monolithic kernel's TPU-XLA compile is unbounded in
+# practice (>60 min observed on v5e); each stage compiles in minutes,
+# caches independently in the persistent compile cache, and the chain
+# keeps all intermediates on device.
 # --------------------------------------------------------------------------
 
 def stage_prepare(pk_xs, pk_ys, pk_present, sig_x_plain, sig_large,
@@ -203,10 +165,7 @@ def stage_scalars(pk_jac, sig_jac, r_bits):
     stage_group, whichever path runs).
 
     `wsig` comes back as the SUM of the weighted signatures, a
-    (1,)-batched point: stage_finish only ever consumes that sum, and
-    the MSM path (stage_scalars_pippenger) hands it the same shape, so
-    ONE stage_finish program serves both scalars paths (its TPU compile
-    is the second longest of the set, PERF.md "On the chip")."""
+    (1,)-batched point: stage_finish only ever consumes that sum."""
     pk_r_jac = PT.scalar_mul_bits(PT.G1_KIT, r_bits, pk_jac)
     wsig = point_batch_sum(
         PT.G2_KIT, PT.scalar_mul_bits(PT.G2_KIT, r_bits, sig_jac))
@@ -250,26 +209,6 @@ def stage_group(pk_r_jac, miller_mask, group_idx, group_present):
     return to_affine_g1(agg), u_mask
 
 
-def stage_scalars_pippenger(pk_jac, sig_jac, glv_digits, group_idx,
-                            group_present, miller_mask):
-    """The MSM-grade replacement for stage_scalars + stage_group
-    (ops/msm.py): multipliers arrive GLV-decomposed as (N, 2, nwin)
-    w-bit digit arrays (r_i = k1_i + k2_i*lambda mod r), the per-group
-    G1 folds run as Pippenger bucket MSMs over (lane, phi(lane))
-    columns — ONE doubling chain per group row — and the whole-batch
-    G2 signature fold collapses to a single bucketed MSM (stage_finish
-    only ever consumes the wsig SUM, so `wsig` comes back as a
-    1-batch point and point_batch_sum is the identity on it).
-
-    Same output contract as stage_group + the wsig half of
-    stage_scalars: (agg_aff (U, ...), u_mask (U,), wsig (1, ...))."""
-    agg = MSM.g1_grouped_msm(pk_jac, glv_digits, group_idx,
-                             group_present, miller_mask)
-    u_mask = ~PT.is_infinity(PT.G1_KIT, agg)
-    wsig = MSM.g2_msm(sig_jac, glv_digits)
-    return to_affine_g1(agg), u_mask, wsig
-
-
 def stage_miller(pk_r_aff, hm_aff, mask):
     """Miller loops — width-polymorphic: per-lane inputs on the
     hm-gather path, per-unique aggregates on the grouped path."""
@@ -307,8 +246,6 @@ def staged_jits():
                     "scalars": _wrap("scalars", stage_scalars),
                     "affine": _wrap("affine", stage_lane_affine),
                     "group": _wrap("group", stage_group),
-                    "scalars_pip": _wrap("scalars_pip",
-                                         stage_scalars_pippenger),
                     "miller": _wrap("miller", stage_miller),
                     "finish": _wrap("finish", stage_finish),
                 }
@@ -368,33 +305,33 @@ def verify_staged_grouped(pk_xs, pk_ys, pk_present, hm_uniq, group_idx,
     return ok, lane_ok
 
 
-def verify_staged_pippenger(pk_xs, pk_ys, pk_present, hm_uniq,
-                            group_idx, group_present, sig_x_plain,
-                            sig_large, sig_inf, glv_digits, lane_valid,
-                            on_stage=None):
-    """The staged GROUPED pipeline with the MSM-grade scalars stage
-    (`--msm-path pippenger`): GLV digit arrays replace r_bits, the
-    scalars_pip program absorbs stage_group, verdict contract is
-    bit-identical to verify_staged_grouped driven with the effective
-    multipliers r_i = k1_i + k2_i*lambda (tests/test_msm.py)."""
-    run = _stage_runner(on_stage)
-    pk_jac, sig_jac, lane_ok, miller_mask = run(
-        "prepare", pk_xs, pk_ys, pk_present, sig_x_plain, sig_large,
-        sig_inf, lane_valid)
-    agg_aff, u_mask, wsig = run("scalars_pip", pk_jac, sig_jac,
-                                glv_digits, group_idx, group_present,
-                                miller_mask)
-    ml = run("miller", agg_aff, hm_uniq, u_mask)
-    ok = run("finish", ml, wsig)
-    return ok, lane_ok
-
-
 def verify_staged(pk_xs, pk_ys, pk_present, u0, u1, group_idx,
                   group_present, sig_x_plain, sig_large, sig_inf,
                   r_bits, lane_valid, on_stage=None):
-    """Same contract as verify_kernel (unique-message draws + group
-    index), via the staged programs.  `on_stage(name, seconds)` reports
-    per-stage wall time (bench)."""
+    """The batched verification dispatch from the unique messages'
+    draws, via the staged programs (single device, dedup-aware).
+
+    pk_xs/pk_ys: (N, K, L) Montgomery limbs — per-triple pubkeys, each
+        already validated (subgroup, non-infinity) by the caller's
+        cache, padded to K along axis 1; aggregation happens in-kernel.
+    u0/u1: Fq2 draws of the batch's UNIQUE messages' hash_to_field
+        (host SHA-256), padded to a pow-2 bucket U <= N — h2c runs at
+        unique width, not lane width.
+    group_idx/group_present: (U, G) lane indices/mask of each unique
+        message's lanes (stage_group: bilinearity folds those lanes
+        into one Miller loop per unique).
+    pk_present: (N, K) — False for key-padding slots.
+    sig_x_plain: ((N, L), (N, L)) plain-form Fq2 x of each signature;
+    sig_large: (N,) wire sign bit; sig_inf: (N,) infinity-signature mask.
+    r_bits: (N, 64) bits of the nonzero random multipliers, MSB first.
+    lane_valid: (N,) — False for padding lanes.
+    `on_stage(name, seconds)` reports per-stage wall time (bench).
+
+    Returns (ok, lane_ok): ok is the whole-batch pairing verdict;
+    lane_ok flags lanes whose signature failed decompression/subgroup
+    checks or whose keys aggregated to infinity (the caller must AND
+    `ok` with all valid lanes' lane_ok).
+    """
     run = _stage_runner(on_stage)
     hm_uniq = run("h2c", u0, u1)
     return verify_staged_grouped(pk_xs, pk_ys, pk_present, hm_uniq,
@@ -403,12 +340,10 @@ def verify_staged(pk_xs, pk_ys, pk_present, u0, u1, group_idx,
                                  on_stage=on_stage)
 
 
-def verify_kernel_sharded_grouped(mesh, axis: str = "dp",
-                                  msm_path: str = "ladder"):
+def verify_kernel_sharded_grouped(mesh, axis: str = "dp"):
     """Multi-chip variant of the DEDUP-AWARE pipeline: message groups
     are the sharding unit, so every chip keeps the unique-message
-    Miller grouping (and, with ``msm_path="pippenger"``, the bucketed
-    MSM scalars stage) that the lane-sharded kernel forfeits.
+    Miller grouping that the lane-sharded kernel forfeits.
 
     GROUP-ALIGNED contract (the provider's shard planner,
     teku_tpu/parallel.plan_group_shards, builds these layouts):
@@ -417,44 +352,35 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp",
       lanes of the message-group rows that shard owns (a group never
       crosses a shard boundary); lane-sharded inputs: pk_xs/pk_ys
       (N, K, L), pk_present (N, K), sig_x ((N, L), (N, L)), sig_large/
-      sig_inf/lane_valid (N,), and the scalars array — r_bits (N, 64)
-      on the ladder path, glv_digits (N, 2, nwin) on the pippenger
-      path;
+      sig_inf/lane_valid (N,), r_bits (N, 64);
     - group rows are ROW-sharded: hm_rows (the per-row H(m) affine
       tree, (U, L) leaves), group_idx (U, G) of SHARD-LOCAL lane
       indices, group_present (U, G).  Padding rows aggregate to
       infinity and mask themselves out of the Miller stage, so empty
       shards contribute exactly the identity.
 
-    Per shard: prepare -> scalars+group (ladder) or the fused
-    Pippenger MSM -> Miller loops at LOCAL row width -> local Fq12
-    product + local G2 weighted-signature sum; then ONE all_gather of
-    those two tiny partials crosses the ICI and the final
-    exponentiation is replicated.  Returns (ok, lane_ok) with lane_ok
+    Per shard: prepare -> scalars -> group -> Miller loops at LOCAL
+    row width -> local Fq12 product + local G2 weighted-signature sum;
+    then ONE all_gather of those two tiny partials crosses the ICI and
+    the final exponentiation is replicated.  Returns (ok, lane_ok) with lane_ok
     in the PERMUTED lane order (callers un-permute on the host).
     """
     from jax.sharding import PartitionSpec as P
 
     lane = P(axis)
     lane2 = P(axis, None)        # (N, L) / (N, 64) / (N, K)
-    lane3 = P(axis, None, None)  # (N, K, L) / (N, 2, nwin)
+    lane3 = P(axis, None, None)  # (N, K, L)
     row2 = P(axis, None)         # (U, G) and the (U, L) hm leaves
-    pippenger = msm_path == "pippenger"
 
     def shard_fn(pk_xs, pk_ys, pk_present, hm_rows, group_idx,
-                 group_present, sig_x, sig_large, sig_inf, scalars,
+                 group_present, sig_x, sig_large, sig_inf, r_bits,
                  lane_valid):
         pk_jac, sig_jac, lane_ok, miller_mask = stage_prepare(
             pk_xs, pk_ys, pk_present, sig_x, sig_large, sig_inf,
             lane_valid)
-        if pippenger:
-            agg_aff, u_mask, wsig = stage_scalars_pippenger(
-                pk_jac, sig_jac, scalars, group_idx, group_present,
-                miller_mask)
-        else:
-            pk_r_jac, wsig = stage_scalars(pk_jac, sig_jac, scalars)
-            agg_aff, u_mask = stage_group(pk_r_jac, miller_mask,
-                                          group_idx, group_present)
+        pk_r_jac, wsig = stage_scalars(pk_jac, sig_jac, r_bits)
+        agg_aff, u_mask = stage_group(pk_r_jac, miller_mask, group_idx,
+                                      group_present)
         ml = stage_miller(agg_aff, hm_rows, u_mask)
         local_prod = PR.batch_product(ml)
         local_sum = point_batch_sum(PT.G2_KIT, wsig)
@@ -471,9 +397,7 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp",
     in_specs = (lane3, lane3, lane2,
                 ((row2, row2), (row2, row2)),   # hm rows (affine x, y)
                 row2, row2,                     # group idx / present
-                (lane2, lane2), lane, lane,
-                lane3 if pippenger else lane2,  # glv digits | r bits
-                lane)
+                (lane2, lane2), lane, lane, lane2, lane)
     out_specs = (P(), lane)
     return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
@@ -496,7 +420,7 @@ def verify_kernel_sharded(mesh, axis: str = "dp"):
     shard function's inputs are all lane-sharded.
 
     Returns a function taking (pk_xs, pk_ys, pk_present, hm, sig_x,
-    sig_large, sig_inf, r_bits, lane_valid) with verify_kernel's result
+    sig_large, sig_inf, r_bits, lane_valid) with verify_staged's result
     (to be called with GLOBAL batch arrays; N must divide the mesh size).
     """
     from jax.sharding import PartitionSpec as P

@@ -6,9 +6,8 @@ and since PR 5 the unit of per-lane work is the MESSAGE GROUP (h2c and
 the Miller loops run once per unique message), the production sharding
 unit is the group row, not the raw lane: ``plan_group_shards`` packs
 whole (message, lane-chunk) rows onto shards so every chip runs the
-full dedup-aware pipeline (grouped Miller rows, optionally the
-GLV+Pippenger MSM scalars stage) on its shard, then ONE tiny
-all_gather (a per-device Fq12 partial product + G2 partial point-sum)
+full dedup-aware pipeline (grouped Miller rows) on its shard, then ONE
+tiny all_gather (a per-device Fq12 partial product + G2 partial point-sum)
 crosses the interconnect before the replicated final exponentiation
 (teku_tpu/ops/verify.py:verify_kernel_sharded_grouped).
 
@@ -286,9 +285,9 @@ class ShardedVerifier:
         return self._fn(*args)
 
 
-# process-level sharded-kernel memo, keyed by (device set, axis, msm
-# path): two GroupShardedVerifier instances over the SAME devices are
-# the same program, so they must share ONE jitted callable — and its
+# process-level sharded-kernel memo, keyed by (device set, axis): two
+# GroupShardedVerifier instances over the SAME devices are the same
+# program, so they must share ONE jitted callable — and its
 # in-memory jit cache of compiled shapes.  This is what makes the
 # self-healer's GROW reshape near-free: re-admitting a device rebuilds
 # a mesh the process already served, and every warmed shape is still
@@ -297,8 +296,7 @@ _KERNELS: dict = {}
 _KERNELS_LOCK = threading.Lock()
 
 
-def kernel_store_name(devices: Sequence[str], axis: str,
-                      msm_path: str) -> str:
+def kernel_store_name(devices: Sequence[str], axis: str) -> str:
     """AOT-store kernel name for a sharded verify program.  The
     device LIST (not just the count) is part of the name: a serialized
     executable binds its device assignment, so an entry compiled for
@@ -309,16 +307,14 @@ def kernel_store_name(devices: Sequence[str], axis: str,
 
     from ..ops import mxu
     dev = hashlib.sha256(repr(tuple(devices)).encode()).hexdigest()[:8]
-    return (f"mesh:{len(devices)}:{axis}:{msm_path}:"
-            f"{mxu.resolve()}:{dev}")
+    return f"mesh:{len(devices)}:{axis}:{mxu.resolve()}:{dev}"
 
 
 class GroupShardedVerifier:
     """Group-aligned production mesh dispatch.
 
-    Owns the per-dispatch shard planner (plan()) and one jitted
-    verify_kernel_sharded_grouped per MSM path (the ladder and
-    pippenger scalars stages are different programs).  The padding
+    Owns the per-dispatch shard planner (plan()) and the jitted
+    verify_kernel_sharded_grouped of its (devices, axis).  The padding
     rule keeps every shard's shapes identical (pow2 lanes/rows per
     shard) — the multi-chip twin of the provider's bucket rule."""
 
@@ -344,23 +340,22 @@ class GroupShardedVerifier:
             min_lanes=self.min_bucket // self.n_devices,
             min_rows=max(min_rows_total // self.n_devices, 1))
 
-    def kernel_key(self, msm_path: str) -> tuple:
+    def kernel_key(self) -> tuple:
         """The identity of the shared jitted kernel serving this
         verifier (the provider's jit-outcome accounting keys on it:
         a fresh instance over known devices is NOT a fresh program)."""
-        return (tuple(self.devices), self.axis, msm_path)
+        return (tuple(self.devices), self.axis)
 
-    def kernel(self, msm_path: str):
-        key = self.kernel_key(msm_path)
+    def kernel(self):
+        key = self.kernel_key()
         with _KERNELS_LOCK:
             fn = _KERNELS.get(key)
             if fn is None:
                 from ..infra import aotstore
                 from ..ops import verify as V
                 fn = aotstore.wrap(
-                    kernel_store_name(self.devices, self.axis,
-                                      msm_path),
+                    kernel_store_name(self.devices, self.axis),
                     jax.jit(V.verify_kernel_sharded_grouped(
-                        self.mesh, self.axis, msm_path)))
+                        self.mesh, self.axis)))
                 _KERNELS[key] = fn
         return fn

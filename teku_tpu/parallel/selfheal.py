@@ -280,6 +280,13 @@ class MeshHealer:
     def live_devices(self) -> Tuple[int, ...]:
         return self._live
 
+    @property
+    def healing(self) -> bool:
+        """True while a heal sweep is in flight (the loadgen holds its
+        virtual clock over one, as it does over a dispatch)."""
+        with self._lock:
+            return self._healing
+
     def close(self) -> None:
         self._closed = True
 

@@ -294,6 +294,41 @@ def test_size_cap_evicts_oldest(aot_dir, monkeypatch):
     assert os.path.exists(new)
 
 
+def test_a_store_with_a_retired_programs_entries_still_serves(aot_dir):
+    """A store directory written before the bucketed MSM went holds
+    `stage:scalars_pip:*` entries no dispatcher asks for any more: the
+    programs that remain load from it under their unchanged names, no
+    outcome counts as an error, and the orphan is left for the size
+    cap to evict."""
+    from teku_tpu.ops import mxu
+    from teku_tpu.ops import verify as V
+    mont = mxu.resolve()
+    x = jnp.arange(8, dtype=jnp.int64)
+    lane_map = jnp.asarray([1, 0, 1, 1], dtype=jnp.int32)
+    hm = ((x[:2, None], x[:2, None]), (x[:2, None], x[:2, None]))
+    # what the parent's process left behind
+    aotstore.AotDispatcher(f"stage:scalars_pip:{mont}",
+                           jax.jit(_oracle))(x)
+    want = jax.tree_util.tree_map(np.asarray, aotstore.AotDispatcher(
+        f"stage:gather:{mont}", jax.jit(V.stage_gather_hm))(hm, lane_map))
+    orphan = aotstore.entry_key(f"stage:scalars_pip:{mont}",
+                                aotstore.shape_sig((x,))) + ".aotx"
+    assert orphan in os.listdir(aot_dir)
+    assert len(os.listdir(aot_dir)) == 2
+    # a fresh process in miniature, on the change's programs alone
+    jax.clear_caches()
+    before = aotstore.stats()
+    got = aotstore.AotDispatcher(
+        f"stage:gather:{mont}", jax.jit(V.stage_gather_hm))(hm, lane_map)
+    moved = aotstore.delta(before)
+    assert moved["loads"] == 1 and moved["misses"] == 0
+    assert moved["errors"] == 0
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), b),
+        got, want)
+    assert orphan in os.listdir(aot_dir)
+
+
 def test_entry_key_is_filename_safe_and_stable():
     sig = (("*", "*"), (((4, 6), "int64"),))
     key = aotstore.entry_key("mesh:2:dp:ladder:vpu:deadbeef", sig)

@@ -109,41 +109,6 @@ def test_overlap_efficiency_gate_flags_skips_and_overrides():
         == "regression"
 
 
-def test_msm_gate_flags_skips_and_overrides():
-    """The PR-8 MSM gate: pippenger's scalars-stage p50 must beat the
-    ladder >= 1.3x at every measured batch >= 256; absent evidence
-    (pre-MSM results, budget-starved runs) skips, sub-256 batches are
-    informational only, and the threshold is operator-tunable."""
-    doc = {"msm": {"dup": 8, "window": 4,
-                   "256": {"scalars": {"ladder_p50_ms": 100.0,
-                                       "pippenger_p50_ms": 90.0,
-                                       "speedup": 1.11}}}}
-    out = bench_diff.compare({}, doc)
-    assert out["verdict"] == "regression"
-    assert _by_metric(out)["msm_scalars_speedup_256"]["status"] \
-        == "regression"
-    doc["msm"]["256"]["scalars"]["speedup"] = 1.45
-    out = bench_diff.compare({}, doc)
-    assert _by_metric(out)["msm_scalars_speedup_256"]["status"] == "ok"
-    # a 4096 entry gets its own gate; an errored batch entry skips
-    doc["msm"]["4096"] = {"error": "TimeoutError: budget"}
-    out = bench_diff.compare({}, doc)
-    checks = _by_metric(out)
-    assert checks["msm_scalars_speedup_4096"]["status"] == "skipped"
-    assert out["verdict"] == "pass"
-    # no msm evidence at all -> no msm checks (older results compare)
-    assert not any(c["metric"].startswith("msm_")
-                   for c in bench_diff.compare({}, {})["checks"])
-    # sub-256 batches are not gated (the crossover is shape-dependent)
-    tiny = {"msm": {"64": {"scalars": {"speedup": 0.5}}}}
-    assert bench_diff.compare({}, tiny)["verdict"] == "pass"
-    # operator override loosens the gate
-    doc["msm"]["256"]["scalars"]["speedup"] = 1.11
-    out = bench_diff.compare({}, doc,
-                             {"msm_scalars_speedup_min": 1.0})
-    assert _by_metric(out)["msm_scalars_speedup_256"]["status"] == "ok"
-
-
 def test_current_bench_r05_vs_itself_passes():
     """The acceptance gate: the checked-in BENCH_r05 (driver envelope
     with a `parsed` key, budget-starved phases missing) must compare

@@ -53,7 +53,7 @@ def test_device_phases_refuse_a_platform_that_is_not_tpu(quiet_bench):
 
 def _run_bench(tmp_path, **env):
     off = {f"BENCH_{p}": "0" for p in (
-        "THROUGHPUT", "P50", "MONT", "MSM", "DEDUP", "MESH", "KZG",
+        "THROUGHPUT", "P50", "MONT", "DEDUP", "MESH", "KZG",
         "EPOCH", "OVERLOAD", "MAINNET", "CHAOS")}
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "bench.py")],

@@ -28,7 +28,6 @@ from teku_tpu.crypto.bls.pure_impl import PureBls12381
 from teku_tpu.infra import dispatchledger, doctor, tracing
 from teku_tpu.infra.flightrecorder import FlightRecorder
 from teku_tpu.infra.metrics import MetricsRegistry
-from teku_tpu.ops import msm
 from teku_tpu.ops import provider as PV
 from teku_tpu.ops.provider import JaxBls12381
 from teku_tpu.services.admission import BatchPlan, VerifyClass
@@ -100,7 +99,7 @@ def test_summarize_waste_imbalance_and_decisions():
          "waste": {"lane": {"real": 48, "padded": 64},
                    "h2c": {"real": 6, "padded": 8}},
          "h2c": {"cache_hits": 2, "cache_misses": 4},
-         "msm": {"path": "pippenger"},
+         "msm": {"path": "ladder"},
          "mesh": {"devices": 8, "makespan_ratio": 1.8},
          "admission": {"plan_mode": "throughput",
                        "brownout_level": 0},
@@ -117,8 +116,7 @@ def test_summarize_waste_imbalance_and_decisions():
     assert s["padding_waste"]["lane"] == round(16 / 80, 4)
     assert s["padding_waste_by_lane_bucket"]["64"] == 0.25
     assert s["dedup_ratio"] == round((64 - 22) / 64, 4)
-    assert s["decisions"] == {"ladder|0|none": 1,
-                              "pippenger|8|throughput": 1}
+    assert s["decisions"] == {"0|none": 1, "8|throughput": 1}
     assert s["compile"] == {"cache_hit": 1, "compile": 1}
     assert s["compile_s"] == 41.0
     assert s["mesh_imbalance"]["max"] == 1.8
@@ -135,10 +133,7 @@ def test_doctor_ranks_findings_and_cites_records():
          "waste": {"lane": {"real": 300, "padded": 512},
                    "h2c": {"real": 300, "padded": 512}},
          "h2c": {"cache_hits": 0, "cache_misses": 300},
-         "msm": {"path": "ladder",
-                 "why": {"configured": "auto", "lanes": 300,
-                         "rows": 75, "tpu": False,
-                         "rule": msm.AUTO_RULE_NOT_TPU}},
+         "msm": {"path": "ladder"},
          "mesh": {"devices": 0},
          "admission": {},
          "compile": {"outcome": "compile", "enqueue_s": 41.0}},
@@ -148,8 +143,7 @@ def test_doctor_ranks_findings_and_cites_records():
          "waste": {"lane": {"real": 40, "padded": 64},
                    "h2c": {"real": 10, "padded": 16}},
          "h2c": {"cache_hits": 10, "cache_misses": 0},
-         "msm": {"path": "ladder", "why": {"rule": "explicitly "
-                                           "configured"}},
+         "msm": {"path": "ladder"},
          "mesh": {"devices": 8, "makespan_ratio": 1.8,
                   "shard_lanes": [5, 5, 5, 9, 4, 4, 4, 4]},
          "admission": {},
@@ -194,41 +188,6 @@ def test_doctor_ranks_findings_and_cites_records():
     assert "aa-000007" in text and "512x8" in text
     # a clean ledger renders healthy
     assert doctor.diagnose([])["healthy"]
-
-
-@pytest.mark.parametrize("configured,tpu,lanes,rows,finds", [
-    # `auto` takes what the chip measured: nothing to report
-    ("auto", True, 250, 8, False), ("auto", True, 4096, 128, False),
-    ("auto", True, 4096, 4096, False), ("auto", False, 250, 8, False),
-    # a path by hand: reported where the chip measured the other one
-    # faster, the CPU A/B's own business off a TPU
-    ("pippenger", True, 250, 8, True), ("ladder", True, 4096, 128, True),
-    ("pippenger", True, 4096, 4096, True),
-    ("ladder", True, 250, 8, False), ("pippenger", True, 4096, 512, False),
-    ("pippenger", False, 250, 8, False),
-])
-def test_doctor_reports_a_path_against_the_measurement(
-        monkeypatch, configured, tpu, lanes, rows, finds):
-    """The doctor's msm finding says what `msm.explain()` says: the
-    records it reads carry explain()'s own `why`."""
-    monkeypatch.setattr(msm, "_device_is_tpu", lambda: tpu)
-    with msm.force(configured):
-        path, why = msm.explain(lanes=lanes, rows=rows)
-    rec = _compile_rec(21, "256x1", "cache_hit")
-    rec["msm"] = {"path": path, "why": why}
-    diagnosis = doctor.diagnose([rec])
-    found = [f for f in diagnosis["findings"]
-             if f["kind"].startswith("msm")]
-    assert bool(found) is finds, found
-    if finds:
-        f = found[0]
-        assert f["kind"] == "msm_path_against_measurement"
-        assert f["severity"] >= doctor.ATTENTION_SEVERITY
-        assert f"msm path {path}" in f["title"]
-        assert "explicitly configured" in f["title"]
-        assert f["evidence"][0]["seq"] == 21
-        assert f["metrics"]["why"] == why
-        assert not diagnosis["healthy"]
 
 
 def _compile_rec(seq, shape, outcome, enqueue_s=30.0):
@@ -451,8 +410,6 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     assert rec["verdict"] is True
     assert rec["device"]["sync_s"] >= 0
     assert rec["mesh"]["devices"] == 0
-    assert rec["msm"]["path"] in ("ladder", "pippenger")
-    assert rec["msm"]["why"]["rule"]
     # warm re-dispatch of the SAME batch: the arena serves every row
     h2c_before = single_impl.h2c_dispatch_count
     assert single_impl.batch_verify(triples)
@@ -462,6 +419,22 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     assert single_impl.h2c_dispatch_count == h2c_before
     assert warm["compile"]["outcome"] == "cache_hit"
     assert "programs" not in warm["compile"]
+
+
+def test_the_fields_the_benchmark_reads_stay(single_impl, keys):
+    """`benchmarks/harness/cell.py` reads `r['msm']['path']` off every
+    completed ledger record and `plan['msm_path']` off `batch_plan()`:
+    the program has one scalars stage and says so in both, with no
+    `why` (there is no decision to explain).  ROADMAP C15 removes the
+    readers and the fields together."""
+    from teku_tpu.ops import shapeset
+    pure, sks, pks = keys
+    assert single_impl.batch_verify(_grid_batch(pure, sks, pks))
+    assert _last_record()["msm"] == {"path": "ladder"}
+    for groups in ([1] * 256, [250], [32] * 128):
+        assert shapeset.batch_plan(
+            groups, min_bucket=256)["msm_path"] == "ladder"
+
 
 
 def test_tampered_batch_records_false_verdict(single_impl, keys):
@@ -496,7 +469,7 @@ def test_mesh_record_carries_shard_plan_and_imbalance(mesh_impl,
     assert gauge.value == rec["mesh"]["makespan_ratio"]
     # the decision counter carries the mesh label
     dec = GLOBAL_REGISTRY.labeled_counter("bls_dispatch_decision_total")
-    assert any(key[1] == "8" for key, _ in dec._items())
+    assert any(key[0] == "8" for key, _ in dec._items())
 
 
 def test_trace_id_lookup_joins_slow_traces_and_endpoint(single_impl,

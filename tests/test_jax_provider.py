@@ -145,50 +145,44 @@ def _signed(n, tag=b"halves", sks=None):
              bls.sign(sks[i % 4], tag + b"-%d" % i)) for i in range(n)]
 
 
-@pytest.mark.parametrize("msm_path", ["ladder", "pippenger"])
 @pytest.mark.parametrize("op", ["batch_verify", "fast_aggregate_verify",
                                 "aggregate_verify"])
-def test_host_half_never_enters_the_device(jax_impl, msm_path, op):
+def test_host_half_never_enters_the_device(jax_impl, op):
     """With its keys in the cache, the host half launches no device
     program and makes no transfer: it may run while another dispatch
     owns the device.  The control: the `jnp` bit expansion it used to
     draw the multipliers with is refused under the same guard."""
     import jax
     import numpy as np
-    from teku_tpu.ops import msm, points as PT
-    triples = _signed(3, tag=b"host-%s" % msm_path.encode())
+    from teku_tpu.ops import points as PT
+    triples = _signed(3, tag=b"host-half")
     assert all(jax_impl.public_key_is_valid(pk) for pk in _pks())
     args = {"batch_verify": (triples,),
             "fast_aggregate_verify": triples[0],
             "aggregate_verify": ([t[0][0] for t in triples],
                                  [t[1] for t in triples],
                                  triples[0][2])}[op]
-    msm.set_path(msm_path)
-    try:
-        with jax.transfer_guard("disallow_explicit"):
-            with pytest.raises(Exception, match="Disallowed"):
-                np.asarray(PT.scalar_from_uint64(
-                    np.ones(4, dtype=np.uint64)))
-            prepared = jax_impl.prepare_dispatch(op, *args)
-    finally:
-        msm.set_path(None)
+    with jax.transfer_guard("disallow_explicit"):
+        with pytest.raises(Exception, match="Disallowed"):
+            np.asarray(PT.scalar_from_uint64(
+                np.ones(4, dtype=np.uint64)))
+        prepared = jax_impl.prepare_dispatch(op, *args)
     assert prepared.verdict is None and not prepared.pk_miss
     (packed,) = prepared.packed
-    assert packed.msm_path == msm_path and not packed.pk_pending
+    assert not packed.pk_pending
     leaves = [packed.pk_xs, packed.pk_ys, packed.pk_present, *packed.sx,
-              packed.s_large, packed.s_inf, packed.scalars,
+              packed.s_large, packed.s_inf, packed.r_bits,
               packed.lane_valid, packed.group_idx, packed.group_present]
     assert all(type(a) is np.ndarray for a in leaves)
     # the multipliers: r = 1 exactly off `batch_verify`, else 64 random
     # bits a lane (never 0)
-    if msm_path == "ladder":
-        assert packed.scalars.shape == (packed.padded, 64)
-        assert packed.scalars.dtype == np.int64
-        if op != "batch_verify":
-            assert (packed.scalars[:, :63] == 0).all()
-            assert (packed.scalars[:, 63] == 1).all()
-        else:
-            assert packed.scalars.any(axis=1).all()
+    assert packed.r_bits.shape == (packed.padded, 64)
+    assert packed.r_bits.dtype == np.int64
+    if op != "batch_verify":
+        assert (packed.r_bits[:, :63] == 0).all()
+        assert (packed.r_bits[:, 63] == 1).all()
+    else:
+        assert packed.r_bits.any(axis=1).all()
 
 
 def test_host_verdicts_need_no_device_half(jax_impl):

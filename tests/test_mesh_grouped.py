@@ -9,8 +9,8 @@ the breaker to oracle fallback, and the mesh observability surfaces.
 
 Compile budget: every device test in the fast tier shares ONE sharded
 kernel shape (32 lanes x kmax 1, 8 rows x group 4 over 8 shards) and
-ONE single-device staged shape set; the pippenger-sharded and mxu-force
-re-traces are extra full-pipeline compiles and live in the slow tier.
+ONE single-device staged shape set; the mxu-force re-trace is an extra
+full-pipeline compile and lives in the slow tier.
 """
 
 import logging
@@ -26,7 +26,6 @@ from teku_tpu.crypto.bls.pure_impl import PureBls12381
 from teku_tpu.infra import capacity, faults
 from teku_tpu.infra.metrics import GLOBAL_REGISTRY, MetricsRegistry
 from teku_tpu.infra.supervisor import (CircuitBreaker)
-from teku_tpu.ops import msm
 from teku_tpu.ops import provider as PV
 from teku_tpu.ops.provider import JaxBls12381
 
@@ -135,22 +134,20 @@ def test_configure_kernel_sets_mesh_env(monkeypatch):
     # _configure_kernel writes these straight to os.environ; restore
     # the process env by hand after the test
     saved = {var: os.environ.get(var)
-             for var in ("TEKU_TPU_MESH", "TEKU_TPU_MONT_MUL",
-                         "TEKU_TPU_MSM")}
+             for var in ("TEKU_TPU_MESH", "TEKU_TPU_MONT_MUL")}
 
     class Args:
         mont_path = None
-        msm_path = None
         mesh = "auto"
     try:
-        mont, msm_choice, mesh = cli._configure_kernel(Args(), {})
+        mont, mesh = cli._configure_kernel(Args(), {})
         assert mesh == "auto"
         assert os.environ["TEKU_TPU_MESH"] == "auto"
         # numeric N forces virtual host devices ONLY if the flag is
         # absent
         monkeypatch.setenv("XLA_FLAGS", "--xla_foo")
         Args.mesh = "4"
-        assert cli._configure_kernel(Args(), {})[2] == "4"
+        assert cli._configure_kernel(Args(), {})[1] == "4"
         assert "xla_force_host_platform_device_count=4" \
             in os.environ["XLA_FLAGS"]
         # already-forced flag (the test env itself) is left untouched
@@ -371,25 +368,8 @@ def test_supervisor_snapshot_and_gauge_carry_mesh():
 
 
 # --------------------------------------------------------------------------
-# slow tier: extra full-pipeline re-traces (pippenger mesh, mxu-force)
+# slow tier: an extra full-pipeline re-trace (mxu-force)
 # --------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_pippenger_sharded_parity(mesh_impl, single_impl, keys):
-    """The mesh kernel is NOT ladder-only: forced pippenger compiles
-    the GLV+Pippenger sharded program and the verdict grid matches the
-    ladder mesh, the single-device pippenger path and the oracle."""
-    pure, sks, pks = keys
-    base = _grid_batch(pure, sks, pks)
-    bad = list(base)
-    bad[5] = (base[5][0], b"pip-tampered", base[5][2])
-    with msm.force("pippenger"):
-        for triples, want in ((base, True), (bad, False)):
-            assert pure.batch_verify(triples) == want
-            assert single_impl.batch_verify(triples) == want
-            assert mesh_impl.batch_verify(triples) == want
-    assert mesh_impl.msm_dispatches["pippenger"] >= 2
-
 
 @pytest.mark.slow
 def test_grouped_sharded_parity_grid_mxu_force(mesh8, keys):

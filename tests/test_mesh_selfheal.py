@@ -51,8 +51,7 @@ def _restore_global_topology_filter():
     latency series on the process-global capacity model and installs
     its live-topology filter.  Left in place, the filter silently
     drops every later non-mesh test's capacity samples in the same
-    process (test_msm's per-path latency-series assertions were the
-    first to notice)."""
+    process."""
     yield
     capacity.TELEMETRY.latency.clear_topology_filter()
 

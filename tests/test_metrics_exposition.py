@@ -295,35 +295,28 @@ def test_dispatch_and_cache_label_contract():
     assert pv  # imported above; JaxBls12381 instances carry .mont_path
 
 
-def test_msm_path_family_label_contract():
-    """The PR-8 MSM scalars-path families must not drift: the dispatch
-    and lane counters carry exactly one `path` label whose vocabulary
-    is the CLOSED {ladder, pippenger} set resolve() can emit —
-    dashboards ratio pippenger lanes over total to see how much
-    traffic rides the bucketed stage."""
+def test_dispatch_prep_family_label_contract():
+    """The per-dispatch families a verify dispatch moves besides the
+    jit-outcome counter must not drift: `bls_dispatch_prep_total`
+    carries exactly (`prep`, `reason`) from CLOSED sets — where the
+    host prep ran, and why it stayed under the device-entry lock — and
+    the h2c dedup counters are unlabeled (one scalars stage: nothing
+    about a dispatch is split by a kernel choice)."""
     import teku_tpu.ops.provider  # noqa: F401 - registers families
     from teku_tpu.infra.metrics import GLOBAL_REGISTRY
-    from teku_tpu.ops import msm
 
     metrics = GLOBAL_REGISTRY.metrics()
-    resolved_vocab = {"ladder", "pippenger"}
-    for fam in ("bls_msm_dispatch_total", "bls_msm_lanes_total"):
-        m = metrics[fam]
-        assert isinstance(m, LabeledCounter), fam
-        assert tuple(m.labelnames) == ("path",), fam
-        assert fam.endswith("_total")
-        # any series already recorded stays inside the closed set
-        for key, _child in m._items():
-            assert set(key) <= resolved_vocab, (fam, key)
-    # the resolver can only emit the documented vocabulary, on every
-    # input shape (incl. the sharded override and no-context auto)
-    for kw in ({}, {"lanes": 4096, "rows": 16},
-               {"lanes": 8, "rows": 8, "sharded": True},
-               {"lanes": 0, "rows": 0}):
-        assert msm.resolve(**kw) in resolved_vocab
-    # and the configured vocabulary matches the CLI mirror
-    from teku_tpu.cli import _MSM_PATHS
-    assert tuple(msm.PATHS) == _MSM_PATHS
+    prep = metrics["bls_dispatch_prep_total"]
+    assert isinstance(prep, LabeledCounter)
+    assert tuple(prep.labelnames) == ("prep", "reason")
+    vocab = {("outside_lock", "none"), ("under_lock", "pk_miss"),
+             ("under_lock", "arena"), ("under_lock", "pk_miss+arena")}
+    # any series already recorded stays inside the closed set
+    for key, _child in prep._items():
+        assert key in vocab, key
+    for fam in ("bls_h2c_lanes_total", "bls_h2c_unique_total",
+                "bls_h2c_dispatch_total"):
+        assert isinstance(metrics[fam], Counter), fam
 
 
 def test_mesh_family_label_contract():
@@ -754,9 +747,9 @@ def test_dispatch_ledger_family_label_contract():
     padding-waste gauge carries exactly one `stage` label from the
     CLOSED {lane, h2c} set (the lane series keeps the pre-ledger
     unlabeled gauge's semantics), the imbalance gauge is unlabeled,
-    and the decision counter's three label vocabularies are all
-    closed — {ladder, pippenger} x {0, pow-2 devices} x the five plan
-    modes.  The ring itself is bounded memory."""
+    and the decision counter's two label vocabularies are both
+    closed — {0, pow-2 devices} x the five plan modes.  The ring itself
+    is bounded memory."""
     import teku_tpu.ops.provider  # noqa: F401 - registers families
     from teku_tpu.infra import dispatchledger
     from teku_tpu.infra.metrics import GLOBAL_REGISTRY
@@ -776,10 +769,9 @@ def test_dispatch_ledger_family_label_contract():
 
     dec = metrics["bls_dispatch_decision_total"]
     assert isinstance(dec, LabeledCounter)
-    assert tuple(dec.labelnames) == ("msm_path", "mesh", "plan_mode")
+    assert tuple(dec.labelnames) == ("mesh", "plan_mode")
     pow2_vocab = {"0"} | {str(1 << i) for i in range(1, 9)}
-    for (msm_path, mesh, plan_mode), _child in dec._items():
-        assert msm_path in ("ladder", "pippenger"), msm_path
+    for (mesh, plan_mode), _child in dec._items():
         assert mesh in pow2_vocab, mesh
         assert plan_mode in dispatchledger.PLAN_MODES, plan_mode
     # the label folder can only emit the documented plan modes, on

@@ -116,10 +116,8 @@ def test_enumerate_programs_names_and_dedup():
     kernels = [k for k, _avals, _meta in programs]
     assert f"pk_validate:{mont}" in kernels
     stages = {m["stage"] for _k, _a, m in programs}
-    assert {"pk_validate", "h2c", "prepare", "miller",
-            "finish"} <= stages
-    # scalars comes on exactly one msm path per profile
-    assert stages & {"scalars", "scalars_pip"}
+    assert stages == {"pk_validate", "h2c", "prepare", "scalars",
+                      "group", "miller", "finish"}
     for k, _avals, meta in programs:
         if meta["stage"] not in ("pk_validate",):
             assert k.startswith("stage:"), k
@@ -147,6 +145,128 @@ def test_enumerate_programs_mesh_kernels():
         # the name the serving path registers for THIS device set —
         # a healed mesh over different devices must miss, never load
         # an executable bound to the wrong device assignment
-        assert kernel == parallel.kernel_store_name(
-            devices, "dp", meta["msm_path"])
+        assert kernel == parallel.kernel_store_name(devices, "dp")
     assert any(m["stage"] == "gather" for _k, _a, m in programs)
+
+
+# --------------------------------------------------------------------------
+# The registry against a real launch, at the benchmark cells' own sizes.
+# Nothing is compiled or run at that size here: the staged programs are
+# replaced by stand-ins that note their name and argument avals and hand
+# on zeros of the shapes `jax.eval_shape` gives for the real stage.
+# --------------------------------------------------------------------------
+
+def _stand_in_stages(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from teku_tpu.infra import aotstore
+    from teku_tpu.ops import mxu
+    from teku_tpu.ops import verify as V
+    mont = mxu.resolve()
+    ran = []
+
+    def stand_in(name, fn):
+        def run(*args):
+            ran.append((f"stage:{name}:{mont}", aotstore.shape_sig(args)))
+            return jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(fn, *args))
+        return run
+
+    monkeypatch.setattr(V, "_STAGED_JITS", {
+        name: stand_in(name, fn) for name, fn in (
+            ("prepare", V.stage_prepare), ("h2c", V.stage_h2c),
+            ("gather", V.stage_gather_hm), ("scalars", V.stage_scalars),
+            ("affine", V.stage_lane_affine), ("group", V.stage_group),
+            ("miller", V.stage_miller), ("finish", V.stage_finish))})
+    return ran
+
+
+def _cell_provider(monkeypatch, env):
+    """A provider at a cell's knobs whose key cache already holds the
+    signers (as after set-up), so a drain launches the stage programs
+    alone."""
+    import numpy as np
+
+    from teku_tpu.ops import limbs as fp
+    for var, value in env.items():
+        monkeypatch.setenv(var, str(value))
+    impl = JaxBls12381(max_batch=256, min_bucket=256)
+    pks = [bytes([0x80]) + i.to_bytes(47, "big") for i in range(1, 257)]
+    for pk in pks:
+        impl._pk_cache.put(pk, ("ok", np.zeros(fp.L, dtype=np.int64),
+                                np.zeros(fp.L, dtype=np.int64)))
+    return impl, pks
+
+
+def _drain(pks, lane_groups, tag):
+    sig = bytes([0x80]) + bytes(94) + b"\x01"
+    triples, lane = [], 0
+    for m, size in enumerate(lane_groups):
+        for _ in range(size):
+            triples.append(([pks[lane]], b"%s-%d" % (tag, m), sig))
+            lane += 1
+    return triples
+
+
+BACKFILL_ENV = {"TEKU_TPU_H2C_MIN_BUCKET": 256,
+                "TEKU_TPU_H2C_GROUP_CAP": 32,
+                "TEKU_TPU_H2C_CACHE_CAP": "off"}
+GOSSIP_ENV = {"TEKU_TPU_H2C_MIN_BUCKET": 16,
+              "TEKU_TPU_H2C_GROUP_CAP": 32}
+
+
+@pytest.mark.parametrize("env,lane_groups,h2c_missing", [
+    (BACKFILL_ENV, [1] * 250, None),    # backfill-unique.saturate
+    (BACKFILL_ENV, [1] * 173, None),    # .poisson: a partial batch
+    (GOSSIP_ENV, [250], None),          # gossip: a fresh drain
+    (GOSSIP_ENV, [250], 0)],            # gossip: a hit drain
+    ids=["all-unique", "open-loop-partial", "gossip-fresh",
+         "gossip-hit"])
+def test_enumerated_programs_are_the_launched_ones(
+        monkeypatch, env, lane_groups, h2c_missing):
+    """For a drain of each benchmark cell, `enumerate_programs` names
+    exactly the stage programs `provider._launch` runs, argument avals
+    and all, and no other."""
+    from teku_tpu.infra import aotstore
+    impl, pks = _cell_provider(monkeypatch, env)
+    if h2c_missing == 0:        # the arena already holds the message
+        ran = _stand_in_stages(monkeypatch)
+        impl.batch_verify(_drain(pks, lane_groups, b"drain"))
+        assert any(k.startswith("stage:h2c:") for k, _s in ran)
+    ran = _stand_in_stages(monkeypatch)
+    impl.batch_verify(_drain(pks, lane_groups, b"drain"))
+    monkeypatch.setattr(
+        shapeset, "warmup_profiles",
+        lambda max_batch: [("drain", lane_groups, h2c_missing)])
+    enumerated = {
+        (kernel, aotstore.shape_sig(avals))
+        for kernel, avals, meta in shapeset.enumerate_programs(
+            max_batch=256, min_bucket=256,
+            h2c_min_bucket=impl._h2c_min_bucket,
+            group_cap=impl._group_cap)
+        if meta["stage"] != "pk_validate"}
+    assert set(ran) == enumerated
+    assert len(ran) == len(enumerated) == (5 if h2c_missing == 0 else 6)
+
+
+@pytest.mark.parametrize("lanes,rows", [(4096, 128), (4096, 512),
+                                        (2049, 256)])
+def test_wide_batches_plan_the_same_stage_programs(monkeypatch, lanes,
+                                                   rows):
+    """Above 2048 lanes at 8 or more a row, on a TPU, the parent's rule
+    planned a second scalars program; the plan and the programs are now
+    those of every other shape."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    per_row = lanes // rows
+    groups = [per_row] * (rows - 1) + [lanes - per_row * (rows - 1)]
+    plan = shapeset.batch_plan(groups, min_bucket=16)
+    assert plan["lanes"] == lanes and plan["msm_path"] == "ladder"
+    monkeypatch.setattr(shapeset, "warmup_profiles",
+                        lambda max_batch: [("wide", groups, None)])
+    stages = [m["stage"] for _k, _a, m in shapeset.enumerate_programs(
+        max_batch=4096, min_bucket=16)]
+    assert stages == ["pk_validate", "h2c", "prepare", "scalars",
+                      "group", "miller", "finish"]

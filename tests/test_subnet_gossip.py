@@ -18,10 +18,9 @@ start of the next) and all-hit in turn.  Pinned here:
 - once the first fresh drain has run, no later miss count inside the
   miss bucket compiles anything (`jax.monitoring`'s backend-compile
   events read 0 over drains with 1, 2 and 3 fresh messages);
-- at the cell's own size, on a TPU, a drain's `shapeset.batch_plan`
-  names the ladder (what the chip measured, `ops/msm.py`), and the
-  programs enumerated for that serving config hold `scalars` and
-  `group` and no `scalars_pip`.
+- at the cell's own size a drain's `shapeset.batch_plan` is one
+  shape whatever its messages, and the programs enumerated for that
+  serving config hold one `scalars` and the committee's `group`.
 """
 
 import hashlib
@@ -35,7 +34,6 @@ from benchmarks.harness import traffic
 from benchmarks.reference import bls as ref
 from teku_tpu.infra import dispatchledger
 from teku_tpu.ops import h2c_cache as HC
-from teku_tpu.ops import msm
 from teku_tpu.ops import shapeset
 from teku_tpu.ops.provider import JaxBls12381
 
@@ -242,28 +240,21 @@ CELL = dict(min_bucket=256, h2c_min_bucket=16, group_cap=32)
     ([214, 36], 1, 9),          # the end of one message, the start of the next
     ([125], 0, 4),              # the probe's bisection, first halves
     ([63], 0, 2)])
-def test_a_gossip_drain_on_a_tpu_plans_the_ladder(monkeypatch, groups,
-                                                  missing, rows):
-    monkeypatch.setattr(msm, "_device_is_tpu", lambda: True)
+def test_a_gossip_drain_plans_one_shape(groups, missing, rows):
     plan = shapeset.batch_plan(groups, h2c_missing=missing, **CELL)
-    assert (plan["msm_path"], plan["rows"], plan["shape"], plan["u_hm"],
-            plan["group_bucket"]) == ("ladder", rows, "256x1", 16, 32)
+    assert (plan["rows"], plan["shape"], plan["u_hm"],
+            plan["group_bucket"]) == (rows, "256x1", 16, 32)
     assert plan["h2c_bucket"] == (16 if missing else 0)
 
 
-def test_the_cells_serving_config_enumerates_no_bucketed_program(
-        monkeypatch):
+def test_the_cells_serving_config_enumerates_one_scalars_program():
     """What `cli precompile`, the loader's warm batches and the AOT
-    store hold for this config on a TPU: the committee-duplicated warm
-    profile (256 lanes, 8 a message) took `scalars_pip` under the
-    CPU-era crossover; it takes `scalars` + `group` now."""
-    monkeypatch.setattr(msm, "_device_is_tpu", lambda: True)
+    store hold for this config: one `scalars` for its one lane shape,
+    and the committee-duplicated warm profile's `group` (256 lanes, 8 a
+    message)."""
     programs = list(shapeset.enumerate_programs(
         max_batch=256, kmax=1, **CELL))
-    assert {m["msm_path"] for _k, _a, m in programs
-            if "msm_path" in m} == {"ladder"}
     stages = [m["stage"] for _k, _a, m in programs]
-    assert "scalars_pip" not in stages
     assert stages.count("scalars") == 1         # one lane shape
     dup = [a for _k, a, m in programs
            if m["stage"] == "group" and m["profile"] == "x256dup8"]

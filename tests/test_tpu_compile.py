@@ -144,25 +144,20 @@ def test_sharded_combine_compiles_with_an_all_gather(topo):
 # (kernel stage, measured sandbox compile seconds on the vpu path) of the
 # staged programs `chip_smoke.py` warms, from PERF.md's table
 _STAGED = {"prepare": 15, "scalars": 30, "group": 3, "miller": 9,
-           "finish": 78, "h2c": 90, "scalars_pip": 95}
+           "finish": 78, "h2c": 90}
 _STAGE_FNS = {"prepare": V.stage_prepare, "scalars": V.stage_scalars,
               "group": V.stage_group, "miller": V.stage_miller,
-              "finish": V.stage_finish, "h2c": V.stage_h2c,
-              "scalars_pip": V.stage_scalars_pippenger}
+              "finish": V.stage_finish, "h2c": V.stage_h2c}
 
 
 @pytest.mark.slow(reason="minutes of TPU compile in all: "
                   + ", ".join(f"{k} ~{v}s" for k, v in _STAGED.items()))
 @pytest.mark.parametrize("stage", sorted(_STAGED))
-def test_staged_program_compiles(one_chip, stage, monkeypatch):
+def test_staged_program_compiles(one_chip, stage):
     """Every staged program of the smoke's shape set (max_batch 256,
-    min_bucket 256, unique bucket 256) with the paths a TPU picks: vpu,
-    and msm auto (the ladder; default_backend() still says cpu here).
-    `scalars_pip` is the explicit `--msm-path pippenger` choice."""
-    from teku_tpu.ops import msm
-    monkeypatch.setattr(msm, "_device_is_tpu", lambda: True)
-    path = "pippenger" if stage == "scalars_pip" else "auto"
-    with mxu.force("vpu"), msm.force(path):
+    min_bucket 256, unique bucket 256) with the engine a TPU picks:
+    vpu."""
+    with mxu.force("vpu"):
         programs = [
             (avals, meta) for _, avals, meta in
             shapeset.enumerate_programs(max_batch=LANES,

@@ -72,7 +72,6 @@ DEFAULT_THRESHOLDS: Dict[str, float] = {
     "stage_p50_ms": 0.30,
     "dedup_speedup_8x_min": 1.5,
     "overload_p50_ms_max": 100.0,
-    "msm_scalars_speedup_min": 1.3,
     "mainnet_critical_p50_ms_max": 300.0,
     # committee-shaped floor: steady mixes measure ~0.34, the boundary
     # storm ~0.24 (brownout sheds duplicated gossip before dispatch);
@@ -229,21 +228,6 @@ def compare(base: dict, new: dict,
         warm.get("h2c_dispatches", new.get("warm_h2c_dispatches")),
         lambda v: v == 0,
         "a fully-warm H(m) cache must dispatch zero h2c")
-
-    # MSM gates (PR-8 acceptance property, absolute): the bucketed
-    # pippenger scalars stage must beat the ladder on the stage-
-    # profile p50 at every measured batch >= 256 (committee dup
-    # shape; skip-if-missing like the dedup gates)
-    for batch, entry in sorted((_get(new, "msm") or {}).items()):
-        if not isinstance(entry, dict) or not batch.isdigit() \
-                or int(batch) < 256:
-            continue
-        _check_absolute(
-            checks, f"msm_scalars_speedup_{batch}",
-            _get(entry, "scalars", "speedup"),
-            lambda v: v >= thr["msm_scalars_speedup_min"],
-            f"pippenger scalars-stage p50 must beat the ladder by >= "
-            f"{thr['msm_scalars_speedup_min']}x at batch {batch}")
 
     # overload gates (PR-7 acceptance properties, absolute): the
     # closed-loop phase's max-offered-load run must hold the SLO by
