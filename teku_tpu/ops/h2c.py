@@ -295,7 +295,12 @@ def messages_to_fields(messages, dst: bytes = DST_G2_POP):
 def to_affine_g2(p):
     """Jacobian -> affine on device (one batched inversion); infinity
     lanes return garbage coords — callers carry the infinity mask."""
-    zinv = T.fq2_inv(p[2])
+    return affine_g2_given(p, T.fq2_inv(p[2]))
+
+
+def affine_g2_given(p, zinv):
+    """to_affine_g2 for a caller that already holds z^-1 (an inversion
+    shared with other work: ops/verify.py:affine_with_signature)."""
     zinv2 = T.fq2_sqr(zinv)
     x = T.fq2_mul(p[0], zinv2)
     y = T.fq2_mul(p[1], T.fq2_mul(zinv2, zinv))

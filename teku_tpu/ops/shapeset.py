@@ -339,19 +339,20 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
         if out:
             yield out
         pk_r_jac, wsig = jax.eval_shape(V.stage_scalars, *sc_in)
-        grp_in = (pk_r_jac, miller_mask, group_idx, group_present)
+        grp_in = (pk_r_jac, miller_mask, group_idx, group_present, wsig)
         out = emit(stage_name("group"), grp_in,
                    {**meta, "stage": "group"})
         if out:
             yield out
-        agg_aff, u_mask = jax.eval_shape(V.stage_group, *grp_in)
-        mil_in = (agg_aff, hm_uniq, u_mask)
+        agg_aff, u_mask, s_aff, s_mask = jax.eval_shape(V.stage_group,
+                                                        *grp_in)
+        mil_in = (agg_aff, hm_uniq, u_mask, s_aff, s_mask)
         out = emit(stage_name("miller"), mil_in,
                    {**meta, "stage": "miller"})
         if out:
             yield out
         ml = jax.eval_shape(V.stage_miller, *mil_in)
-        out = emit(stage_name("finish"), (ml, wsig),
+        out = emit(stage_name("finish"), (ml,),
                    {**meta, "stage": "finish"})
         if out:
             yield out

@@ -226,16 +226,26 @@ def fq2_mul_by_xi(a):
     return (fp.sub(a[0], a[1]), fp.add(a[0], a[1]))
 
 
+def fq2_norm(a):
+    """The Fq norm a0^2 + a1^2 (compressed), the one value an Fq2
+    inversion has to invert.  Input may be lazy up to ~5 units."""
+    sq = fp.mont_sqr(_stk(a[0], a[1]))
+    return fp.compress(fp.add(sq[..., 0, :], sq[..., 1, :]))
+
+
+def fq2_inv_given(a, ninv):
+    """a^-1 = conj(a) * norm(a)^-1, for a caller that inverted
+    `fq2_norm(a)` in a batch of its own."""
+    t = fp.mont_mul(_stk(a[0], a[1]), ninv[..., None, :])
+    return (t[..., 0, :], fp.neg(t[..., 1, :]))
+
+
 def fq2_inv(a):
     """Branch-free inverse; inv(0) = 0 (callers select around zero).
     Input may be lazy up to ~5 units.  The underlying Fq inversion of
     the norm is batched across the whole batch shape (ONE Fermat
     exponentiation per call via limbs.inv_many)."""
-    sq = fp.mont_sqr(_stk(a[0], a[1]))
-    norm = fp.compress(fp.add(sq[..., 0, :], sq[..., 1, :]))
-    ninv = fp.inv_many(norm)
-    t = fp.mont_mul(_stk(a[0], a[1]), ninv[..., None, :])
-    return (t[..., 0, :], fp.neg(t[..., 1, :]))
+    return fq2_inv_given(a, fp.inv_many(fq2_norm(a)))
 
 
 def fq2_is_zero(a):

@@ -28,6 +28,16 @@ by the duplication factor with an unchanged verdict.
 Lanes carry masks instead of branches: padding lanes (valid=False)
 contribute the identity; infinity signatures contribute the infinity
 point exactly like the oracle (crypto/bls/pure_impl.py:205-214).
+
+THE SIGNATURE'S ROW.  The pair e(-g1, S) of the summed weighted
+signature S = sum_i [r_i]sig_i has no program of its own: its affine
+conversion shares the one Fermat inversion of the G1 affine conversion
+(affine_with_signature, inside stage_group / stage_lane_affine), its
+Miller loop is one more row of stage_miller's scan (the last), and
+stage_finish keeps the product over the rows and the final
+exponentiation.  The two mesh kernels know S only after their
+all_gather, so their tail (_finish) runs the same three pieces on that
+one row.
 """
 
 import numpy as np
@@ -52,14 +62,50 @@ _NEG_G1_Y = np.asarray(fp.int_to_mont(_NEG_G1[1]))
 point_batch_sum = PT.point_batch_sum
 
 
-def to_affine_g1(p):
-    """Batched Jacobian -> affine for G1 (one batched inversion: a
-    single Fermat exponentiation for the whole batch)."""
-    zinv = fp.inv_many(p[2])
+def _neg_g1_row():
+    """P of the signature's row: -g1, a (1,)-batched affine point."""
+    return (jnp.asarray(_NEG_G1_X)[None], jnp.asarray(_NEG_G1_Y)[None])
+
+
+def _affine_g1_given(p, zinv):
+    """to_affine_g1 for a caller that already holds the z^-1 batch."""
     zinv2 = fp.mont_sqr(zinv)
     t = fp.mont_mul(jnp.stack([p[0], fp.mont_mul(zinv2, zinv)], axis=-2),
                     jnp.stack([zinv2, p[1]], axis=-2))
     return (t[..., 0, :], t[..., 1, :])
+
+
+def to_affine_g1(p):
+    """Batched Jacobian -> affine for G1 (one batched inversion: a
+    single Fermat exponentiation for the whole batch)."""
+    return _affine_g1_given(p, fp.inv_many(p[2]))
+
+
+def affine_with_signature(pk_jac, wsig):
+    """The signature-row helper: ONE Fermat inversion converts a batch
+    of Jacobian G1 points AND the summed weighted signature to affine.
+
+    pk_jac: Jacobian G1, (U, L) leaves — or None where there are no G1
+        points to convert (the mesh kernels' post-gather tail).
+    wsig: the (1,)-batched Jacobian G2 sum S that stage_scalars returns.
+
+    The one `inv_many` runs over the U `z`s and the Fq norm of S.z
+    (z0^2 + z1^2, what towers.fq2_inv would invert alone); S.z^-1 is
+    conj(S.z) * norm^-1 and the coordinates follow as in to_affine_g2.
+    inv_many masks zero lanes out of its shared product, so an infinity
+    S and an infinity G1 point cannot poison each other; both come out
+    with garbage coordinates that `s_mask` / the caller's mask carry out
+    of the Miller loop.
+
+    Returns (pk_aff or None, s_aff ((1, L) leaves), s_mask (1,))."""
+    norm = T.fq2_norm(wsig[2])
+    zs = norm if pk_jac is None else jnp.concatenate(
+        [pk_jac[2], norm], axis=0)
+    zinv = fp.inv_many(zs)
+    s_aff = h2c.affine_g2_given(wsig, T.fq2_inv_given(wsig[2], zinv[-1:]))
+    pk_aff = None if pk_jac is None else _affine_g1_given(pk_jac,
+                                                          zinv[:-1])
+    return pk_aff, s_aff, ~PT.is_infinity(PT.G2_KIT, wsig)
 
 
 def _aggregate_lane_pks(pk_xs, pk_ys, pk_present):
@@ -86,10 +132,12 @@ def _aggregate_lane_pks(pk_xs, pk_ys, pk_present):
 
 def _lane_work(pk_xs, pk_ys, pk_present, hm_aff, sig_x_plain, sig_large,
                sig_inf, r_bits, lane_valid):
-    """Per-lane pipeline (shardable over the batch axis with no
-    communication), COMPOSED from the stage functions below so the
-    monolithic/sharded kernels and the staged dispatch can never
-    diverge.
+    """Per-lane pipeline of the lane-sharded mesh kernel (shardable
+    over the batch axis with no communication), COMPOSED from the stage
+    functions below and the pieces the row stages are made of
+    (to_affine_g1, PR.miller_loop), so the sharded kernel and the staged
+    dispatch can never diverge.  No signature row here: a shard holds a
+    partial sum only, so that row is `_finish`'s, after the gather.
 
     Takes the per-lane H(m) AFFINE points (`hm_aff`), not the field
     draws: hash-to-curve runs over the batch's UNIQUE messages upstream
@@ -104,20 +152,25 @@ def _lane_work(pk_xs, pk_ys, pk_present, hm_aff, sig_x_plain, sig_large,
         pk_xs, pk_ys, pk_present, sig_x_plain, sig_large, sig_inf,
         lane_valid)
     pk_r_jac, wsig = stage_scalars(pk_jac, sig_jac, r_bits)
-    ml = stage_miller(stage_lane_affine(pk_r_jac), hm_aff, miller_mask)
+    ml = PR.miller_loop(to_affine_g1(pk_r_jac), hm_aff, mask=miller_mask)
     return ml, wsig, lane_ok
 
 
-def _finish(ml_prod, s_sum):
-    """Cross-lane combine: one Miller loop on the aggregated-signature
-    lane and the shared final exponentiation."""
-    s_inf = PT.is_infinity(PT.G2_KIT, s_sum)
-    s_aff = h2c.to_affine_g2(tuple(
-        jax.tree_util.tree_map(lambda x: x[None], c) for c in s_sum))
-    neg_g1 = (jnp.asarray(_NEG_G1_X)[None], jnp.asarray(_NEG_G1_Y)[None])
-    ml_s = PR.miller_loop(neg_g1, s_aff, mask=~s_inf[None])
-    f = T.fq12_mul(ml_prod, jax.tree_util.tree_map(lambda x: x[0], ml_s))
-    return PR.pairing_check(f)
+def _finish(ml_prod, wsig):
+    """The mesh kernels' post-gather tail, where the signature's row
+    cannot ride the shards' inversion and scan (S exists only after
+    the all_gather): the same helper, a Miller loop on that one row,
+    and stage_finish's verdict.  `wsig` is (1,)-batched, `ml_prod` the
+    product of every shard's rows."""
+    _, s_aff, s_mask = affine_with_signature(None, wsig)
+    ml_s = PR.miller_loop(_neg_g1_row(), s_aff, mask=s_mask)
+    return _verdict(ml_prod, jax.tree_util.tree_map(lambda x: x[0], ml_s))
+
+
+def _verdict(rows_prod, sig_row):
+    """(product of the message rows) * (the signature's row), the
+    shared final exponentiation, == 1."""
+    return PR.pairing_check(T.fq12_mul(rows_prod, sig_row))
 
 
 # --------------------------------------------------------------------------
@@ -159,38 +212,36 @@ def stage_gather_hm(hm_uniq, lane_map):
     return jax.tree_util.tree_map(lambda x: x[lane_map], hm_uniq)
 
 
+def _signature_sum(sigs):
+    """Sum of Jacobian G2 points over axis 0, kept (1,)-batched: the
+    form every consumer of the signature sum takes."""
+    return jax.tree_util.tree_map(
+        lambda x: x[None], point_batch_sum(PT.G2_KIT, sigs))
+
+
 def stage_scalars(pk_jac, sig_jac, r_bits):
     """Random-multiplier scalar muls (Jacobian G1 out — the affine
     conversion happens per-lane in stage_lane_affine or per-UNIQUE in
     stage_group, whichever path runs).
 
     `wsig` comes back as the SUM of the weighted signatures, a
-    (1,)-batched point: stage_finish only ever consumes that sum."""
+    (1,)-batched point: the pairing only ever consumes that sum, and
+    stage_group / stage_lane_affine take it to affine next."""
     pk_r_jac = PT.scalar_mul_bits(PT.G1_KIT, r_bits, pk_jac)
-    wsig = point_batch_sum(
-        PT.G2_KIT, PT.scalar_mul_bits(PT.G2_KIT, r_bits, sig_jac))
-    return pk_r_jac, jax.tree_util.tree_map(lambda x: x[None], wsig)
+    return pk_r_jac, _signature_sum(
+        PT.scalar_mul_bits(PT.G2_KIT, r_bits, sig_jac))
 
 
-def stage_lane_affine(pk_r_jac):
-    """Per-lane batched G1 affine (the non-grouped pipeline)."""
-    return to_affine_g1(pk_r_jac)
+def stage_lane_affine(pk_r_jac, wsig):
+    """Per-lane batched G1 affine (the non-grouped pipeline) and, on
+    the same inversion, the signature's row: (pk_r_aff, s_aff, s_mask)
+    as affine_with_signature returns them."""
+    return affine_with_signature(pk_r_jac, wsig)
 
 
-def stage_group(pk_r_jac, miller_mask, group_idx, group_present):
-    """Fold each unique message's lanes into ONE pairing input.
-
-    The pairing is bilinear in its G1 argument, so lanes sharing H(m)
-    satisfy prod_i e([r_i]pk_i, H(m)) == e(sum_i [r_i]pk_i, H(m)): the
-    per-lane Miller loops of a committee-duplicated batch collapse to
-    one loop per UNIQUE message.  Masked lanes (padding/invalid) enter
-    the sum as infinity — exactly the identity contribution the
-    per-lane mask gave them — and a unique whose aggregate is infinity
-    is masked out of the Miller stage (e(infinity, Q) == 1).
-
-    group_idx: (U, G) lane indices of each unique's lanes (padded rows
-    arbitrary); group_present: (U, G) False for group padding.
-    Returns ((x, y) affine aggregates (U, L), u_mask (U,))."""
+def _group_aggregates(pk_r_jac, miller_mask, group_idx, group_present):
+    """Each unique message's lanes summed: (Jacobian aggregates (U, L),
+    u_mask (U,) false where a row sums to infinity)."""
     inf = PT.infinity_like(PT.G1_KIT, pk_r_jac[0])
     masked = PT._select_point(PT.G1_KIT, miller_mask, pk_r_jac, inf)
     grouped = jax.tree_util.tree_map(lambda x: x[group_idx], masked)
@@ -202,22 +253,60 @@ def stage_group(pk_r_jac, miller_mask, group_idx, group_present):
         gmoved = jax.tree_util.tree_map(
             lambda x: jnp.moveaxis(x, 1, 0), grouped)   # (G, U, L)
         agg = point_batch_sum(PT.G1_KIT, gmoved)
-    u_mask = ~PT.is_infinity(PT.G1_KIT, agg)
-    # affine conversion now costs ONE batched inversion at unique
-    # width, not lane width (infinity aggregates give garbage coords —
-    # u_mask carries them out of the Miller loop)
-    return to_affine_g1(agg), u_mask
+    return agg, ~PT.is_infinity(PT.G1_KIT, agg)
 
 
-def stage_miller(pk_r_aff, hm_aff, mask):
+def stage_group(pk_r_jac, miller_mask, group_idx, group_present, wsig):
+    """Fold each unique message's lanes into ONE pairing input, and
+    take the summed signature to affine on the same inversion.
+
+    The pairing is bilinear in its G1 argument, so lanes sharing H(m)
+    satisfy prod_i e([r_i]pk_i, H(m)) == e(sum_i [r_i]pk_i, H(m)): the
+    per-lane Miller loops of a committee-duplicated batch collapse to
+    one loop per UNIQUE message.  Masked lanes (padding/invalid) enter
+    the sum as infinity — exactly the identity contribution the
+    per-lane mask gave them — and a unique whose aggregate is infinity
+    is masked out of the Miller stage (e(infinity, Q) == 1).
+
+    group_idx: (U, G) lane indices of each unique's lanes (padded rows
+    arbitrary); group_present: (U, G) False for group padding;
+    wsig: stage_scalars' (1,)-batched signature sum.
+    Returns ((x, y) affine aggregates (U, L), u_mask (U,), s_aff,
+    s_mask): this program owns the signature's affine conversion."""
+    agg, u_mask = _group_aggregates(pk_r_jac, miller_mask, group_idx,
+                                    group_present)
+    # ONE batched inversion at unique width + 1, not lane width
+    # (infinity aggregates give garbage coords — u_mask carries them
+    # out of the Miller loop, s_mask an infinity signature sum)
+    agg_aff, s_aff, s_mask = affine_with_signature(agg, wsig)
+    return agg_aff, u_mask, s_aff, s_mask
+
+
+def stage_miller(pk_r_aff, hm_aff, mask, s_aff, s_mask):
     """Miller loops — width-polymorphic: per-lane inputs on the
-    hm-gather path, per-unique aggregates on the grouped path."""
-    return PR.miller_loop(pk_r_aff, hm_aff, mask=mask)
+    hm-gather path, per-unique aggregates on the grouped path — with
+    the signature's row (-g1, S) appended INSIDE the program as the
+    last row, so one scan at width U + 1 does what a private width-1
+    loop did, and the host's packing and row buckets never see it.
+    Returns (U + 1)-row Fq12 values; the last is ONE when s_mask is
+    false."""
+    def cat(a, b):
+        return jnp.concatenate([a, b], axis=0)
+
+    return PR.miller_loop(
+        jax.tree_util.tree_map(cat, pk_r_aff, _neg_g1_row()),
+        jax.tree_util.tree_map(cat, hm_aff, s_aff),
+        mask=cat(mask, s_mask))
 
 
-def stage_finish(ml, wsig):
-    """Cross-lane reduction + final exponentiation + verdict."""
-    return _finish(PR.batch_product(ml), point_batch_sum(PT.G2_KIT, wsig))
+def stage_finish(ml):
+    """Product of stage_miller's rows + final exponentiation + verdict.
+    The U message rows fold as a power of two (tree_fold_pairs rolls
+    only those into one loop); the signature's row, the last, joins
+    with one more multiplication."""
+    rows = jax.tree_util.tree_map(lambda x: x[:-1], ml)
+    sig_row = jax.tree_util.tree_map(lambda x: x[-1], ml)
+    return _verdict(PR.batch_product(rows), sig_row)
 
 
 _STAGED_JITS = None
@@ -281,9 +370,9 @@ def verify_staged_hm(pk_xs, pk_ys, pk_present, hm_aff, sig_x_plain,
         "prepare", pk_xs, pk_ys, pk_present, sig_x_plain, sig_large,
         sig_inf, lane_valid)
     pk_r_jac, wsig = run("scalars", pk_jac, sig_jac, r_bits)
-    pk_r_aff = run("affine", pk_r_jac)
-    ml = run("miller", pk_r_aff, hm_aff, miller_mask)
-    ok = run("finish", ml, wsig)
+    pk_r_aff, s_aff, s_mask = run("affine", pk_r_jac, wsig)
+    ml = run("miller", pk_r_aff, hm_aff, miller_mask, s_aff, s_mask)
+    ok = run("finish", ml)
     return ok, lane_ok
 
 
@@ -298,10 +387,10 @@ def verify_staged_grouped(pk_xs, pk_ys, pk_present, hm_uniq, group_idx,
         "prepare", pk_xs, pk_ys, pk_present, sig_x_plain, sig_large,
         sig_inf, lane_valid)
     pk_r_jac, wsig = run("scalars", pk_jac, sig_jac, r_bits)
-    agg_aff, u_mask = run("group", pk_r_jac, miller_mask, group_idx,
-                          group_present)
-    ml = run("miller", agg_aff, hm_uniq, u_mask)
-    ok = run("finish", ml, wsig)
+    agg_aff, u_mask, s_aff, s_mask = run(
+        "group", pk_r_jac, miller_mask, group_idx, group_present, wsig)
+    ml = run("miller", agg_aff, hm_uniq, u_mask, s_aff, s_mask)
+    ok = run("finish", ml)
     return ok, lane_ok
 
 
@@ -379,9 +468,9 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp"):
             pk_xs, pk_ys, pk_present, sig_x, sig_large, sig_inf,
             lane_valid)
         pk_r_jac, wsig = stage_scalars(pk_jac, sig_jac, r_bits)
-        agg_aff, u_mask = stage_group(pk_r_jac, miller_mask, group_idx,
-                                      group_present)
-        ml = stage_miller(agg_aff, hm_rows, u_mask)
+        agg, u_mask = _group_aggregates(pk_r_jac, miller_mask,
+                                        group_idx, group_present)
+        ml = PR.miller_loop(to_affine_g1(agg), hm_rows, mask=u_mask)
         local_prod = PR.batch_product(ml)
         local_sum = point_batch_sum(PT.G2_KIT, wsig)
         # the tiny per-device partials (one Fq12 value + one G2 point)
@@ -391,7 +480,7 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp"):
         gathered_sum = jax.tree_util.tree_map(
             lambda x: jax.lax.all_gather(x, axis), local_sum)
         ok = _finish(PR.batch_product(gathered_prod),
-                     point_batch_sum(PT.G2_KIT, gathered_sum))
+                     _signature_sum(gathered_sum))
         return ok, lane_ok
 
     in_specs = (lane3, lane3, lane2,
@@ -441,9 +530,8 @@ def verify_kernel_sharded(mesh, axis: str = "dp"):
             lambda x: jax.lax.all_gather(x, axis), local_prod)
         gathered_sum = jax.tree_util.tree_map(
             lambda x: jax.lax.all_gather(x, axis), local_sum)
-        total_prod = PR.batch_product(gathered_prod)
-        total_sum = point_batch_sum(PT.G2_KIT, gathered_sum)
-        ok = _finish(total_prod, total_sum)
+        ok = _finish(PR.batch_product(gathered_prod),
+                     _signature_sum(gathered_sum))
         return ok, lane_ok
 
     in_specs = (lane3, lane3, lane2,

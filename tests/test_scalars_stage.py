@@ -3,7 +3,9 @@
 `stage_scalars` -> `stage_group` is what every dispatch runs between
 `stage_prepare` and the Miller loops: a 64-bit multiplier a lane, the
 r-weighted public keys folded into one pairing input a Miller row, the
-r-weighted signatures folded into one G2 point.  Every case here is
+r-weighted signatures folded into one G2 point that `stage_group` takes
+to affine on the rows' one inversion (tests/test_signature_row.py has
+that row's own cases).  Every case here is
 compared with `crypto/bls/curve.py` / `PureBls12381` on the host, never
 with another device path:
 
@@ -88,6 +90,21 @@ def assert_affine_rows(agg_aff, u_mask, want_rows):
         ex, ey = C.to_affine(C.FQ_OPS, want)
         assert np.array_equal(xs[u], fp.int_to_limbs(ex)), f"row {u}"
         assert np.array_equal(ys[u], fp.int_to_limbs(ey)), f"row {u}"
+
+
+def assert_signature_row(s_aff, s_mask, want):
+    """`stage_group`'s second result, the summed signature taken to
+    affine on the aggregates' inversion, against the oracle's point."""
+    assert np.asarray(s_mask).shape == (1,)
+    if C.is_infinity(C.FQ2_OPS, want):
+        assert not bool(np.asarray(s_mask)[0])
+        return
+    assert bool(np.asarray(s_mask)[0])
+    (ex0, ex1), (ey0, ey1) = C.to_affine(C.FQ2_OPS, want)
+    for got, e in zip(jax.tree_util.tree_leaves(s_aff),
+                      (ex0, ex1, ey0, ey1)):
+        assert np.array_equal(np.asarray(fp.canonical_plain(got))[0],
+                              fp.int_to_limbs(e))
 
 
 def _nudged_multipliers(monkeypatch, lanes):
@@ -196,14 +213,16 @@ def grid():
     def scalars_then_group(pk_jac, sig_jac, r_bits, miller_mask,
                            group_idx, group_present):
         pk_r_jac, wsig = V.stage_scalars(pk_jac, sig_jac, r_bits)
-        return V.stage_group(pk_r_jac, miller_mask, group_idx,
-                             group_present) + (wsig,)
+        agg_aff, u_mask, s_aff, s_mask = V.stage_group(
+            pk_r_jac, miller_mask, group_idx, group_present, wsig)
+        return agg_aff, u_mask, wsig, s_aff, s_mask
 
     calls = []
     for c, g2_pattern in enumerate(G2_PATTERNS):
         g1 = G1_PATTERNS[ROWS * c:ROWS * c + ROWS]
         args, want_rows, want_wsig = _grid_call(g1, g2_pattern, nudged)
-        agg_aff, u_mask, wsig = scalars_then_group(*args)
+        agg_aff, u_mask, wsig, s_aff, s_mask = scalars_then_group(*args)
+        assert_signature_row(s_aff, s_mask, want_wsig)
         calls.append((agg_aff, u_mask, wsig, want_rows, want_wsig))
     assert scalars_then_group._cache_size() == 1
     return {"calls": calls, "nudged": nudged}
@@ -338,9 +357,12 @@ def test_stage_group_layout(rows, row_lanes, half):
     present = np.ones((rows, row_lanes), dtype=bool)
     if half:
         present[:, row_lanes // 2:] = False
-    agg_aff, u_mask = jax.jit(V.stage_group)(
-        stack_g1(pts), np.ones(lanes, dtype=bool), group_idx, present)
+    sig = rand_g2()
+    agg_aff, u_mask, s_aff, s_mask = jax.jit(V.stage_group)(
+        stack_g1(pts), np.ones(lanes, dtype=bool), group_idx, present,
+        stack_g2([sig]))
     want = [weighted_sum(C.FQ_OPS, [1] * row_lanes,
                          [pts[i] for i in group_idx[u]], present[u])
             for u in range(rows)]
     assert_affine_rows(agg_aff, u_mask, want)
+    assert_signature_row(s_aff, s_mask, sig)

@@ -251,6 +251,41 @@ def test_enumerated_programs_are_the_launched_ones(
     assert len(ran) == len(enumerated) == (5 if h2c_missing == 0 else 6)
 
 
+@pytest.mark.parametrize("env,lane_groups,rows", [
+    (BACKFILL_ENV, [1] * 250, 256), (GOSSIP_ENV, [250], 16)],
+    ids=["all-unique", "gossip"])
+def test_registry_follows_the_signature_row(monkeypatch, env, lane_groups,
+                                            rows):
+    """The signature's row is added inside `stage_miller`, so the
+    registry's avals follow it through `jax.eval_shape` with no field of
+    `batch_plan` knowing: `stage_group` takes the (1,)-batched signature
+    sum, `stage_miller` the row's Q and mask and returns one row more
+    than the row bucket, which is all `stage_finish` takes."""
+    from jax.tree_util import tree_leaves as jax_leaves
+
+    from teku_tpu.ops import limbs as fp
+    impl, _pks = _cell_provider(monkeypatch, env)
+    monkeypatch.setattr(shapeset, "warmup_profiles",
+                        lambda max_batch: [("drain", lane_groups, None)])
+    avals = {meta["stage"]: avals for _k, avals, meta in
+             shapeset.enumerate_programs(
+                 max_batch=256, min_bucket=256,
+                 h2c_min_bucket=impl._h2c_min_bucket,
+                 group_cap=impl._group_cap)}
+    plan = shapeset.batch_plan(lane_groups, min_bucket=256,
+                               h2c_min_bucket=impl._h2c_min_bucket,
+                               group_cap=impl._group_cap)
+    assert plan["u_hm"] == rows
+    *_, wsig = avals["group"]
+    assert len(avals["group"]) == 5
+    assert [leaf.shape for leaf in jax_leaves(wsig)] == [(1, fp.L)] * 6
+    agg_aff, hm, u_mask, s_aff, s_mask = avals["miller"]
+    assert u_mask.shape == (rows,) and s_mask.shape == (1,)
+    assert [leaf.shape for leaf in jax_leaves(s_aff)] == [(1, fp.L)] * 4
+    (ml,) = avals["finish"]
+    assert {leaf.shape for leaf in jax_leaves(ml)} == {(rows + 1, fp.L)}
+
+
 @pytest.mark.parametrize("lanes,rows", [(4096, 128), (4096, 512),
                                         (2049, 256)])
 def test_wide_batches_plan_the_same_stage_programs(monkeypatch, lanes,
