@@ -1140,8 +1140,8 @@ def cmd_precompile(args) -> int:
     outcomes = {"compile": 0, "load": 0, "error": 0}
     t_all = _time.monotonic()
     for kernel, avals, meta in programs:
-        if meta.get("stage") == "mesh_kernel":
-            impl._sharded.kernel()
+        if str(meta.get("stage")).startswith("mesh_"):
+            impl._sharded.programs()
         disp = aotstore.dispatchers().get(kernel)
         if disp is None:
             print(f"  SKIP {kernel}: no registered dispatcher "

@@ -15,7 +15,9 @@ the file name and carry an identity header — jax version, backend
 platform, device kind, device count, and a fingerprint of the kernel
 source tree — checked at load: a mismatched or corrupt entry degrades
 to a fresh compile with ONE WARN per complaint (the infra/env.py knob
-contract, applied to blobs).
+contract, applied to blobs).  An entry built for another platform,
+device count or device assignment than this process has is another
+machine's, not a fault: it reads as a MISS and is overwritten.
 
 ``wrap()`` is the serving seam: it decorates a jitted callable so each
 argument signature resolves ONCE per process — to the deserialized
@@ -162,6 +164,11 @@ def identity() -> dict:
             "fingerprint": fingerprint()}
 
 
+# the identity keys that say WHERE an entry may run, as against what
+# it was built from (format, jax version, the kernels' source)
+_PLACE_KEYS = ("platform", "device_kind", "device_count")
+
+
 def shape_sig(args: Sequence) -> tuple:
     """Canonical hashable signature of one positional-argument tuple:
     the flattened pytree structure plus each leaf's (shape, dtype).
@@ -259,8 +266,9 @@ def save(kernel: str, sig: tuple, compiled) -> Optional[str]:
 def load(kernel: str, sig: tuple,
          record: Optional[dict] = None) -> Optional[Callable]:
     """Deserialize the stored executable for (kernel, sig), or None —
-    missing entries count a miss; corrupt blobs and identity
-    mismatches (jax version / device / code fingerprint) degrade to
+    missing entries, and entries built for another platform, device
+    count or device assignment, count a miss; corrupt blobs and
+    identity mismatches (jax version / code fingerprint) degrade to
     None with ONE WARN per complaint, and the caller compiles fresh.
     `record` (a load record in the making) takes the entry's bytes and
     the seconds of the read and of the deserialize (`decode_s`: its
@@ -302,7 +310,21 @@ def _deserialize(path: str, blob: bytes, record: Optional[dict]
                    f"aot store: corrupt entry {os.path.basename(path)}"
                    " (unreadable blob); compiling fresh")
         return None
+    import jax
     want = identity()
+    by_id = {d.id: d for d in jax.devices()}
+    if (any(stored.get(k) != want[k] for k in _PLACE_KEYS)
+            or any(i not in by_id for i in entry.get("devices", ()))):
+        # built for another platform, device kind or count, or for
+        # devices this process lacks: another machine's entry (a
+        # checkout that ran the CPU tests, or a one-chip cell, before
+        # a four-chip one), not a fault of the store.  A miss: the
+        # fresh compile overwrites it for this machine
+        _count("miss")
+        _LOG.info("aot store: %s was built for %s x%s; compiling for "
+                  "this process", os.path.basename(path),
+                  stored.get("device_kind"), stored.get("device_count"))
+        return None
     if stored != want:
         _count("error")
         drift = sorted(k for k in want
@@ -315,8 +337,6 @@ def _deserialize(path: str, blob: bytes, record: Optional[dict]
     if record is not None:
         record["decode_s"] = round(time.perf_counter() - t_decode, 6)
     try:
-        import jax
-        by_id = {d.id: d for d in jax.devices()}
         payload, in_tree, out_tree = entry["triple"]
         fn = serialize_executable.deserialize_and_load(
             payload, in_tree, out_tree,

@@ -34,15 +34,18 @@ MESH: constructed with mesh=..., dispatches shard GROUP-ALIGNED
 across the chips (teku_tpu/parallel.GroupShardedVerifier): whole
 message-group rows per shard, lanes permuted to follow their rows, so
 the dedup pipeline (unique-message h2c, grouped Miller rows)
-survives the mesh; one all_gather of per-device partials crosses the
-ICI and the verdict contract is unchanged (lane_ok un-permutes at the
-sync point).
+survives the mesh: hash-to-curve, the arena and the gather of the H(m)
+rows into the shard layout run on the mesh's first chip, the stage
+functions on every chip's own lanes and rows; one all_gather of
+per-device Fq12 partials crosses the ICI and the verdict contract is
+unchanged (lane_ok un-permutes at the sync point).
 
 Batch sizes (and the per-lane key-count axis) are padded to powers of
 two so the jit cache stays small and shapes stay static (XLA recompiles
 nothing after warm-up).
 """
 
+import contextlib
 import hashlib
 import os
 import secrets
@@ -869,6 +872,17 @@ class JaxBls12381(BLS12381):
             slots[np.asarray(missing)] = new_slots
         return self._h2c_cache.gather(slots[pack.row_msg])
 
+    @staticmethod
+    def _hm_programs(plan, pack: "_Packed") -> List[str]:
+        """The programs `_hm_device` launches for this plan, by the
+        names the profiler's module line gives them."""
+        slots, missing, _, _ = plan
+        if slots is None:
+            return ["stage_h2c"] + (
+                [] if pack.n_rows == pack.n_unique
+                else ["stage_gather_hm"])
+        return (["stage_h2c", "_scatter"] if missing else []) + ["_gather"]
+
     def _pack(self, semis: Sequence[_Semi],
               randomize: bool) -> "_Packed":
         """One dispatch's host half: numpy and plain Python alone."""
@@ -1121,6 +1135,20 @@ class JaxBls12381(BLS12381):
         try:
             hm_uniq = self._hm_device(hm_plan, pack)
             if self._sharded is not None:
+                # what a reader needs to split this dispatch: up to
+                # here every program ran on ONE chip (stage_h2c, the
+                # arena, the row gather below) while the others stood
+                # idle; from `sharded_at_s` (on device_enqueue's
+                # clock) the sharded programs launch on all
+                one_chip = [str(d) for d in jax.tree_util.tree_leaves(
+                    hm_uniq)[0].devices()]
+                mesh_block["programs"] = (
+                    [{"name": name, "on": one_chip}
+                     for name in self._hm_programs(hm_plan, pack)
+                     + ["stage_gather_hm"]]
+                    + [{"name": f"mesh_{name}",
+                        "on": mesh_block["live"]}
+                       for name in V.MESH_STAGES])
                 # `bls.mesh_shard` fault site: a wedged SHARD wedges
                 # the whole mesh dispatch.  The LIVE device names ride
                 # as keys so the chaos harness can wedge exactly one
@@ -1133,17 +1161,22 @@ class JaxBls12381(BLS12381):
                 # scatter the canonical H(m) rows into the shard
                 # layout with one gather, then the group-aligned
                 # kernel runs the full dedup pipeline per shard
-                hm_rows = V.staged_jits()["gather"](
+                hm_in = V.staged_jits()["gather"](
                     hm_uniq, jnp.asarray(pack.row_gather))
                 kernel = self._sharded.kernel()
-                hm_in = hm_rows
+                mesh_block["sharded_at_s"] = round(
+                    time.perf_counter() - t_dev0, 6)
+                launch = jax.profiler.TraceAnnotation("mesh_launch")
             else:
                 kernel = V.verify_staged_grouped
                 hm_in = hm_uniq
-            ok, lane_ok = kernel(
-                pack.pk_xs, pack.pk_ys, pack.pk_present, hm_in,
-                pack.group_idx, pack.group_present, pack.sx, pack.s_large,
-                pack.s_inf, pack.r_bits, pack.lane_valid)
+                launch = contextlib.nullcontext()
+            with launch:
+                ok, lane_ok = kernel(
+                    pack.pk_xs, pack.pk_ys, pack.pk_present, hm_in,
+                    pack.group_idx, pack.group_present, pack.sx,
+                    pack.s_large, pack.s_inf, pack.r_bits,
+                    pack.lane_valid)
             enqueued = True
         finally:
             if first:

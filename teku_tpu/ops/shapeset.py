@@ -305,8 +305,8 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
         group_idx = _sds((U, G), i32)
         group_present = _sds((U, G), b_)
         if mesh_devices >= 2:
-            # mesh: prepare/scalars/group run inside the sharded
-            # kernel; the host-side programs are gather + the kernel
+            # mesh: the single-chip gather, then the stages as
+            # sharded programs of their own and the exchange
             row_gather = _sds((U,), i32)
             hm_rows = jax.eval_shape(V.stage_gather_hm, hm_uniq,
                                      row_gather)
@@ -314,18 +314,42 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
                        {**meta, "stage": "gather"})
             if out:
                 yield out
+            from jax.sharding import NamedSharding, PartitionSpec
             from .. import parallel
-            kern = parallel.kernel_store_name(
-                [str(d) for d in np.ravel(mesh.devices)], axis)
-            sig_x = (_sds((P, fp.L), i64), _sds((P, fp.L), i64))
-            out = emit(kern, (
-                prepare_in[0], prepare_in[1], prepare_in[2], hm_rows,
-                group_idx, group_present, sig_x, _sds((P,), b_),
-                _sds((P,), b_), r_bits, _sds((P,), b_)),
-                {**meta, "stage": "mesh_kernel", "axis": axis,
-                 "devices": mesh_devices})
-            if out:
-                yield out
+            names = [str(d) for d in np.ravel(mesh.devices)]
+            over_chips = NamedSharding(mesh, PartitionSpec(axis))
+            fns = V.verify_kernel_sharded_grouped(mesh, axis)
+
+            def emit_mesh(name, avals):
+                # every argument arrives sharded over its leading axis
+                avals = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=over_chips), avals)
+                return emit(
+                    parallel.kernel_store_name(names, axis, name), avals,
+                    {**meta, "stage": f"mesh_{name}", "axis": axis,
+                     "devices": mesh_devices})
+
+            # the chain GroupShardedVerifier.kernel runs, avals from
+            # the real sharded stage functions
+            chain = [("prepare", prepare_in)]
+            pk_jac, sig_jac, _lane_ok, miller_mask = jax.eval_shape(
+                fns["prepare"], *chain[-1][1])
+            chain.append(("scalars", (pk_jac, sig_jac, r_bits)))
+            pk_r_jac, wsig = jax.eval_shape(fns["scalars"],
+                                            *chain[-1][1])
+            chain.append(("group", (pk_r_jac, miller_mask, group_idx,
+                                    group_present, wsig)))
+            agg_aff, u_mask, s_aff, s_mask = jax.eval_shape(
+                fns["group"], *chain[-1][1])
+            chain.append(("miller", (agg_aff, hm_rows, u_mask, s_aff,
+                                     s_mask)))
+            chain.append(("exchange", (jax.eval_shape(
+                fns["miller"], *chain[-1][1]),)))
+            for name, avals in chain:
+                out = emit_mesh(name, avals)
+                if out:
+                    yield out
             continue
         out = emit(stage_name("prepare"), prepare_in,
                    {**meta, "stage": "prepare"})

@@ -35,9 +35,12 @@ conversion shares the one Fermat inversion of the G1 affine conversion
 (affine_with_signature, inside stage_group / stage_lane_affine), its
 Miller loop is one more row of stage_miller's scan (the last), and
 stage_finish keeps the product over the rows and the final
-exponentiation.  The two mesh kernels know S only after their
-all_gather, so their tail (_finish) runs the same three pieces on that
-one row.
+exponentiation.  The group-sharded mesh kernel does the same a shard:
+by bilinearity prod_k e(-g1, S_k) == e(-g1, sum_k S_k), so each shard's
+row is its OWN partial sum S_k and only Fq12 partial products cross the
+chips.  The legacy lane-sharded kernel knows S only after its
+all_gather, so its tail (_finish) runs the three pieces on that one
+row.
 """
 
 import numpy as np
@@ -157,20 +160,15 @@ def _lane_work(pk_xs, pk_ys, pk_present, hm_aff, sig_x_plain, sig_large,
 
 
 def _finish(ml_prod, wsig):
-    """The mesh kernels' post-gather tail, where the signature's row
-    cannot ride the shards' inversion and scan (S exists only after
-    the all_gather): the same helper, a Miller loop on that one row,
-    and stage_finish's verdict.  `wsig` is (1,)-batched, `ml_prod` the
-    product of every shard's rows."""
+    """The legacy lane-sharded kernel's post-gather tail, where the
+    signature's row cannot ride the shards' inversion and scan (S
+    exists only after the all_gather): the same helper, a Miller loop
+    on that one row, and stage_finish's verdict.  `wsig` is
+    (1,)-batched, `ml_prod` the product of every shard's rows."""
     _, s_aff, s_mask = affine_with_signature(None, wsig)
     ml_s = PR.miller_loop(_neg_g1_row(), s_aff, mask=s_mask)
-    return _verdict(ml_prod, jax.tree_util.tree_map(lambda x: x[0], ml_s))
-
-
-def _verdict(rows_prod, sig_row):
-    """(product of the message rows) * (the signature's row), the
-    shared final exponentiation, == 1."""
-    return PR.pairing_check(T.fq12_mul(rows_prod, sig_row))
+    sig_row = jax.tree_util.tree_map(lambda x: x[0], ml_s)
+    return PR.pairing_check(T.fq12_mul(ml_prod, sig_row))
 
 
 # --------------------------------------------------------------------------
@@ -299,14 +297,19 @@ def stage_miller(pk_r_aff, hm_aff, mask, s_aff, s_mask):
         mask=cat(mask, s_mask))
 
 
-def stage_finish(ml):
-    """Product of stage_miller's rows + final exponentiation + verdict.
+def fold_rows(ml):
+    """Product of stage_miller's rows, before the final exponentiation.
     The U message rows fold as a power of two (tree_fold_pairs rolls
     only those into one loop); the signature's row, the last, joins
     with one more multiplication."""
     rows = jax.tree_util.tree_map(lambda x: x[:-1], ml)
     sig_row = jax.tree_util.tree_map(lambda x: x[-1], ml)
-    return _verdict(PR.batch_product(rows), sig_row)
+    return T.fq12_mul(PR.batch_product(rows), sig_row)
+
+
+def stage_finish(ml):
+    """Product of stage_miller's rows + final exponentiation + verdict."""
+    return PR.pairing_check(fold_rows(ml))
 
 
 _STAGED_JITS = None
@@ -429,10 +432,16 @@ def verify_staged(pk_xs, pk_ys, pk_present, u0, u1, group_idx,
                                  on_stage=on_stage)
 
 
+# the programs of one group-sharded mesh dispatch, in launch order: the
+# four shard-local stages, then the one cross-chip exchange
+MESH_STAGES = ("prepare", "scalars", "group", "miller", "exchange")
+
+
 def verify_kernel_sharded_grouped(mesh, axis: str = "dp"):
     """Multi-chip variant of the DEDUP-AWARE pipeline: message groups
     are the sharding unit, so every chip keeps the unique-message
-    Miller grouping that the lane-sharded kernel forfeits.
+    Miller grouping that the lane-sharded kernel forfeits, and runs
+    the single-chip stage functions on its own lanes and rows.
 
     GROUP-ALIGNED contract (the provider's shard planner,
     teku_tpu/parallel.plan_group_shards, builds these layouts):
@@ -445,51 +454,59 @@ def verify_kernel_sharded_grouped(mesh, axis: str = "dp"):
     - group rows are ROW-sharded: hm_rows (the per-row H(m) affine
       tree, (U, L) leaves), group_idx (U, G) of SHARD-LOCAL lane
       indices, group_present (U, G).  Padding rows aggregate to
-      infinity and mask themselves out of the Miller stage, so empty
-      shards contribute exactly the identity.
+      infinity and mask themselves out of the Miller stage.
 
-    Per shard: prepare -> scalars -> group -> Miller loops at LOCAL
-    row width -> local Fq12 product + local G2 weighted-signature sum;
-    then ONE all_gather of those two tiny partials crosses the ICI and
-    the final exponentiation is replicated.  Returns (ok, lane_ok) with lane_ok
-    in the PERMUTED lane order (callers un-permute on the host).
+    Returns {name: function} over MESH_STAGES, each jitted apart by
+    the caller like the staged programs of one chip (a stage compiles
+    and caches on its own: a new group bucket costs `group` alone),
+    each named `mesh_<name>` on the profiler's module line:
+
+    - `prepare`, `scalars`, `group`: stage_prepare / stage_scalars /
+      stage_group under shard_map, every argument and result sharded
+      over its leading axis, NO communication.  A shard's `wsig` is
+      its OWN partial sum S_k: by bilinearity prod_k e(-g1, S_k) ==
+      e(-g1, sum_k S_k), so the signature's row rides each shard's one
+      inversion and its scan exactly as on one chip.
+    - `miller`: stage_miller at the shard's rows + that row, folded
+      (fold_rows) to ONE pre-exponentiation Fq12 partial a shard
+      ((n_shards, ...) leaves).  An empty shard's S_k is infinity and
+      its rows are masked, so its partial is ONE.
+    - `exchange`: ONE all_gather of the Fq12 partials, the only
+      cross-chip traffic; their product and the final exponentiation
+      (stage_finish's) are replicated.  Returns `ok`.
+
+    `lane_ok` (from `prepare`) is in the PERMUTED lane order (callers
+    un-permute on the host).
     """
     from jax.sharding import PartitionSpec as P
 
-    lane = P(axis)
-    lane2 = P(axis, None)        # (N, L) / (N, 64) / (N, K)
-    lane3 = P(axis, None, None)  # (N, K, L)
-    row2 = P(axis, None)         # (U, G) and the (U, L) hm leaves
+    def miller_fold(agg_aff, hm_rows, u_mask, s_aff, s_mask):
+        partial = fold_rows(
+            stage_miller(agg_aff, hm_rows, u_mask, s_aff, s_mask))
+        return jax.tree_util.tree_map(lambda x: x[None], partial)
 
-    def shard_fn(pk_xs, pk_ys, pk_present, hm_rows, group_idx,
-                 group_present, sig_x, sig_large, sig_inf, r_bits,
-                 lane_valid):
-        pk_jac, sig_jac, lane_ok, miller_mask = stage_prepare(
-            pk_xs, pk_ys, pk_present, sig_x, sig_large, sig_inf,
-            lane_valid)
-        pk_r_jac, wsig = stage_scalars(pk_jac, sig_jac, r_bits)
-        agg, u_mask = _group_aggregates(pk_r_jac, miller_mask,
-                                        group_idx, group_present)
-        ml = PR.miller_loop(to_affine_g1(agg), hm_rows, mask=u_mask)
-        local_prod = PR.batch_product(ml)
-        local_sum = point_batch_sum(PT.G2_KIT, wsig)
-        # the tiny per-device partials (one Fq12 value + one G2 point)
-        # are the ONLY cross-chip traffic; combine + finish replicated
-        gathered_prod = jax.tree_util.tree_map(
-            lambda x: jax.lax.all_gather(x, axis), local_prod)
-        gathered_sum = jax.tree_util.tree_map(
-            lambda x: jax.lax.all_gather(x, axis), local_sum)
-        ok = _finish(PR.batch_product(gathered_prod),
-                     _signature_sum(gathered_sum))
-        return ok, lane_ok
+    def exchange(partials):
+        gathered = jax.tree_util.tree_map(
+            lambda x: jax.lax.all_gather(x[0], axis), partials)
+        return PR.pairing_check(PR.batch_product(gathered))
 
-    in_specs = (lane3, lane3, lane2,
-                ((row2, row2), (row2, row2)),   # hm rows (affine x, y)
-                row2, row2,                     # group idx / present
-                (lane2, lane2), lane, lane, lane2, lane)
-    out_specs = (P(), lane)
-    return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    def over_shards(name, fn, out_specs):
+        # every array of the pipeline, lanes, rows, a shard's (1,)
+        # signature sum and its partial alike, is sharded over its
+        # leading axis: one spec stands for every leaf
+        sharded = jax.shard_map(fn, mesh=mesh, in_specs=P(axis),
+                                out_specs=out_specs, check_vma=False)
+        # a jitted function's name is its module's on the profiler's
+        # line, which is all a trace reader sees of it
+        sharded.__name__ = sharded.__qualname__ = f"mesh_{name}"
+        return sharded
+
+    stages = {"prepare": stage_prepare, "scalars": stage_scalars,
+              "group": stage_group, "miller": miller_fold}
+    out = {name: over_shards(name, fn, P(axis))
+           for name, fn in stages.items()}
+    out["exchange"] = over_shards("exchange", exchange, P())
+    return out
 
 
 def verify_kernel_sharded(mesh, axis: str = "dp"):

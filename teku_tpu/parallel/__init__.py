@@ -6,9 +6,10 @@ and since PR 5 the unit of per-lane work is the MESSAGE GROUP (h2c and
 the Miller loops run once per unique message), the production sharding
 unit is the group row, not the raw lane: ``plan_group_shards`` packs
 whole (message, lane-chunk) rows onto shards so every chip runs the
-full dedup-aware pipeline (grouped Miller rows) on its shard, then ONE
-tiny all_gather (a per-device Fq12 partial product + G2 partial point-sum)
-crosses the interconnect before the replicated final exponentiation
+full dedup-aware pipeline (the single-chip stage functions, the
+signature's row on the shard's own partial sum) on its shard, then ONE
+tiny all_gather (a per-device Fq12 partial product) crosses the
+interconnect before the replicated final exponentiation
 (teku_tpu/ops/verify.py:verify_kernel_sharded_grouped).
 
 The reference has no chip-mesh analogue — its scale-out is worker
@@ -296,9 +297,11 @@ _KERNELS: dict = {}
 _KERNELS_LOCK = threading.Lock()
 
 
-def kernel_store_name(devices: Sequence[str], axis: str) -> str:
-    """AOT-store kernel name for a sharded verify program.  The
-    device LIST (not just the count) is part of the name: a serialized
+def kernel_store_name(devices: Sequence[str], axis: str,
+                      program: str) -> str:
+    """AOT-store kernel name for one of a mesh's sharded verify
+    programs (`program` is one of ops/verify.MESH_STAGES).  The device
+    LIST (not just the count) is part of the name: a serialized
     executable binds its device assignment, so an entry compiled for
     mesh [0..3] must never deserialize onto a healed mesh that ejected
     device 2 — those are different programs to the store.  Mont path
@@ -307,16 +310,18 @@ def kernel_store_name(devices: Sequence[str], axis: str) -> str:
 
     from ..ops import mxu
     dev = hashlib.sha256(repr(tuple(devices)).encode()).hexdigest()[:8]
-    return f"mesh:{len(devices)}:{axis}:{mxu.resolve()}:{dev}"
+    return (f"mesh:{len(devices)}:{axis}:{mxu.resolve()}:{dev}"
+            f":{program}")
 
 
 class GroupShardedVerifier:
     """Group-aligned production mesh dispatch.
 
     Owns the per-dispatch shard planner (plan()) and the jitted
-    verify_kernel_sharded_grouped of its (devices, axis).  The padding
-    rule keeps every shard's shapes identical (pow2 lanes/rows per
-    shard) — the multi-chip twin of the provider's bucket rule."""
+    programs of verify_kernel_sharded_grouped for its (devices, axis).
+    The padding rule keeps every shard's shapes identical (pow2
+    lanes/rows per shard) — the multi-chip twin of the provider's
+    bucket rule."""
 
     def __init__(self, mesh: Mesh, axis: str = DEFAULT_AXIS,
                  min_bucket: int = 16):
@@ -346,16 +351,42 @@ class GroupShardedVerifier:
         a fresh instance over known devices is NOT a fresh program)."""
         return (tuple(self.devices), self.axis)
 
-    def kernel(self):
+    def programs(self) -> dict:
+        """The mesh's store-wrapped programs by name
+        (ops/verify.MESH_STAGES)."""
         key = self.kernel_key()
         with _KERNELS_LOCK:
-            fn = _KERNELS.get(key)
-            if fn is None:
+            fns = _KERNELS.get(key)
+            if fns is None:
                 from ..infra import aotstore
                 from ..ops import verify as V
-                fn = aotstore.wrap(
-                    kernel_store_name(self.devices, self.axis),
-                    jax.jit(V.verify_kernel_sharded_grouped(
-                        self.mesh, self.axis)))
-                _KERNELS[key] = fn
-        return fn
+                fns = {
+                    name: aotstore.wrap(
+                        kernel_store_name(self.devices, self.axis, name),
+                        jax.jit(fn))
+                    for name, fn in V.verify_kernel_sharded_grouped(
+                        self.mesh, self.axis).items()}
+                _KERNELS[key] = fns
+        return fns
+
+    def kernel(self):
+        """The whole sharded dispatch as one callable with
+        verify_staged_grouped's arguments and result: the same chain,
+        the product crossing the chips before the final
+        exponentiation."""
+        programs = self.programs()
+
+        def run(pk_xs, pk_ys, pk_present, hm_rows, group_idx,
+                group_present, sig_x_plain, sig_large, sig_inf, r_bits,
+                lane_valid):
+            pk_jac, sig_jac, lane_ok, miller_mask = programs["prepare"](
+                pk_xs, pk_ys, pk_present, sig_x_plain, sig_large,
+                sig_inf, lane_valid)
+            pk_r_jac, wsig = programs["scalars"](pk_jac, sig_jac, r_bits)
+            agg_aff, u_mask, s_aff, s_mask = programs["group"](
+                pk_r_jac, miller_mask, group_idx, group_present, wsig)
+            partials = programs["miller"](agg_aff, hm_rows, u_mask,
+                                          s_aff, s_mask)
+            return programs["exchange"](partials), lane_ok
+
+        return run

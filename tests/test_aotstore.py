@@ -179,6 +179,47 @@ def test_identity_mismatch_one_warn_and_fresh_compile(
         "the WARN must name the fix (re-run cli precompile)"
 
 
+@pytest.mark.parametrize("edit", [
+    lambda e: e["identity"].update(platform="tpu",
+                                   device_kind="TPU v5 lite"),
+    lambda e: e["identity"].update(device_count=1234),
+    lambda e: e.update(devices=[4321]),
+], ids=["platform", "device_count", "device_assignment"])
+def test_another_machines_entry_is_a_miss_not_an_error(
+        aot_dir, caplog, edit):
+    """A checkout that ran the CPU tests, or a one-chip cell, and then
+    runs on another platform or device count: its entries are not this
+    process's, which is no fault of the store.  They read as a miss,
+    without a WARN, and the fresh compile overwrites them."""
+    x = jnp.arange(4, dtype=jnp.int64)
+    disp = aotstore.wrap("test:place", jax.jit(_oracle))
+    disp(x)
+    (entry_name,) = os.listdir(aot_dir)
+    path = os.path.join(aot_dir, entry_name)
+    with open(path, "rb") as fh:
+        entry = aotstore._decode(fh.read())
+    edit(entry)
+    with open(path, "wb") as fh:
+        fh.write(aotstore._encode(entry))
+
+    disp.reset_memo()
+    aotstore._reset_warnings()
+    with caplog.at_level(logging.WARNING,
+                         logger="teku_tpu.infra.aotstore"):
+        before = aotstore.stats()
+        out = np.asarray(disp(x))
+        moved = aotstore.delta(before)
+    assert moved == {"loads": 0, "misses": 1, "saves": 1, "errors": 0}
+    assert not caplog.records
+    np.testing.assert_array_equal(
+        out, _oracle(np.arange(4, dtype=np.int64)))
+    # the overwritten entry is this process's own again
+    disp.reset_memo()
+    before = aotstore.stats()
+    disp(x)
+    assert aotstore.delta(before)["loads"] == 1
+
+
 def test_store_off_serves_from_jit_without_counting(monkeypatch):
     monkeypatch.setenv(aotstore.ENV_ON, "0")
     assert aotstore.store_dir() is None

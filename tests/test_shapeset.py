@@ -137,15 +137,18 @@ def test_enumerate_programs_mesh_kernels():
     mesh = parallel.make_mesh(2, advertise=False)
     programs = list(shapeset.enumerate_programs(
         max_batch=8, min_bucket=4, mesh=mesh))
-    mesh_progs = [(k, m) for k, _a, m in programs
-                  if m["stage"] == "mesh_kernel"]
-    assert mesh_progs, "mesh config must enumerate the sharded kernel"
+    mesh_progs = {m["stage"]: k for k, _a, m in programs
+                  if m["stage"].startswith("mesh_")}
+    from teku_tpu.ops import verify as V
+    assert set(mesh_progs) == {f"mesh_{n}" for n in V.MESH_STAGES}, \
+        "mesh config must enumerate every sharded program"
     devices = [str(d) for d in mesh.devices.ravel()]
-    for kernel, meta in mesh_progs:
+    for stage, kernel in mesh_progs.items():
         # the name the serving path registers for THIS device set —
         # a healed mesh over different devices must miss, never load
         # an executable bound to the wrong device assignment
-        assert kernel == parallel.kernel_store_name(devices, "dp")
+        assert kernel == parallel.kernel_store_name(
+            devices, "dp", stage.removeprefix("mesh_"))
     assert any(m["stage"] == "gather" for _k, _a, m in programs)
 
 
