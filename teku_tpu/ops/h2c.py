@@ -7,24 +7,31 @@ branch-free on device.  The reference client hashes inside native blst
 (reference: infrastructure/bls/src/main/java/tech/pegasys/teku/bls/impl/
 blst/HashToCurve.java:23 — the DST this module shares via the oracle).
 
-DIVISIONLESS DESIGN.  Field inversion (Fermat, a ~380-iteration scan) is
-the compile-time and runtime hotspot, so the map runs fully projective:
+DIVISIONLESS DESIGN.  A batched field inversion (`limbs.inv_many`) is
+one Fermat exponentiation at width 1, a ~95-digit windowed scan that
+costs a v5e chip ~41 ms however many elements share it (PERF.md §6,
+PR 33), so the served path `hash_to_g2_device` holds none: the only one
+in `stage_h2c` is the caller's `to_affine_g2` on the finished point.
+This is RFC 9380 appendix F.2's straight-line form, the one blst follows:
 
-- SSWU computes x = xn/xd and y = yp/xd^3 without ever dividing (the
-  RFC's non-division form: x1n = -B(tv2+1), x1d = A*tv2, with the
-  exceptional case selected in).  The square root is taken on
-  gval = gx_num * xd^3 — same residue class as gx, so the QR decision
-  and the 4-candidate constant-time sqrt shape are unchanged — and the
-  root IS the projective y: (yp)^2 = gval  <=>  (yp/xd^3)^2 = gx.
-- The 3-isogeny maps numerators/denominators homogeneously
-  (x = XN/XD, y = YN/YD), still division-free.
-- ONE batched inversion (limbs.inv_many — a single Fermat for the whole
-  batch via Montgomery's trick) converts both draws of every lane to
-  affine, where the RFC sgn0 sign is applied.
+- SSWU computes x = xn/xd without dividing (x1n = -B(tv2+1),
+  x1d = A*tv2, with the exceptional case selected in).
+- y' comes out AFFINE from `sqrt_ratio` (F.2.1; q = p^2 = 9 mod 16):
+  with U = the numerator of g(x1) over V = xd^3,
+  U V^7 (U V^15)^((q-9)/16) = (U/V)^((q+7)/16), ONE exponentiation of
+  the plain square root's length, and a candidate c is tested by
+  c^2 V == U.  The RFC's sign rule sgn0(u) == sgn0(y') is applied to
+  that y' directly.
+- The 3-isogeny maps numerators and denominators homogeneously
+  (x = XN/XD, y = YN/YD with YN = y' * y_num, YD = y_den: both have
+  degree 3, so no power of xd is left over).
+- (XN/XD, YN/YD) becomes Jacobian by multiplying through: Z = XD YD,
+  X = XN XD YD^2, Y = YN XD^3 YD^2 (`iso_to_jacobian`), and the two
+  draws are added as Jacobian points.
 
-Square roots use ONE Fq2 exponentiation per draw via the SSWU identity
-gx2 = Z^3 u^6 gx1: candidates for sqrt(gval2) reuse the same power times
-u^3 (Z^3)^((q+7)/16) (q = p^2 ≡ 9 mod 16).
+The two draws share the one exponentiation at double width, and the
+second candidate set needs none: gx2 = Z^3 u^6 gx1, so candidates for
+sqrt(g(x2)) are the same power times u^3 (Z^3)^((q+7)/16).
 
 Cofactor clearing is Budroni-Pintore via the psi endomorphism, matching
 the oracle's production path (crypto/bls/hash_to_curve.py:152-158).
@@ -77,9 +84,62 @@ def fq2_sgn0(a):
     return a0_odd | (a0_zero.astype(jnp.int64) & a1_odd)
 
 
+def _sqrt_ratio_cand(U, V):
+    """(U/V)^((q+7)/16) without dividing, as U V^7 (U V^15)^((q-9)/16)
+    (V^(q-1) = 1): RFC 9380 F.2.1's sqrt_ratio for q = p^2 = 9 mod 16.
+    One exponentiation of SQRT_EXP's length; U, V one-unit, V != 0."""
+    V2 = T.fq2_compress(T.fq2_sqr(V))
+    V4, V3 = (T.fq2_compress(c) for c in T._fq2u(T.fq2_mul(
+        T._fq2s([V2, V2]), T._fq2s([V2, V]))))
+    V8, V7 = (T.fq2_compress(c) for c in T._fq2u(T.fq2_mul(
+        T._fq2s([V4, V4]), T._fq2s([V4, V3]))))
+    V15, UV7 = (T.fq2_compress(c) for c in T._fq2u(T.fq2_mul(
+        T._fq2s([V8, U]), T._fq2s([V7, V7]))))
+    power = T.fq2_pow_static(T.fq2_mul(U, V15), T.SQRT_EXP - 1)
+    return T.fq2_compress(T.fq2_mul(UV7, power))
+
+
+def _roots_of_ratios(cands, targets, V):
+    """For each pair (cand, target): the first of the four root-of-unity
+    multiples {1, R1, R2, R3} * cand whose square times V is target, as
+    (found, root); root is cand where none is.  The multiples, their
+    squares and every match test run as ONE wide call each (lane axis
+    -2: [cand, R1 cand, R2 cand, R3 cand, cand2, R1 cand2, ...])."""
+    k = len(cands)
+    roots = T._fq2s([_c(r, V) for r in ("R1", "R2", "R3")] * k)
+    scaled = T._fq2u(T.fq2_mul(
+        roots, T._fq2s([c for c in cands for _ in range(3)])))
+    tries = [t for j, c in enumerate(cands)
+             for t in [c] + scaled[3 * j:3 * j + 3]]
+    wide_v = (V[0][..., None, :], V[1][..., None, :])
+    d = T.fq2_sub(T.fq2_mul(T.fq2_sqr(T._fq2s(tries)), wide_v),
+                  T._fq2s([t for t in targets for _ in range(4)]))
+    match = jnp.all(fp.canonical(jnp.stack(d, axis=-2)) == 0,
+                    axis=(-2, -1))                       # (..., 4k)
+    out = []
+    for j in range(k):
+        found = jnp.zeros(match.shape[:-1], dtype=bool)
+        y = cands[j]
+        for i in range(4 * j, 4 * j + 4):
+            m = match[..., i] & ~found
+            y = T.fq2_select(m, tries[i], y)
+            found |= m
+        out.append((found, y))
+    return out
+
+
+def sqrt_ratio(U, V):
+    """RFC 9380 F.2.1 sqrt_ratio, batched: (is_square, a root of U/V)
+    for one-unit U and V != 0; the root is garbage where U/V is a
+    non-residue.  The map below runs the same pieces over both of its
+    candidate sets at once; this form is the test surface."""
+    return _roots_of_ratios([_sqrt_ratio_cand(U, V)], [U], V)[0]
+
+
 def map_to_curve_sswu_proj(u):
-    """Batched divisionless simplified SWU: Fq2 u -> (xn, xd, yp) on E'
-    with x = xn/xd and y = yp/xd^3 (sgn0 sign NOT yet applied)."""
+    """Batched divisionless simplified SWU: Fq2 u -> (xn, xd, y) on E'
+    with x = xn/xd and y AFFINE, carrying the RFC's sign
+    (sgn0(y) == sgn0(u))."""
     one = T._bcast2(T.FQ2_ONE_NP, u)
     u2 = T.fq2_sqr(u)
     tv = T.fq2_compress(T.fq2_mul(_c("Z", u), u2))
@@ -95,7 +155,7 @@ def map_to_curve_sswu_proj(u):
     xd = T.fq2_compress(r1[1])
     x1n = T.fq2_compress(x1n)
 
-    # gx1n = x1n^3 + A x1n xd^2 + B xd^3  (numerator of g(x1) over xd^3)
+    # g(x1) = gx1n / xd^3 with gx1n = x1n^3 + A x1n xd^2 + B xd^3
     sq = T._fq2u(T.fq2_sqr(T._fq2s([x1n, xd])))
     x1n2, xd2 = (T.fq2_compress(s) for s in sq)
     r2 = T._fq2u(T.fq2_mul(
@@ -103,52 +163,29 @@ def map_to_curve_sswu_proj(u):
         T._fq2s([x1n, xd, xd2])))
     x1n3, xd3, axd2 = r2
     xd3 = T.fq2_compress(xd3)
-    gx1n = T.fq2_add(T.fq2_add(x1n3, axd2),
-                     T.fq2_mul(_c("B", u), xd3))
-    # the sqrt runs on gval = gx1n * xd^3: same QR class as g(x1), and a
-    # root yp of gval is exactly the projective y (y = yp/xd^3)
-    gval = T.fq2_compress(T.fq2_mul(T.fq2_compress(gx1n), xd3))
+    gx1n = T.fq2_compress(T.fq2_add(T.fq2_add(x1n3, axd2),
+                                    T.fq2_mul(_c("B", u), xd3)))
 
-    cand = T.fq2_pow_static(gval, T.SQRT_EXP)
-    # second candidate set for x2 = tv*x1: gval2 = tv^3 gval = Z^3 u^6 gval
+    cand = _sqrt_ratio_cand(gx1n, xd3)
+    # second candidate set for x2 = tv*x1: g(x2) = tv^3 g(x1) = Z^3 u^6 g(x1)
     u3 = T.fq2_compress(T.fq2_mul(u2, u))
     cand2 = T.fq2_mul(T.fq2_mul(u3, _c("Z3E", u)), cand)
     tv3 = T.fq2_compress(T.fq2_mul(T.fq2_compress(T.fq2_sqr(tv)), tv))
-    gval2 = T.fq2_compress(T.fq2_mul(tv3, gval))
-
-    # the four root-of-unity multiples of both candidates, their squares
-    # and the eight match tests, each as ONE wide call (lane axis -2:
-    # [cand, R1 cand, R2 cand, R3 cand, cand2, R1 cand2, ...])
-    roots = T._fq2s([_c(r, u) for r in ("R1", "R2", "R3")] * 2)
-    scaled = T._fq2u(T.fq2_mul(roots, T._fq2s([cand] * 3 + [cand2] * 3)))
-    tries = [cand] + scaled[:3] + [cand2] + scaled[3:]
-    d = T.fq2_sub(T.fq2_sqr(T._fq2s(tries)),
-                  T._fq2s([gval] * 4 + [gval2] * 4))
-    match = jnp.all(fp.canonical(jnp.stack(d, axis=-2)) == 0,
-                    axis=(-2, -1))                       # (..., 8)
-
-    def first_match(tries, match):
-        found = jnp.zeros(tv2_zero.shape, dtype=bool)
-        y = tries[0]
-        for i, t in enumerate(tries):
-            m = match[..., i] & ~found
-            y = T.fq2_select(m, t, y)
-            found |= m
-        return found, y
-
-    found1, y1 = first_match(tries[:4], match[..., :4])
-    _, y2 = first_match(tries[4:], match[..., 4:])
+    gx2n = T.fq2_compress(T.fq2_mul(tv3, gx1n))
+    (found1, y1), (_, y2) = _roots_of_ratios(
+        [cand, cand2], [gx1n, gx2n], xd3)
 
     xn = T.fq2_select(found1, x1n, T.fq2_compress(T.fq2_mul(tv, x1n)))
-    yp = T.fq2_select(found1, y1, y2)
-    return T.fq2_compress(xn), xd, T.fq2_compress(yp)
+    y = T.fq2_select(found1, y1, y2)
+    y = T.fq2_select(fq2_sgn0(u) != fq2_sgn0(y), T.fq2_neg(y), y)
+    return T.fq2_compress(xn), xd, T.fq2_compress(y)
 
 
-def iso_map_proj(xn, xd, yp):
-    """3-isogeny E' -> E on projective inputs, division-free.
+def iso_map_proj(xn, xd, y):
+    """3-isogeny E' -> E on a projective x, division-free.
 
-    Input x = xn/xd, y = yp/xd^3; output x = XN/XD, y = YN/YD with all
-    four homogeneous in (xn, xd)."""
+    Input x = xn/xd and the affine y; output x = XN/XD, y = YN/YD with
+    XN, XD and YN/y, YD homogeneous in (xn, xd)."""
     sq = T._fq2u(T.fq2_sqr(T._fq2s([xn, xd])))
     xn2, xd2 = (T.fq2_compress(s) for s in sq)
     r = T._fq2u(T.fq2_mul(T._fq2s([xn2, xd2]), T._fq2s([xn, xd])))
@@ -185,34 +222,29 @@ def iso_map_proj(xn, xd, yp):
         pos += len(co)
     x_num, x_den, y_num, y_den = T._fq2u(T.fq2_compress(T._fq2s(sums)))
 
-    XN = x_num                                   # deg 3
-    XD = T.fq2_mul(xd, x_den)                    # deg 2 -> * xd
-    YN = T.fq2_mul(yp, y_num)                    # y factor: yp/xd^3
-    YD = T.fq2_mul(xd3, y_den)                   # matching xd^3
-    return XN, T.fq2_compress(XD), T.fq2_compress(YN), T.fq2_compress(YD)
+    # x_num, y_num, y_den have degree 3 and x_den degree 2: only XD
+    # keeps a factor xd
+    XD, YN = T._fq2u(T.fq2_compress(T.fq2_mul(
+        T._fq2s([xd, y]), T._fq2s([x_den, y_num]))))
+    return x_num, XD, YN, y_den
 
 
-def _proj_to_affine_signed(u, XN, XD, YN, YD):
-    """Batched projective -> affine with RFC sgn0(u) sign fix; ONE
-    inversion of XD*YD per element, batched into a single Fermat
-    exponentiation across the whole batch (limbs.inv_many)."""
-    pinv = T.fq2_inv(T.fq2_compress(T.fq2_mul(XD, YD)))
-    r = T._fq2u(T.fq2_mul(T._fq2s([XN, YN]),
-                          T._fq2s([T.fq2_compress(T.fq2_mul(pinv, YD)),
-                                   T.fq2_compress(T.fq2_mul(pinv, XD))])))
-    x, y = (T.fq2_compress(c) for c in r)
-    flip = fq2_sgn0(u) != fq2_sgn0(y)
-    y = T.fq2_select(flip, T.fq2_neg(y), y)
-    return x, T.fq2_compress(y)
+def iso_to_jacobian(XN, XD, YN, YD):
+    """(XN/XD, YN/YD) as a Jacobian point without dividing: Z = XD YD,
+    X = XN XD YD^2, Y = YN XD^3 YD^2.  Where XD YD = 0 (x' in the
+    isogeny's kernel) that is the point at infinity, as the RFC has it."""
+    Z, xn_yd, yn_xd = (T.fq2_compress(c) for c in T._fq2u(T.fq2_mul(
+        T._fq2s([XD, XN, YN]), T._fq2s([YD, YD, XD]))))
+    X, ZZ = (T.fq2_compress(c) for c in T._fq2u(T.fq2_mul(
+        T._fq2s([xn_yd, Z]), T._fq2s([Z, Z]))))
+    return X, T.fq2_compress(T.fq2_mul(yn_xd, ZZ)), Z
 
 
 def map_to_curve_sswu(u):
-    """Affine SSWU on E' (test/oracle parity surface): projective map +
-    affine conversion + sgn0 sign."""
-    xn, xd, yp = map_to_curve_sswu_proj(u)
-    # y = yp/xd^3: reuse the generic converter with XD=xd, YN=yp, YD=xd^3
-    xd3 = T.fq2_compress(T.fq2_mul(T.fq2_compress(T.fq2_sqr(xd)), xd))
-    return _proj_to_affine_signed(u, xn, xd, yp, xd3)
+    """Affine SSWU on E' (test/oracle parity surface, on no served
+    path): the projective map and one inversion of xd."""
+    xn, xd, y = map_to_curve_sswu_proj(u)
+    return T.fq2_compress(T.fq2_mul(xn, T.fq2_inv(xd))), y
 
 
 # --------------------------------------------------------------------------
@@ -243,37 +275,19 @@ def clear_cofactor(p):
 
 
 def hash_to_g2_device(u0, u1):
-    """Device pipeline: two Fq2 draws -> G2 Jacobian point (in-subgroup).
+    """Device pipeline: two Fq2 draws -> G2 Jacobian point (in-subgroup),
+    with no field inversion.
 
-    Both draws are stacked on a leading axis so the map, the isogeny and
-    the (single, batched) inversion run once at double width.
-
-    The RFC's sgn0 sign applies to the E' point BEFORE the isogeny
-    (y' = yp/xd^3); flipping y' flips the isogeny output, so the affine
-    y' (needed only for its sign) and the affine E coordinates are all
-    recovered from ONE shared inversion of xd^3 * XD * YD."""
+    Both draws are stacked on a leading axis so the map (its one
+    exponentiation), the isogeny and the Jacobian conversion run once at
+    double width; the draws are then added as Jacobian points.  A draw
+    whose x' lies in the isogeny's kernel (XD YD = 0; probability
+    ~2^-380, never seen) is the point at infinity, which is what RFC
+    9380 says and `point_add` selects around."""
     U = T.tree_stack([u0, u1])
-    xn, xd, yp = map_to_curve_sswu_proj(U)
-    XN, XD, YN, YD = iso_map_proj(xn, xd, yp)
-    xd3 = T.fq2_compress(T.fq2_mul(T.fq2_compress(T.fq2_sqr(xd)), xd))
-    xd3_XD = T.fq2_compress(T.fq2_mul(xd3, XD))
-    pinv = T.fq2_inv(T.fq2_compress(T.fq2_mul(xd3_XD, YD)))  # batched
-    r = T._fq2u(T.fq2_mul(
-        T._fq2s([T.fq2_compress(T.fq2_mul(XD, YD)),
-                 T.fq2_compress(T.fq2_mul(xd3, YD)),
-                 xd3_XD]),
-        T._fq2s([pinv, pinv, pinv])))
-    inv_xd3, inv_XD, inv_YD = (T.fq2_compress(c) for c in r)
-    r2 = T._fq2u(T.fq2_mul(T._fq2s([yp, XN, YN]),
-                           T._fq2s([inv_xd3, inv_XD, inv_YD])))
-    y_prime, x, y = (T.fq2_compress(c) for c in r2)
-    flip = fq2_sgn0(U) != fq2_sgn0(y_prime)
-    y = T.fq2_select(flip, T.fq2_neg(y), y)
-    y = T.fq2_compress(y)
-    one = T._bcast2(T.FQ2_ONE_NP, x)
-    (x0, y0, o0), (x1, y1, o1) = T.tree_unstack((x, y, one), 2)
-    r = PT.point_add(PT.G2_KIT, (x0, y0, o0), (x1, y1, o1))
-    return clear_cofactor(r)
+    q = iso_to_jacobian(*iso_map_proj(*map_to_curve_sswu_proj(U)))
+    q0, q1 = T.tree_unstack(q, 2)
+    return clear_cofactor(PT.point_add(PT.G2_KIT, q0, q1))
 
 
 def messages_to_fields(messages, dst: bytes = DST_G2_POP):

@@ -143,7 +143,7 @@ def test_sharded_combine_compiles_with_an_all_gather(topo):
 # (kernel stage, measured sandbox compile seconds on the vpu path) of the
 # staged programs `chip_smoke.py` warms, from PERF.md's table
 _STAGED = {"prepare": 15, "scalars": 30, "group": 13, "miller": 11,
-           "finish": 52, "h2c": 90}
+           "finish": 52, "h2c": 77}
 _STAGE_FNS = {"prepare": V.stage_prepare, "scalars": V.stage_scalars,
               "group": V.stage_group, "miller": V.stage_miller,
               "finish": V.stage_finish, "h2c": V.stage_h2c}
