@@ -165,8 +165,9 @@ class GuardedBls12381(BLS12381):
         # follow device order).  A provider's host half
         # (`prepare_dispatch`: parsing, cache lookups, array packing)
         # runs before the lock is taken, so one worker packs its batch
-        # while the other one's runs on the chip; the provider's host
-        # caches carry their own locks (`LimitedMap`).  A timed-out
+        # while the other one's runs on the chip (one host half at a
+        # time: `_prep_lock` below); the provider's host caches carry
+        # their own locks (`LimitedMap`).  A timed-out
         # dispatch's orphaned thread may still be on the device (e.g.
         # finishing a cold compile): it keeps the lock for as long, a
         # later dispatch preps, then blocks there until the orphan
@@ -178,6 +179,24 @@ class GuardedBls12381(BLS12381):
         # orphans keep the old lock), new dispatches take the new
         # provider immediately and never queue behind a wedged orphan.
         self._serving = (device, threading.Lock())
+        # Host halves take turns.  They are plain Python and small
+        # numpy calls under the interpreter lock, so two at once gain
+        # nothing, and they convoy: each call that lets the
+        # interpreter lock go waits a switch interval to get it back
+        # from the other packer.  Two workers that drained a burst
+        # together packed a 256 x 512 batch in 1.14-1.45 s each on the
+        # chip's host, where one alone takes 0.11 s (PERF.md, PR 36).
+        # The wait for the turn is the `prep_wait` phase; the turn is
+        # given back before `lock_wait` begins, so a worker standing at
+        # the device-entry lock never holds up the other's packing.
+        # Not part of the swapped pair: it guards no device state, and
+        # a reshape leaves it.  A host half that hangs keeps the turn
+        # for as long (a `with` block: it gives it back when it ends,
+        # however it ends); the breaker's deadline bounds wait + prep
+        # + wait + device, so a dispatch behind it is answered by the
+        # oracle and its orphaned thread packs, and runs, once the
+        # turn comes.
+        self._prep_lock = threading.Lock()
 
     @property
     def device(self) -> BLS12381:
@@ -248,13 +267,17 @@ class GuardedBls12381(BLS12381):
         def locked():
             # runs on the breaker's dispatch thread: the hop to it ends
             # with the first mark here.  The host half comes first, off
-            # the lock; what follows until the lock is ours is the wait
-            # behind the other worker's launches and sync
+            # the device-entry lock and in its turn (`prep_wait`: the
+            # other worker is packing); what follows until the lock is
+            # ours is the wait behind the other worker's launches and
+            # sync
             marks = tracing.current_marks()
             prepared = None
             if prepare is not None:
-                marks.mark("host_prep")
-                prepared = prepare(op, *args)
+                marks.mark("prep_wait")
+                with self._prep_lock:
+                    marks.mark("host_prep")
+                    prepared = prepare(op, *args)
                 if prepared.verdict is not None:
                     # known on the host (malformed wire, a cached
                     # key): nothing enters the device

@@ -20,7 +20,12 @@ device time, verdict).  Each record captures:
 - lanes real/padded and rows real/padded: padding waste SPLIT BY
   STAGE BUCKET (the lane bucket the scalars/finish stages pay vs the
   unique-h2c/Miller row bucket the dedup pipeline pays) plus the
-  per-dispatch dedup ratio;
+  per-dispatch dedup ratio; ``keys`` (live public keys) and
+  ``waste.key`` (``real``: live keys, ``padded``: padded lanes x the
+  key bucket): the slots ``stage_prepare``'s masked key sum runs
+  over, mirrored by ``bls_key_slots_filled_total{kmax}`` /
+  ``bls_key_slots_dispatched_total{kmax}`` (the provider's counters;
+  not a series of the padding gauge below);
 - H(m) arena hits/misses and the h2c dispatch bucket actually paid;
 - the resolved mesh plan (device count, per-shard row/lane loads,
   makespan ratio = max shard lane load / mean);
@@ -29,9 +34,11 @@ device time, verdict).  Each record captures:
   ``compile.programs``: one load record per AOT program resolved
   inside that enqueue (``infra/aotstore.py`` ``load_records``);
 - ``phases``: the dispatch's whole life as ``[name, t_mono,
-  seconds]`` in order (thread_hop, host_prep, lock_wait,
+  seconds]`` in order (thread_hop, prep_wait, host_prep, lock_wait,
   device_enqueue, device_sync, return_hop, settle: the marks of
-  ``infra/tracing.py``, tiling first mark to last), ``lock``:
+  ``infra/tracing.py``, tiling first mark to last; ``prep_wait`` is
+  the wait for the guarded provider's turn to pack, ``host_prep``
+  packing alone), ``lock``:
   ``{acquired, released}`` of the guarded provider's device-entry
   lock, and ``parent_seq``: the failed batch a bisect dispatch came
   from (absent with tracing off);

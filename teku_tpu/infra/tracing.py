@@ -3,11 +3,11 @@
 The north-star metric is attestation-gossip p50 verify latency, but an
 end-to-end number cannot say WHERE a slow verify spent its time — the
 asyncio queue, batch assembly, the hop to the dispatch thread, the wait
-at the guarded provider's lock, host-side limb packing, a JAX
-recompile, the device, the hop back, or an oracle fallback.  This
-module is the attribution layer (the reference's analogue is the
-per-stage labelled timers its Besu MetricsSystem hangs off the
-validation pipeline):
+for the turn to pack, host-side limb packing, the wait at the guarded
+provider's lock, a JAX recompile, the device, the hop back, or an
+oracle fallback.  This module is the attribution layer (the
+reference's analogue is the per-stage labelled timers its Besu
+MetricsSystem hangs off the validation pipeline):
 
 - ``span(stage, **labels)`` — a context-manager stopwatch usable from
   asyncio tasks AND worker threads (monotonic ``perf_counter``); on
@@ -67,10 +67,15 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 # its batch to the instant its last future is settled:
 #   thread_hop      service hands over → the dispatch thread runs
 #                   (`to_thread` pool wait, thread start)
+#   prep_wait       blocked on the guarded provider's turn to pack:
+#                   host halves run one at a time (two at once convoy
+#                   on the interpreter lock); absent where the
+#                   provider has no host half
 #   host_prep       wire parse, key lookup, array packing: the
-#                   provider's host half, off the device-entry lock
-#                   (a second, short one under the lock where a key
-#                   had to be validated or the H(m) arena looked up)
+#                   provider's host half, packing alone, off the
+#                   device-entry lock (a second, short one under the
+#                   lock where a key had to be validated or the H(m)
+#                   arena looked up)
 #   lock_wait       blocked on the serving pair's device-entry lock
 #   device_enqueue  the async launches (plus XLA compile or program
 #                   load on a first shape)
@@ -81,13 +86,14 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 # (the parent of thread_hop .. return_hop in the span tree);
 # `oracle_execute` is a guarded call the oracle served for the device.
 STAGES = ("queue_wait", "assembly", "dispatch", "thread_hop",
-          "host_prep", "lock_wait", "device_enqueue", "device_sync",
-          "return_hop", "settle", "oracle_execute", "complete")
+          "prep_wait", "host_prep", "lock_wait", "device_enqueue",
+          "device_sync", "return_hop", "settle", "oracle_execute",
+          "complete")
 
 # phases that begin and end on ONE thread: entered as profiler
 # annotations too (a hop crosses threads and cannot be)
-_ANNOTATED = frozenset(("lock_wait", "host_prep", "device_enqueue",
-                        "device_sync", "settle"))
+_ANNOTATED = frozenset(("prep_wait", "host_prep", "lock_wait",
+                        "device_enqueue", "device_sync", "settle"))
 
 _enabled = True
 

@@ -140,6 +140,24 @@ _M_PREP = GLOBAL_REGISTRY.labeled_counter(
     labelnames=("prep", "reason"))
 
 
+# The key axis: every lane is padded to the batch's key bucket (`kmax`,
+# a power of two: a closed vocabulary, linted in
+# test_metrics_exposition), and the masked tree sum of `stage_prepare`
+# runs over every slot, filled or not.  filled / dispatched is the
+# share of that sum which added a real key; the ledger record carries
+# the same as `waste.key` and `keys`.
+_M_KEY_SLOTS_FILLED = GLOBAL_REGISTRY.labeled_counter(
+    "bls_key_slots_filled_total",
+    "public-key slots of verify dispatches that held a live key, by "
+    "key bucket (keys a lane, padded to a power of two)",
+    labelnames=("kmax",))
+_M_KEY_SLOTS = GLOBAL_REGISTRY.labeled_counter(
+    "bls_key_slots_dispatched_total",
+    "public-key slots verify dispatches put on the device (padded "
+    "lanes x key bucket), by key bucket",
+    labelnames=("kmax",))
+
+
 def _dedup_ratio() -> float:
     # read unique BEFORE lanes (writers inc lanes first): a dispatch
     # landing between the reads skews the ratio high, never negative
@@ -1085,6 +1103,11 @@ class JaxBls12381(BLS12381):
         aot_before = aotstore.stats() if first else None
         _M_H2C_LANES.inc(n)
         _M_H2C_UNIQUE.inc(pack.n_unique)
+        keys = int(np.count_nonzero(pack.pk_present))
+        key_slots = padded * pack.kmax
+        key_bucket = str(pack.kmax)
+        _M_KEY_SLOTS_FILLED.labels(kmax=key_bucket).inc(keys)
+        _M_KEY_SLOTS.labels(kmax=key_bucket).inc(key_slots)
         if mesh_n:
             _M_MESH_DISPATCH.labels(devices=str(mesh_n)).inc()
         # device section: every launch below is async (XLA compiles
@@ -1116,12 +1139,13 @@ class JaxBls12381(BLS12381):
         rec = dispatchledger.open_record(
             trace_ids=[t.trace_id for t in traces],
             shape=shape, mont_path=mont_path, randomized=pack.randomize,
-            lanes=n, kmax=pack.kmax,
+            lanes=n, kmax=pack.kmax, keys=keys,
             unique_messages=pack.n_unique, rows=pack.n_rows,
             group_bucket=pack.g_bucket,
             dedup_ratio=round((n - pack.n_unique) / n, 4),
             waste={"lane": {"real": n, "padded": padded},
-                   "h2c": {"real": pack.n_rows, "padded": pack.u_total}},
+                   "h2c": {"real": pack.n_rows, "padded": pack.u_total},
+                   "key": {"real": keys, "padded": key_slots}},
             h2c=h2c_stats,
             # one scalars stage; the field stays because the
             # benchmark's set-up log reads it (ROADMAP C15)

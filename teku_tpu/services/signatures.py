@@ -408,6 +408,15 @@ class AggregatingSignatureVerificationService:
             f"{name}_batch_count_total", "batches dispatched")
         self._m_tasks = registry.counter(
             f"{name}_task_count_total", "tasks completed")
+        # a task is one triple (`verify`) or several verified together
+        # (`verify_multi`: the three checks of an aggregate-and-proof);
+        # a dispatch's lanes are triples, so load by task undercounts
+        self._m_triples = registry.counter(
+            f"{name}_triple_count_total",
+            "triples of the completed tasks")
+        self._m_multi_tasks = registry.counter(
+            f"{name}_multi_task_count_total",
+            "completed tasks of more than one triple")
         self._m_batch_size = registry.histogram(
             f"{name}_batch_size", "signatures per dispatched batch",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512))
@@ -1131,5 +1140,8 @@ class AggregatingSignatureVerificationService:
 
     def _complete(self, task: _Task, result: bool) -> None:
         self._m_tasks.inc()
+        self._m_triples.inc(len(task.triples))
+        if len(task.triples) > 1:
+            self._m_multi_tasks.inc()
         self._drop_pending(task)
         task.settle(result)

@@ -25,7 +25,7 @@ from teku_tpu import parallel
 from teku_tpu.crypto import bls
 from teku_tpu.crypto.bls import keygen
 from teku_tpu.crypto.bls.pure_impl import PureBls12381
-from teku_tpu.infra import dispatchledger, doctor, tracing
+from teku_tpu.infra import aotstore, dispatchledger, doctor, tracing
 from teku_tpu.infra.flightrecorder import FlightRecorder
 from teku_tpu.infra.metrics import MetricsRegistry
 from teku_tpu.ops import provider as PV
@@ -361,6 +361,11 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
     fields to all-hits/zero-bucket."""
     pure, sks, pks = keys
     triples = _grid_batch(pure, sks, pks)
+    # the staged programs are the process's, not the provider's: an
+    # earlier file of the same xdist worker may have resolved some of
+    # them at these shapes, and the first dispatch here would list the
+    # rest alone.  Forgetting them makes this dispatch first
+    aotstore.reset_memos()
     before = (PV._M_H2C_LANES.value,
               dispatchledger.LEDGER._padded["lane"],
               PV._M_H2C_UNIQUE.value, single_impl.h2c_dispatch_count,
@@ -435,6 +440,57 @@ def test_the_fields_the_benchmark_reads_stay(single_impl, keys):
         assert shapeset.batch_plan(
             groups, min_bucket=256)["msm_path"] == "ladder"
 
+
+
+def _key_slot_counts():
+    return {fam: {key[0]: child.value
+                  for key, child in getattr(PV, fam)._items()}
+            for fam in ("_M_KEY_SLOTS_FILLED", "_M_KEY_SLOTS")}
+
+
+def test_key_axis_of_a_mixed_dispatch(single_impl, keys, monkeypatch):
+    """1-key and many-key lanes in one dispatch at key bucket 8: the
+    record's `keys` and `waste.key.real` are the live keys,
+    `waste.key.padded` the padded lanes x 8, and the two key-slot
+    counters move by the same under `kmax="8"` and under no other
+    label.  The packer and `stage_h2c` are the real ones; the staged
+    kernel is stood in for (a 16 x 8 compile of its own is not this
+    test's to pay), so the verdict is the stand-in's."""
+    import numpy as np
+    pure, sks, pks = keys
+    seen = {}
+
+    def kernel(pk_xs, pk_ys, pk_present, *rest):
+        seen["present"] = np.asarray(pk_present)
+        lanes = seen["present"].shape[0]
+        return np.bool_(True), np.ones(lanes, dtype=bool)
+
+    monkeypatch.setattr(PV.V, "verify_staged_grouped", kernel)
+    counts = [1, 5, 8, 1, 3, 1, 7, 1, 2, 1]         # keys a lane
+    triples = []
+    for lane, k in enumerate(counts):
+        msg = b"key-axis-%d" % lane
+        triples.append((pks[:k], msg, pure.sign(sks[0], msg)))
+    before = _key_slot_counts()
+    assert single_impl.batch_verify(triples)
+    rec = _last_record()
+    live = sum(counts)
+    assert rec["kmax"] == 8 and rec["shape"] == "16x8"
+    assert seen["present"].shape == (16, 8)
+    assert rec["keys"] == live == int(seen["present"].sum())
+    assert rec["waste"]["key"] == {"real": live, "padded": 16 * 8}
+    assert rec["waste"]["lane"] == {"real": 10, "padded": 16}
+    after = _key_slot_counts()
+    moved = {fam: {k: v - before[fam].get(k, 0)
+                   for k, v in after[fam].items()
+                   if v != before[fam].get(k, 0)}
+             for fam in after}
+    assert moved == {"_M_KEY_SLOTS_FILLED": {"8": live},
+                     "_M_KEY_SLOTS": {"8": 16 * 8}}
+    # not a series of the padding gauge: its stages stay {lane, h2c}
+    assert "key" not in dispatchledger.WASTE_STAGES
+    assert set(dispatchledger.summarize([rec])["padding_waste"]) \
+        == set(dispatchledger.WASTE_STAGES)
 
 
 def test_tampered_batch_records_false_verdict(single_impl, keys):

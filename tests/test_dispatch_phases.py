@@ -23,14 +23,14 @@ import pytest
 from teku_tpu.crypto import bls
 from teku_tpu.crypto.bls import loader
 from teku_tpu.crypto.bls.spi import PreparedDispatch
-from teku_tpu.infra import dispatchledger, timeline, tracing
+from teku_tpu.infra import clock, dispatchledger, timeline, tracing
 from teku_tpu.infra.metrics import GLOBAL_REGISTRY, MetricsRegistry
 from teku_tpu.infra.supervisor import CircuitBreaker
 from teku_tpu.services.signatures import (
     AggregatingSignatureVerificationService)
 
-SERVED = ["thread_hop", "host_prep", "lock_wait", "device_enqueue",
-          "device_sync", "return_hop", "settle"]
+SERVED = ["thread_hop", "prep_wait", "host_prep", "lock_wait",
+          "device_enqueue", "device_sync", "return_hop", "settle"]
 PK = b"\xa0" + bytes(47)
 
 
@@ -130,12 +130,15 @@ def _serve(impl, messages, traced=True, **service_kw):
             with tracing.attach([tr]):
                 futs.append(svc.verify([PK], msg, b"sig"))
         verdicts = await asyncio.gather(*futs)
+        # a task's trace ends with its verdict: the service's stop and
+        # the loop's teardown are not part of it (they read as a hole
+        # after `settle`, 5 ms and more on a loaded machine)
+        for tr in traces:
+            tracing.finish(tr)
         await svc.stop()
         return verdicts
 
     verdicts = asyncio.run(main())
-    for tr in traces:
-        tracing.finish(tr)
     records = [r for r in dispatchledger.LEDGER.snapshot()
                if r["seq"] > seq0]
     return verdicts, traces, records
@@ -212,9 +215,9 @@ def test_span_tree_names_the_whole_dispatch():
 
 
 def test_second_worker_waits_out_the_first_ones_hold():
-    """Two workers, two batches at once: both pack side by side, then
-    the second one's `lock_wait` runs from its host half's end to the
-    first one's release of the lock."""
+    """Two workers, two batches at once: they pack one after the
+    other (`prep_wait`), then the second one's `lock_wait` runs from
+    its host half's end to the first one's release of the lock."""
     hold = 0.12
     verdicts, _t, records = _serve(
         _guarded(PhasedDevice(hold)), [b"a0", b"a1", b"b0", b"b1"],
@@ -240,6 +243,80 @@ def test_second_worker_waits_out_the_first_ones_hold():
     # nobody held the lock between the two for longer than a hand-over
     assert 0 <= second["lock"]["acquired"] - first["lock"]["released"] \
         < 0.03
+
+
+class RecordingDevice(PhasedDevice):
+    """Notes when each host half began and ended, on the marks'
+    clock."""
+
+    def __init__(self, hold_s):
+        super().__init__(hold_s)
+        self.preps = []
+
+    def prepare_dispatch(self, op, *args):
+        t_in = clock.mono()
+        prepared = super().prepare_dispatch(op, *args)
+        self.preps.append((t_in, clock.mono()))
+        return prepared
+
+
+def test_two_workers_pack_in_turn_and_say_how_long_they_waited():
+    """Two workers drain a burst together: the host halves never
+    overlap, the second one's `prep_wait` is the first one's packing,
+    its own `host_prep` is its packing alone, and both records still
+    tile first mark to last."""
+    hold = 0.4                       # a host half sleeps 0.2 s
+    device = RecordingDevice(hold)
+    verdicts, _t, records = _serve(
+        _guarded(device), [b"a0", b"a1", b"b0", b"b1"],
+        num_workers=2, max_batch_size=2)
+    assert verdicts == [True] * 4 and len(records) == 2
+    (in0, out0), (in1, out1) = sorted(device.preps)
+    assert out0 <= in1                      # one at a time
+    by_name = []
+    for rec in records:
+        assert [n for n, _t, _s in rec["phases"]] == SERVED
+        _assert_tiles(rec["phases"])
+        by_name.append({n: (t, s) for n, t, s in rec["phases"]})
+    first, second = sorted(by_name, key=lambda ph: ph["host_prep"][0])
+    # the first found the turn free; the second waited out the first's
+    # packing (both began within the hand-over of each other)
+    assert first["prep_wait"][1] < 0.05
+    assert second["prep_wait"][1] == pytest.approx(
+        sum(first["host_prep"]) - second["prep_wait"][0], abs=0.01)
+    assert second["prep_wait"][1] > hold / 2 - 0.1
+    # `host_prep` is packing alone: the sleep, not the sleep twice over
+    for phases in (first, second):
+        assert hold / 2 <= phases["host_prep"][1] < hold / 2 + 0.1
+    # the wait for the chip begins only after the turn is given back
+    assert sum(second["host_prep"]) <= second["lock_wait"][0] + 2.5e-6
+
+
+def test_span_tree_holds_prep_wait_and_no_new_hole():
+    """A task served behind another worker's packing: its span tree
+    names the wait (`prep_wait`, a child of `dispatch`, of the length
+    the ledger's phase has), and what no span covers stays what it
+    was: seams, not the wait."""
+    hold = 0.2
+    _v, traces, records = _serve(
+        _guarded(PhasedDevice(hold)), [b"a0", b"a1", b"b0", b"b1"],
+        num_workers=2, max_batch_size=2)
+    waited = max(records, key=lambda r: dict(
+        (n, s) for n, _t, s in r["phases"])["prep_wait"])
+    wait_s = dict((n, s) for n, _t, s in waited["phases"])["prep_wait"]
+    assert wait_s > hold / 2 - 0.08
+    trace = next(t for t in traces if t.trace_id in waited["trace_ids"])
+    tree = timeline.span_tree(trace.to_dict())
+    dispatch = next(c for c in tree["children"]
+                    if c["phase"] == "dispatch")
+    named = [c for c in dispatch["children"]
+             if c["phase"] != "unattributed"]
+    assert [c["phase"] for c in named] == SERVED[:-1]
+    node = next(c for c in named if c["phase"] == "prep_wait")
+    assert node["dur_ms"] == pytest.approx(wait_s * 1e3, abs=0.01)
+    holes = [c["dur_ms"] for n in (tree, dispatch)
+             for c in n["children"] if c["phase"] == "unattributed"]
+    assert sum(holes) < 5.0
 
 
 def test_lock_is_taken_after_host_prep_never_over_it():
@@ -278,8 +355,8 @@ def test_profiler_annotations_cover_the_single_thread_phases(monkeypatch):
 
     monkeypatch.setattr(tracing, "_annotation", annotation)
     _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
-    want = ["host_prep", "lock_wait", "device_enqueue", "device_sync",
-            "settle"]
+    want = ["prep_wait", "host_prep", "lock_wait", "device_enqueue",
+            "device_sync", "settle"]
     assert entered == want and exited == want
 
 
@@ -454,6 +531,26 @@ def test_oracle_fallback_is_a_phase_of_the_dispatch():
 # --------------------------------------------------------------------------
 # (e) vocabulary
 # --------------------------------------------------------------------------
+
+def test_prep_wait_is_a_stage_of_the_histogram_and_an_annotation():
+    """The phase is in the closed stage vocabulary, in the
+    single-thread set (it begins and ends on the breaker's dispatch
+    thread) and, once a dispatch was served, a label of
+    `verify_stage_duration_seconds`; every label of that family is a
+    declared stage."""
+    assert "prep_wait" in tracing.STAGES
+    assert tracing.STAGES.index("thread_hop") \
+        < tracing.STAGES.index("prep_wait") \
+        < tracing.STAGES.index("host_prep") \
+        < tracing.STAGES.index("lock_wait")
+    assert "prep_wait" in tracing._ANNOTATED
+    _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
+    hist = GLOBAL_REGISTRY.labeled_histogram(
+        "verify_stage_duration_seconds", labelnames=("stage",))
+    labels = {key[0] for key, _child in hist._items()}
+    assert "prep_wait" in labels
+    assert labels <= set(tracing.STAGES), labels - set(tracing.STAGES)
+
 
 def test_new_stage_names_are_declared_and_no_timeline_phase_is_new():
     assert set(SERVED) | {"oracle_execute", "dispatch", "complete",

@@ -255,10 +255,10 @@ def test_missed_key_is_validated_under_the_lock(jax_impl):
                                                      "pk_miss")
         assert _prep_count("under_lock", "pk_miss") == before + 1
         names = [n for n, _t, _s in rec["phases"]]
-        assert names[:5] == ["thread_hop", "host_prep", "lock_wait",
-                             "host_prep", "device_enqueue"]
+        assert names[:6] == ["thread_hop", "prep_wait", "host_prep",
+                             "lock_wait", "host_prep", "device_enqueue"]
         # the second `host_prep` (the validation) lies under the lock
-        t_validate = rec["phases"][3][1]
+        t_validate = rec["phases"][4][1]
         assert rec["lock"]["acquired"] <= t_validate + 2.5e-6
         assert jax_impl._pk_cache.get(fresh)[0] == "ok"
         # the same batch again: every key cached, all prep off the lock
@@ -268,8 +268,9 @@ def test_missed_key_is_validated_under_the_lock(jax_impl):
         assert ok is True and rec["prep"] == "outside_lock"
         assert "prep_reason" not in rec
         assert _prep_count("outside_lock", "none") == before + 1
-        assert [n for n, _t, _s in rec["phases"]][:4] == [
-            "thread_hop", "host_prep", "lock_wait", "device_enqueue"]
+        assert [n for n, _t, _s in rec["phases"]][:5] == [
+            "thread_hop", "prep_wait", "host_prep", "lock_wait",
+            "device_enqueue"]
         # a forged lane behind a fresh key is false, not an error
         sk2 = keygen(b"\x78" * 32)
         fresh2 = PureBls12381().secret_key_to_public_key(sk2)

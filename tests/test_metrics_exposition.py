@@ -319,6 +319,52 @@ def test_dispatch_prep_family_label_contract():
         assert isinstance(metrics[fam], Counter), fam
 
 
+def test_key_slot_family_label_contract():
+    """The key axis's two counters carry exactly one `kmax` label from
+    the CLOSED power-of-two vocabulary `shapeset.kmax_bucket` emits
+    (keys a lane, padded; a committee or the sync committee is 512, a
+    block's largest aggregate at most 2048), and a dispatch moves the
+    filled one by no more than the dispatched one."""
+    import teku_tpu.ops.provider  # noqa: F401 - registers families
+    from teku_tpu.infra.metrics import GLOBAL_REGISTRY
+    from teku_tpu.ops import shapeset
+
+    metrics = GLOBAL_REGISTRY.metrics()
+    pow2_vocab = {str(1 << i) for i in range(0, 12)}    # 1..2048
+    assert {str(shapeset.kmax_bucket(k))
+            for k in (1, 2, 3, 400, 488, 512, 2048)} <= pow2_vocab
+    filled = metrics["bls_key_slots_filled_total"]
+    slots = metrics["bls_key_slots_dispatched_total"]
+    for fam in (filled, slots):
+        assert isinstance(fam, LabeledCounter)
+        assert tuple(fam.labelnames) == ("kmax",)
+        for key, _child in fam._items():
+            assert set(key) <= pow2_vocab, key
+    dispatched = {key: child.value for key, child in slots._items()}
+    for key, child in filled._items():
+        assert 0 < child.value <= dispatched[key]
+
+
+def test_service_task_and_triple_families():
+    """The batching service counts tasks, their triples and the tasks
+    of several triples apart, name-prefixed like its other families
+    and present from scrape 1 (triples / tasks is the lanes a task
+    takes; a dashboard divides two series that both exist)."""
+    from teku_tpu.services.signatures import (
+        AggregatingSignatureVerificationService)
+    reg = MetricsRegistry()
+    AggregatingSignatureVerificationService(registry=reg, name="lint_svc")
+    metrics = reg.metrics()
+    for fam in ("lint_svc_task_count_total",
+                "lint_svc_triple_count_total",
+                "lint_svc_multi_task_count_total"):
+        assert isinstance(metrics[fam], Counter), fam
+        assert metrics[fam].value == 0
+    fams = parse_exposition(reg.expose())
+    assert {"lint_svc_triple_count_total",
+            "lint_svc_multi_task_count_total"} <= set(fams)
+
+
 def test_mesh_family_label_contract():
     """The PR-10 mesh families must not drift: the sharded-dispatch
     counter carries exactly one `devices` label whose values come from

@@ -1,6 +1,9 @@
 """The guarded provider's device-entry lock covers only what enters the
 device: a provider's host half (`prepare_dispatch`) runs BEFORE the lock
 is taken, so one worker packs its batch while the other one's runs.
+Host halves take turns (`prep_wait`: two at once convoy on the
+interpreter lock), and the turn is given back before the wait for the
+device begins.
 
 Deterministic: the fake provider's halves block on events, no sleeps.
 The real provider's halves are pinned in `tests/test_jax_provider.py`
@@ -97,21 +100,21 @@ def _call(guarded, tag, out):
     return thread
 
 
-class _WaitSeen:
-    """`tracing.DispatchMarks.mark`, announcing each `lock_wait`."""
+class _MarkSeen:
+    """`tracing.DispatchMarks.mark`, announcing each mark of `name`
+    made once `armed` is set."""
 
-    def __init__(self, monkeypatch):
+    def __init__(self, monkeypatch, name):
         self.seen = threading.Event()
+        self.armed = threading.Event()
         real = tracing.DispatchMarks.mark
-        seen = self.seen
 
-        def mark(marks, name):
-            now = real(marks, name)
-            if name == "lock_wait" and seen.armed:
-                seen.set()
+        def mark(marks, phase):
+            now = real(marks, phase)
+            if phase == name and self.armed.is_set():
+                self.seen.set()
             return now
 
-        seen.armed = False
         monkeypatch.setattr(tracing.DispatchMarks, "mark", mark)
 
 
@@ -120,14 +123,14 @@ def test_second_dispatch_preps_while_the_first_holds_the_lock(monkeypatch):
     device.release[b"first"] = threading.Event()
     guarded = _guarded(device)
     _device, lock = guarded._serving
-    waiting = _WaitSeen(monkeypatch)
+    waiting = _MarkSeen(monkeypatch, "lock_wait")
     out = {}
     first = _call(guarded, b"first", out)
     assert device.in_device.wait(WAIT_S)
     assert lock.locked()
     # the first is on the device, under the lock: the second one's host
     # half runs to its end and its wait for the lock begins
-    waiting.seen.armed = True
+    waiting.armed.set()
     second = _call(guarded, b"second", out)
     assert waiting.seen.wait(WAIT_S)
     assert lock.locked()
@@ -144,13 +147,264 @@ def test_second_dispatch_preps_while_the_first_holds_the_lock(monkeypatch):
     for tag in (b"first", b"second"):
         marks = out[tag][1]
         names = [name for name, _t0, _secs in marks.phases]
-        assert names[:3] == ["thread_hop", "host_prep", "lock_wait"]
-        prep_end = marks.phases[1][1] + marks.phases[1][2]
+        assert names[:4] == ["thread_hop", "prep_wait", "host_prep",
+                             "lock_wait"]
+        prep_end = marks.phases[2][1] + marks.phases[2][2]
         assert prep_end <= marks.lock["acquired"] + 2.5e-6
     # the second one waited from its prep's end to the first's release
     one, two = out[b"first"][1], out[b"second"][1]
-    assert one.lock["acquired"] < two.phases[2][1] < one.lock["released"]
+    assert one.lock["acquired"] < two.phases[3][1] < one.lock["released"]
     assert two.lock["acquired"] >= one.lock["released"]
+
+
+def _gated_prepare(device, tag, entered, gate):
+    """`device.prepare_dispatch`, standing inside the host half of
+    the batch `tag` until the test opens `gate`."""
+    real_prepare = device.prepare_dispatch
+
+    def prepare(op, triples):
+        prepared = real_prepare(op, triples)
+        if prepared.tag == tag:
+            entered.set()
+            assert gate.wait(WAIT_S)
+        return prepared
+
+    device.prepare_dispatch = prepare
+
+
+def _assert_tiles(phases):
+    for (_n0, t0, secs), (_n1, t1, _s1) in zip(phases, phases[1:]):
+        assert t0 + secs == pytest.approx(t1, abs=2.5e-6)
+    assert all(secs >= 0 for _n, _t, secs in phases)
+
+
+def test_host_halves_take_turns(monkeypatch):
+    """Two dispatches that arrive together pack one after the other:
+    the second's host half starts when the first's has returned (two
+    at once convoy on the interpreter lock), its wait for the turn is
+    `prep_wait` and not `host_prep`, and neither waits for the
+    device-entry lock to pack."""
+    device = TwoHalves()
+    in_prep, gate = threading.Event(), threading.Event()
+    _gated_prepare(device, b"first", in_prep, gate)
+    guarded = _guarded(device)
+    _device, lock = guarded._serving
+    waiting = _MarkSeen(monkeypatch, "prep_wait")
+    out = {}
+    first = _call(guarded, b"first", out)
+    assert in_prep.wait(WAIT_S)
+    waiting.armed.set()
+    second = _call(guarded, b"second", out)
+    assert waiting.seen.wait(WAIT_S)
+    # the first still packs: the second stands at the packers' turn,
+    # and nobody holds the device's lock
+    assert guarded._prep_lock.locked() and not lock.locked()
+    assert device.events == [("prep", b"first")]
+    gate.set()
+    first.join(WAIT_S)
+    second.join(WAIT_S)
+    assert not first.is_alive() and not second.is_alive()
+    assert [e for e in device.events if e[0] == "prep"] \
+        == [("prep", b"first"), ("prep", b"second")]
+    assert out[b"first"][0] is True and out[b"second"][0] is True
+    assert not guarded._prep_lock.locked() and not lock.locked()
+    one, two = out[b"first"][1].phases, out[b"second"][1].phases
+    for phases in (one, two):
+        assert [name for name, _t0, _s in phases][:4] == [
+            "thread_hop", "prep_wait", "host_prep", "lock_wait"]
+        _assert_tiles(phases)
+    # the second's wait ended, and its own packing began, when the
+    # first's packing had ended: the host halves never overlap
+    first_prep_end = one[2][1] + one[2][2]
+    assert two[2][1] >= first_prep_end - 2.5e-6
+    # and it began while the first was inside its host half
+    assert one[2][1] <= two[1][1] + 2.5e-6
+    assert two[1][1] + two[1][2] == pytest.approx(two[2][1], abs=2.5e-6)
+
+
+def test_many_dispatches_at_once_never_share_a_host_half():
+    """More dispatch threads than cores, the interpreter switching
+    every 10 us: at no instant are two host halves inside
+    `prepare_dispatch`, nor two device halves under the lock, and
+    every dispatch is served by the device."""
+    import os
+    import sys
+
+    class Counting(TwoHalves):
+        def __init__(self):
+            super().__init__()
+            self.inside = {"prep": 0, "launch": 0}
+            self.worst = {"prep": 0, "launch": 0}
+
+        def _enter(self, half):
+            with self._order:
+                self.inside[half] += 1
+                self.worst[half] = max(self.worst[half],
+                                       self.inside[half])
+
+        def _leave(self, half):
+            with self._order:
+                self.inside[half] -= 1
+
+        def prepare_dispatch(self, op, triples):
+            self._enter("prep")
+            try:
+                sum(range(200))           # lets the interpreter switch
+                return _Batch(triples[0][1])
+            finally:
+                self._leave("prep")
+
+        def launch_dispatch(self, prepared):
+            self._enter("launch")
+            try:
+                sum(range(200))
+                return ResolvedHandle(True)
+            finally:
+                self._leave("launch")
+
+    device = Counting()
+    guarded = _guarded(device)
+    threads_n, each = min(32, 2 * (os.cpu_count() or 4)), 25
+    verdicts = []
+
+    def run(i):
+        for j in range(each):
+            verdicts.append(guarded.batch_verify(
+                [([b"pk"], b"t%d-%d" % (i, j), b"sig")]))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(i,), daemon=True)
+                   for i in range(threads_n)]
+        for thread in threads:
+            thread.start()
+        for thread in threads:
+            thread.join(6 * WAIT_S)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(thread.is_alive() for thread in threads)
+    assert verdicts == [True] * (threads_n * each)
+    assert guarded.oracle.served == []
+    assert device.worst == {"prep": 1, "launch": 1}
+    assert not guarded._prep_lock.locked()
+
+
+def test_a_worker_waiting_for_the_device_does_not_hold_the_turn(
+        monkeypatch):
+    """The turn is given back BEFORE `lock_wait` begins: with the
+    device-entry lock held by somebody else, one dispatch stands in
+    `lock_wait` and a second one's host half still runs to its end."""
+    device = TwoHalves()
+    guarded = _guarded(device)
+    _device, lock = guarded._serving
+    waiting = _MarkSeen(monkeypatch, "lock_wait")
+    out = {}
+    assert lock.acquire(timeout=WAIT_S)       # a third party's hold
+    try:
+        waiting.armed.set()
+        first = _call(guarded, b"first", out)
+        assert waiting.seen.wait(WAIT_S)
+        assert device.events == [("prep", b"first")]
+        assert not guarded._prep_lock.locked()
+        waiting.seen.clear()
+        second = _call(guarded, b"second", out)
+        assert waiting.seen.wait(WAIT_S)
+        # both packed, neither launched: the lock is still the third
+        # party's
+        assert device.events == [("prep", b"first"), ("prep", b"second")]
+        assert not guarded._prep_lock.locked()
+    finally:
+        lock.release()
+    first.join(WAIT_S)
+    second.join(WAIT_S)
+    assert not first.is_alive() and not second.is_alive()
+    assert out[b"first"][0] is True and out[b"second"][0] is True
+    assert sorted(e for e in device.events if e[0] == "done") \
+        == [("done", b"first"), ("done", b"second")]
+    for tag in (b"first", b"second"):
+        _assert_tiles(out[tag][1].phases)
+
+
+def test_a_swap_leaves_the_turn_where_it_is(monkeypatch):
+    """A reshape swaps the (provider, device-entry lock) pair and not
+    the packers' turn: it guards no device state.  A dispatch that
+    took the new pair still waits out the old provider's host half."""
+    old, new = TwoHalves(), TwoHalves()
+    guarded = _guarded(old)
+    turn = guarded._prep_lock
+    _old, old_lock = guarded._serving
+    in_prep, gate = threading.Event(), threading.Event()
+    _gated_prepare(old, b"on-old", in_prep, gate)
+    waiting = _MarkSeen(monkeypatch, "prep_wait")
+    out = {}
+    first = _call(guarded, b"on-old", out)
+    assert in_prep.wait(WAIT_S)
+    guarded.swap_device(new)
+    _new, new_lock = guarded._serving
+    assert guarded._prep_lock is turn and new_lock is not old_lock
+    waiting.armed.set()
+    second = _call(guarded, b"on-new", out)
+    assert waiting.seen.wait(WAIT_S)
+    second.join(0.05)
+    assert second.is_alive() and new.events == []   # waits for the turn
+    gate.set()
+    first.join(WAIT_S)
+    second.join(WAIT_S)
+    assert not first.is_alive() and not second.is_alive()
+    assert out[b"on-old"][0] is True and out[b"on-new"][0] is True
+    assert [e[0] for e in old.events] == ["prep", "launch", "done"]
+    assert [e[0] for e in new.events] == ["prep", "launch", "done"]
+    assert guarded._prep_lock is turn and not turn.locked()
+    assert not old_lock.locked() and not new_lock.locked()
+    waited = dict((n, s) for n, _t, s in out[b"on-new"][1].phases)
+    assert waited["prep_wait"] >= 0.04
+
+
+def test_a_hung_host_half_gives_the_turn_back_when_it_ends():
+    """A host half that outlasts the breaker's deadline keeps the
+    turn; the oracle answers its dispatch and the one that waited out
+    the deadline behind it (`prep_wait` is under the deadline as
+    `lock_wait` is).  When the hung half ends the turn is free, the
+    orphans run, and the device serves the next dispatch."""
+    device = TwoHalves()
+    in_prep, gate = threading.Event(), threading.Event()
+    _gated_prepare(device, b"hung", in_prep, gate)
+    guarded = _guarded(device, deadline_s=0.2)
+    _device, lock = guarded._serving
+    timeouts = guarded.breaker._m_timeouts
+    assert guarded.batch_verify([([b"pk"], b"hung", b"sig")]) is True
+    assert guarded.oracle.served == [b"hung"] and timeouts.value == 1
+    assert guarded._prep_lock.locked() and not lock.locked()
+    with tracing.dispatch_marks("thread_hop") as marks:
+        assert guarded.batch_verify([([b"pk"], b"behind", b"sig")]) is True
+    assert guarded.oracle.served == [b"hung", b"behind"]
+    assert timeouts.value == 2
+    # it never packed: it stood in `prep_wait` until the deadline
+    assert ("prep", b"behind") not in device.events
+    names = [name for name, _t0, _s in marks.phases]
+    assert names[:2] == ["thread_hop", "prep_wait"]
+    assert "host_prep" not in names and "lock_wait" not in names
+    assert dict((n, s) for n, _t, s in marks.phases)["prep_wait"] >= 0.15
+    assert guarded.breaker.state == CircuitBreaker.CLOSED
+    gate.set()
+    tick = threading.Event()
+    for _ in range(int(WAIT_S / 0.005)):
+        if ("done", b"behind") in device.events \
+                and ("done", b"hung") in device.events:
+            break
+        tick.wait(0.005)
+    assert ("done", b"hung") in device.events
+    assert ("done", b"behind") in device.events
+    for _ in range(int(WAIT_S / 0.005)):
+        if not (guarded._prep_lock.locked() or lock.locked()):
+            break
+        tick.wait(0.005)
+    assert not guarded._prep_lock.locked() and not lock.locked()
+    assert guarded.batch_verify([([b"pk"], b"after", b"sig")]) is True
+    assert guarded.oracle.served == [b"hung", b"behind"]
+    assert device.events[-3:] == [("prep", b"after"), ("launch", b"after"),
+                                  ("done", b"after")]
 
 
 def test_host_verdict_never_waits_at_the_lock():
@@ -229,25 +483,35 @@ def test_a_swap_mid_prep_keeps_the_batch_on_its_own_provider():
     assert [e[0] for e in new.events] == ["prep", "launch", "done"]
 
 
-def test_a_provider_without_halves_runs_whole_under_the_lock():
+@pytest.mark.parametrize("kind", ["no_halves", "wrapped_verb"])
+def test_a_provider_without_halves_runs_whole_under_the_lock(kind):
     """The oracle family and the models have no host half to run ahead:
-    their verb is the device half."""
+    their verb is the device half.  So is a verb replaced on the
+    instance (a fault harness: the halves would go around it).  They
+    take no turn: neither `prep_wait` nor `host_prep` is marked, and
+    the packers' lock is never touched."""
     held = []
 
-    class Whole:
-        name = "whole"
+    def whole(triples):
+        held.append((guarded._prep_lock.locked(), lock.locked()))
+        return True
 
-        def batch_verify(self, triples):
-            held.append(lock.locked())
-            return True
-
-    guarded = _guarded(Whole())
+    if kind == "no_halves":
+        class Whole:
+            name = "whole"
+            batch_verify = staticmethod(whole)
+        device = Whole()
+    else:
+        device = TwoHalves()
+        device.batch_verify = whole
+    guarded = _guarded(device)
     _device, lock = guarded._serving
     with tracing.dispatch_marks("thread_hop") as marks:
         assert guarded.batch_verify([([b"pk"], b"m", b"sig")]) is True
-    assert held == [True]
-    assert [name for name, _t0, _s in marks.phases] == ["thread_hop",
-                                                         "lock_wait"]
+    assert held == [(False, True)]
+    names = [name for name, _t0, _s in marks.phases]
+    assert names == ["thread_hop", "lock_wait"]
+    assert getattr(device, "events", []) == []
 
 
 # --------------------------------------------------------------------------
@@ -287,13 +551,14 @@ def test_numpy_bit_expansion_equals_the_device_one(vals):
 # the benchmark's reader of the `prep` field
 # --------------------------------------------------------------------------
 
-def _reader():
+def _reader(name="guard.prep_outside_share"):
     import importlib.util
     import os
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks", "layer_metrics",
-        "guard.prep_outside_share.py")
-    spec = importlib.util.spec_from_file_location("prep_share", path)
+        name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module.read
@@ -311,3 +576,32 @@ def _reader():
 ])
 def test_prep_outside_share_reads_the_ledgers_prep_field(ledger, share):
     assert _reader()({"window_ledger": ledger}) == share
+
+
+def _dispatch(wait_s=None, prep_s=0.11):
+    """A ledger record's `phases` as the guard marks them; without
+    `wait_s`, as the parent of the PR that brought `prep_wait` did."""
+    phases = [["thread_hop", 10.0, 0.002]]
+    t = 10.002
+    if wait_s is not None:
+        phases.append(["prep_wait", t, wait_s])
+        t += wait_s
+    phases += [["host_prep", t, prep_s], ["lock_wait", t + prep_s, 0.5]]
+    return {"lanes": 252, "phases": phases}
+
+
+@pytest.mark.parametrize("ledger,median_ms", [
+    # a burst's pair: the second waits out the first's packing
+    ([_dispatch(0.0), _dispatch(0.11)], 55.0),
+    ([_dispatch(0.0), _dispatch(0.11), _dispatch(0.0004)], 0.4),
+    # records without the phase do not count: a provider without a
+    # host half, a program from before the phase, one without phases
+    ([_dispatch(0.002), _dispatch(), {"lanes": 1}], 2.0),
+    ([_dispatch(), _dispatch(prep_s=1.136)], None),
+    ([{"lanes": 250}], None),
+    ([], None),
+])
+def test_prep_wait_reader_reads_the_ledgers_phase(ledger, median_ms):
+    got = _reader("guard.prep_wait_ms")({"window_ledger": ledger})
+    assert got == (None if median_ms is None
+                   else pytest.approx(median_ms))

@@ -95,6 +95,43 @@ def test_multi_triple_task_atomic():
     run(main())
 
 
+@pytest.mark.parametrize("triples,moved", [
+    (1, (1, 1, 0)),      # `verify`: one task, one triple, no multi task
+    (3, (1, 3, 1)),      # `verify_multi` of an aggregate-and-proof
+])
+def test_tasks_triples_and_multi_tasks_are_counted_apart(triples, moved):
+    """A dispatch's lanes are triples: `_task_count_total` alone
+    under-counts an aggregate topic threefold.  Each counter moves at
+    completion, whatever the verdict."""
+    async def main():
+        reg = MetricsRegistry()
+        svc = make_service(num_workers=1, registry=reg, name="counted")
+        await svc.start()
+        task = [([PKS[i]], b"counted-%d" % i,
+                 bls.sign(SKS[i], b"counted-%d" % i))
+                for i in range(triples)]
+        names = ("counted_task_count_total", "counted_triple_count_total",
+                 "counted_multi_task_count_total")
+        before = [reg.counter(n).value for n in names]
+        if triples == 1:
+            ok = await svc.verify(*task[0])
+        else:
+            ok = await svc.verify_multi(task)
+        after = [reg.counter(n).value for n in names]
+        assert ok is True
+        assert tuple(a - b for a, b in zip(after, before)) == moved
+        # a false task is a completed task too
+        forged = [(pks, msg + b"!", sig) for pks, msg, sig in task]
+        bad = (await svc.verify(*forged[0]) if triples == 1
+               else await svc.verify_multi(forged))
+        await svc.stop()
+        assert bad is False
+        assert tuple(reg.counter(n).value - b
+                     for n, b in zip(names, before)) \
+            == tuple(2 * m for m in moved)
+    run(main())
+
+
 def test_queue_overflow():
     async def main():
         svc = make_service(num_workers=1, queue_capacity=2)
