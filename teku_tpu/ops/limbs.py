@@ -348,10 +348,30 @@ def pow_static(a, e: int, window: int = POW_WINDOW):
     return acc
 
 
+# Rows a Fermat scan runs on.  The chip runs the scan's ~489 sequential
+# mont_muls 7-8x faster on a tile of rows than on one row (PERF.md
+# §7.9), so a narrow inversion is padded to a tile.  Inside the programs
+# (each alone, median of 7; PERF.md §6, PR 37) stage_finish at 17 rows
+# read 59.23 / 25.05 / 24.37 / 24.36 ms and stage_group at 256 rows
+# 42.02 / 7.59 / 6.49 / 6.41 ms with the scan at 1 / 16 / 64 / 128 rows.
+_FERMAT_ROWS = 128
+
+
 def inv(a):
     """Field inverse via Fermat (a^(P-2)); inv(0) ≡ 0 (callers select
-    around it, branch-free)."""
-    return pow_static(a, P - 2)
+    around it, branch-free).
+
+    An operand of fewer than _FERMAT_ROWS elements runs the scan on a
+    tile: its rows, then rows of ONE_MONT (inv(1) = 1), of which only
+    the live ones are kept.  Each row of the scan is independent, so
+    the live rows are bit-identical to the narrow scan's."""
+    flat = a.reshape((-1, L))
+    m = flat.shape[0]
+    if m < _FERMAT_ROWS:
+        ones = jnp.broadcast_to(jnp.asarray(ONE_MONT),
+                                (_FERMAT_ROWS - m, L))
+        flat = jnp.concatenate([flat, ones], axis=0)
+    return pow_static(flat, P - 2)[:m].reshape(a.shape)
 
 
 def inv_many(a):
@@ -360,10 +380,10 @@ def inv_many(a):
     product scan.
 
     a: (..., L) Montgomery units, any batch shape (flattened internally).
-    Cost: one single-element a^(P-2) scan plus ~2*log2(M) mont_muls per
-    element (one rolled log-depth scan + the recombine), versus one
-    full 380-bit Fermat scan per element for `inv` — the dominant
-    compile-time and runtime win of the verification kernel.
+    Cost: one a^(P-2) scan (on `inv`'s tile of rows) plus ~2*log2(M)
+    mont_muls per element (one rolled log-depth scan + the recombine),
+    versus one full 380-bit Fermat scan per element for `inv` — the
+    dominant compile-time and runtime win of the verification kernel.
 
     inv_many(0) ≡ 0 per-lane (zero lanes are masked out of the product
     so they cannot poison the batch).

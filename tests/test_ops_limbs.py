@@ -8,9 +8,12 @@ cross-path parity gate asserting bit-identical ``canonical()`` images.
 """
 
 import random
+import re
 
 import numpy as np
 import pytest
+
+import jax
 
 from teku_tpu.crypto.bls.constants import P, R
 from teku_tpu.ops import limbs as fp
@@ -99,16 +102,116 @@ def test_mul_small():
         assert unbatch(fp.mul_small(a, k)) == [v * k % P for v in a_vals]
 
 
-def test_pow_static_and_inv():
-    a_vals = [rand_fq() for _ in range(4)] + [1, P - 1]
+def _inv_oracle(v):
+    return pow(v, -1, P) if v % P else 0      # inv(0) = 0 convention
+
+
+# row counts around the tile `inv` pads a narrow operand to
+INV_ROWS = [1, 2, fp._FERMAT_ROWS - 1, fp._FERMAT_ROWS, fp._FERMAT_ROWS + 1]
+
+
+@pytest.mark.parametrize("path", ["vpu", "mxu-force"])
+@pytest.mark.parametrize("rows", INV_ROWS)
+def test_pow_static_and_inv(rows, path):
+    """inv on `rows` elements (zero rows and rows of P - 1 among them)
+    is the field inverse, and bit-for-bit the unpadded Fermat scan's
+    limbs: a padded tile keeps its live rows exact."""
+    r = random.Random(rows)
+    specials = [0, P - 1, 1]
+    a_vals = [P - 1] if rows == 1 else [
+        specials[i // 2 % 3] if i % 2 else r.randrange(P)
+        for i in range(rows)]
     a = batch_mont(a_vals)
-    for e in (1, 2, 3, 65537, (P - 1) // 2):
-        assert unbatch(fp.pow_static(a, e)) == [pow(v, e, P) for v in a_vals]
-    got = unbatch(fp.inv(a))
-    assert got == [pow(v, -1, P) for v in a_vals]
-    # inv(0) = 0 convention
-    z = batch_mont([0])
-    assert unbatch(fp.inv(z)) == [0]
+    with mxu.force(path):
+        if rows == INV_ROWS[2]:
+            for e in (1, 2, 3, 65537, (P - 1) // 2):
+                assert unbatch(fp.pow_static(a, e)) == [pow(v, e, P)
+                                                          for v in a_vals]
+            assert unbatch(fp.inv(batch_mont([0]))) == [0]
+        got = np.asarray(fp.inv(a))
+        assert (got == np.asarray(fp.pow_static(a, P - 2))).all()
+    assert got.shape == a.shape
+    assert unbatch(got) == [_inv_oracle(v) for v in a_vals]
+
+
+@pytest.mark.parametrize("path", ["vpu", "mxu-force"])
+@pytest.mark.parametrize("vals", [[5], [0], [P - 1, 0, 7, 0, 1, 0]],
+                         ids=["one", "one-zero", "zero-lanes"])
+def test_inv_many(vals, path):
+    """inv_many's one-element batch goes to inv whole; zero lanes of a
+    batch come out 0 and do not poison the others' shared product."""
+    a = batch_mont(vals)
+    with mxu.force(path):
+        got = np.asarray(fp.inv_many(a))
+        if len(vals) == 1:
+            assert (got == np.asarray(fp.inv(a))).all()
+    assert unbatch(got) == [_inv_oracle(v) for v in vals]
+
+
+def _while_loops(text):
+    """Each stablehlo.while of a lowered module as (its carried types,
+    the carried types of every loop it runs), following the func.calls
+    its regions make: a scan's body is a function of its own."""
+    loops, funcs = [], {}   # [types, inner loop ids, callees]; fn -> ids, callees
+    open_loops, depth, fn = [], 0, None
+    for line in text.splitlines():
+        name = re.search(r"func\.func \w* ?@([\w.]+)", line)
+        if name:
+            fn = funcs.setdefault(name.group(1), ([], set()))
+        if "stablehlo.while(" in line:
+            for i, _ in open_loops:
+                loops[i][1].append(len(loops))
+            fn[0].append(len(loops))
+            open_loops.append((len(loops), depth))
+            loops.append([line.rsplit(") : ", 1)[1], [], set()])
+        for callee in re.findall(r"call @([\w.]+)", line):
+            fn[1].add(callee)
+            for i, _ in open_loops:
+                loops[i][2].add(callee)
+        depth += line.count("{") - line.count("}")
+        while open_loops and "}" in line and depth <= open_loops[-1][1]:
+            open_loops.pop()
+
+    def runs(ids, callees, seen):
+        out = [loops[i][0] for i in ids]
+        for c in callees - seen:
+            seen.add(c)
+            out += runs(*funcs[c], seen)
+        return out
+
+    return [(t, runs(inner, callees, set())) for t, inner, callees in loops]
+
+
+@pytest.mark.parametrize("stage", ["finish", "group"])
+def test_fermat_scan_runs_on_a_tile(stage):
+    """Each Fermat scan of a staged program carries _FERMAT_ROWS rows,
+    and no loop inside one carries a width-1 (1, L) / (L, 1) element
+    (the shape at which a scan step cost the chip 83 us: PERF.md §7.9).
+    Engagement is decided from static shapes at trace time, so the
+    lowered program is the witness."""
+    from teku_tpu.ops import shapeset
+    from teku_tpu.ops import verify as V
+    avals = next(av for _, av, meta in shapeset.enumerate_programs(
+        max_batch=8, min_bucket=8, h2c_min_bucket=8)
+        if meta["stage"] == stage and meta.get("profile") == "x1")
+    fn = {"finish": V.stage_finish, "group": V.stage_group}[stage]
+    with mxu.force("vpu"):
+        loops = _while_loops(jax.jit(fn).lower(*avals).as_text())
+    n_digits = -(-(P - 2).bit_length() // fp.POW_WINDOW)
+    digits = f"tensor<{n_digits - 1}xi64>"
+    rows = fp._FERMAT_ROWS
+    scans = [(t, inner) for t, inner in loops if digits in t]
+    assert len(scans) == 1                     # ONE inversion a program
+    for types, inner in scans:
+        assert f"tensor<{rows}x{fp.L}xi64>" in types
+        assert f"tensor<{1 << fp.POW_WINDOW}x{rows}x{fp.L}xi64>" in types
+        assert inner, "the scan's mont_muls are loops of their own"
+        for t in [types] + inner:
+            assert f"tensor<1x{fp.L}xi64>" not in t
+            assert f"tensor<{fp.L}x1xi64>" not in t
+    # and the scan's table of a^d is built at the same width
+    assert not any(f"tensor<{1 << fp.POW_WINDOW}x1x{fp.L}xi64>" in t
+                   for t, _ in loops)
 
 
 def test_sqrt_candidate():
