@@ -284,7 +284,10 @@ class GuardedBls12381(BLS12381):
                     return prepared.verdict
             marks.mark("lock_wait")
             with lock:
-                marks.stamp_lock("acquired")
+                # the lock is ours: up to the provider's first program
+                # call the hold is host work (`launch_head`), which
+                # begins at the very instant stamped as the lock's edge
+                marks.stamp_lock("acquired", marks.mark("launch_head"))
                 try:
                     if prepared is None:
                         return device_fn(*args)

@@ -45,7 +45,7 @@ import zlib
 from collections import deque
 from typing import Callable, List, Optional, Sequence, Tuple
 
-from . import clock
+from . import clock, tracing
 from .env import env_int, env_str
 from .metrics import GLOBAL_REGISTRY
 
@@ -394,10 +394,17 @@ class AotDispatcher:
     is the AOT twin of jax's in-memory jit cache).  A store
     executable that fails its FIRST call (an aval corner the signature
     missed, a blob the runtime rejects) permanently falls back to the jit
-    for that signature: correctness never depends on the store."""
+    for that signature: correctness never depends on the store.
+
+    Every call is a launch of the dispatch that makes it
+    (`tracing.launched`), named `program`: the jitted function's name,
+    which is its module's on the profiler's line less `jit_` and the
+    run id.  A signature's first call is one launch whose seconds hold
+    its load or compile."""
 
     def __init__(self, kernel: str, jit_fn: Callable):
         self.kernel = kernel
+        self.program = getattr(jit_fn, "__name__", kernel)
         self._jit = jit_fn
         self._memo: dict = {}
         # signatures whose store executable has not completed a call
@@ -446,6 +453,9 @@ class AotDispatcher:
         return self._jit, rec
 
     def __call__(self, *args):
+        return tracing.launched(self.program, self._call, *args)
+
+    def _call(self, *args):
         sig = shape_sig(args)
         with self._memo_lock:
             fn = self._memo.get(sig)

@@ -35,12 +35,15 @@ device time, verdict).  Each record captures:
   inside that enqueue (``infra/aotstore.py`` ``load_records``);
 - ``phases``: the dispatch's whole life as ``[name, t_mono,
   seconds]`` in order (thread_hop, prep_wait, host_prep, lock_wait,
-  device_enqueue, device_sync, return_hop, settle: the marks of
-  ``infra/tracing.py``, tiling first mark to last; ``prep_wait`` is
-  the wait for the guarded provider's turn to pack, ``host_prep``
-  packing alone), ``lock``:
+  launch_head, device_enqueue, device_sync, return_hop, settle: the
+  marks of ``infra/tracing.py``, tiling first mark to last;
+  ``prep_wait`` is the wait for the guarded provider's turn to pack,
+  ``host_prep`` packing alone, ``launch_head`` the held lock's host
+  work up to the first program call), ``lock``:
   ``{acquired, released}`` of the guarded provider's device-entry
-  lock, and ``parent_seq``: the failed batch a bisect dispatch came
+  lock, ``launches``: each program call as ``[program, t_mono,
+  seconds]`` in launch order (on a mesh a fourth item, the chips it
+  ran on), and ``parent_seq``: the failed batch a bisect dispatch came
   from (absent with tracing off);
 - ``prep``: where the dispatch's host prep ran: ``outside_lock``
   (all of it in the provider's host half, before the device-entry
@@ -348,13 +351,15 @@ def open_record(**fields) -> dict:
     rec["admission"] = ann
     rec.update(fields)
     # the dispatch's phase marks (infra/tracing.py) travel the same
-    # way: the record takes `phases` and `lock` BY REFERENCE, so the
-    # phases that end after the provider has published it (return_hop,
+    # way: the record takes `phases`, `lock` and `launches` BY
+    # REFERENCE, so the launches made after it opened and the phases
+    # that end after the provider has published it (return_hop,
     # settle, on the service's side) complete it in place
     marks = tracing.current_marks()
     if marks:
         rec["phases"] = marks.phases
         rec["lock"] = marks.lock
+        rec["launches"] = marks.launches
         rec["parent_seq"] = marks.parent_seq
         marks.record = rec
     return rec

@@ -35,6 +35,11 @@ MetricsSystem hangs off the validation pipeline):
   traces with its exact start; the phases that begin and end on one
   thread are also entered as ``jax.profiler.TraceAnnotation``, so a
   profiler capture holds them on the device trace's own clock;
+- ``launched(program, fn, *args)`` — one program call, stamped into
+  the dispatch's ``launches`` (two clock reads and an append; nothing
+  where no dispatch is marked): the serving seam
+  (``infra/aotstore.py``) and the H(m) arena call their programs
+  through it;
 - a bounded ring of the N slowest complete traces with their stage
   breakdowns, dumped by ``GET /teku/v1/admin/traces``.
 
@@ -77,8 +82,12 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 #                   lock where a key had to be validated or the H(m)
 #                   arena looked up)
 #   lock_wait       blocked on the serving pair's device-entry lock
-#   device_enqueue  the async launches (plus XLA compile or program
-#                   load on a first shape)
+#   launch_head     the lock is held, no program called yet: the
+#                   provider's bookkeeping and ledger record (a second
+#                   piece after the H(m) arena's plan, under the lock)
+#   device_enqueue  from the first program call: the async launches
+#                   (plus XLA compile or program load on a first
+#                   shape), each stamped in `DispatchMarks.launches`
 #   device_sync     only the blocking wait at the handle's result()
 #   return_hop      sync ended → the service runs again on the event loop
 #   settle          the verified batch's futures resolved, to the last
@@ -86,14 +95,15 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 # (the parent of thread_hop .. return_hop in the span tree);
 # `oracle_execute` is a guarded call the oracle served for the device.
 STAGES = ("queue_wait", "assembly", "dispatch", "thread_hop",
-          "prep_wait", "host_prep", "lock_wait", "device_enqueue",
-          "device_sync", "return_hop", "settle", "oracle_execute",
-          "complete")
+          "prep_wait", "host_prep", "lock_wait", "launch_head",
+          "device_enqueue", "device_sync", "return_hop", "settle",
+          "oracle_execute", "complete")
 
 # phases that begin and end on ONE thread: entered as profiler
 # annotations too (a hop crosses threads and cannot be)
 _ANNOTATED = frozenset(("prep_wait", "host_prep", "lock_wait",
-                        "device_enqueue", "device_sync", "settle"))
+                        "launch_head", "device_enqueue", "device_sync",
+                        "settle"))
 
 _enabled = True
 
@@ -333,22 +343,31 @@ class DispatchMarks:
     only a timed-out dispatch's orphaned thread can interleave, and
     its record is a fault's best effort.
 
-    `phases` and `lock` are handed to the dispatch's ledger record by
-    reference (`dispatchledger.open_record`), so what the service marks
-    after the provider has published the record completes it in place.
+    `launches` stamps each program call of the dispatch, in launch
+    order: `[program, t_mono, seconds]` (and, on a mesh, the chips it
+    ran on), `program` being the name the profiler's module line gives
+    it less `jit_` and the run id.  A call made while `launch_head` is
+    open ends that phase and opens `device_enqueue`; the later ones
+    change no phase.
+
+    `phases`, `lock` and `launches` are handed to the dispatch's ledger
+    record by reference (`dispatchledger.open_record`), so what the
+    service marks after the provider has published the record
+    completes it in place.
     A closed phase reaches the stage histogram at once and the batch's
     traces at `close()`, all phases in one pass over the traces: the
     only per-task work, and less of it than a span a phase.
     """
 
-    __slots__ = ("traces", "phases", "lock", "parent_seq", "record",
-                 "_open", "_t0", "_ann", "_copied")
+    __slots__ = ("traces", "phases", "lock", "launches", "parent_seq",
+                 "record", "_open", "_t0", "_ann", "_copied")
 
     def __init__(self, traces: Tuple[Trace, ...],
                  parent_seq: Optional[int] = None):
         self.traces = traces
         self.phases: List[list] = []    # [name, t_mono, seconds]
         self.lock: Dict[str, float] = {}    # acquired / released
+        self.launches: List[list] = []  # [program, t_mono, seconds]
         self.parent_seq = parent_seq
         self.record: Optional[dict] = None
         self._open: Optional[str] = None
@@ -388,9 +407,10 @@ class DispatchMarks:
             for trace in self.traces:
                 trace.add_spans(fresh)
 
-    def stamp_lock(self, edge: str) -> None:
-        """`acquired` / `released` of the device-entry lock."""
-        self.lock[edge] = round(clock.mono(), 6)
+    def stamp_lock(self, edge: str, at: Optional[float] = None) -> None:
+        """`acquired` / `released` of the device-entry lock (`at`: an
+        instant the caller already read, e.g. a mark's)."""
+        self.lock[edge] = round(clock.mono() if at is None else at, 6)
 
     @property
     def seq(self) -> Optional[int]:
@@ -407,6 +427,7 @@ class _NoMarks:
 
     __slots__ = ()
     seq = None
+    launches = ()
 
     def __bool__(self) -> bool:
         return False
@@ -417,7 +438,7 @@ class _NoMarks:
     def close(self) -> None:
         pass
 
-    def stamp_lock(self, edge: str) -> None:
+    def stamp_lock(self, edge: str, at: Optional[float] = None) -> None:
         pass
 
 
@@ -446,6 +467,23 @@ def new_marks(traces: Sequence[Optional[Trace]] = (),
 def current_marks():
     """The context's dispatch marks (the shared no-op when none)."""
     return _MARKS.get()
+
+
+def launched(program: str, fn: Callable, *args):
+    """`fn(*args)`, a program call, stamped on the context's dispatch
+    marks as a launch of `program`; with no marks (tracing disabled, no
+    dispatch marked) the call alone."""
+    marks = _MARKS.get()
+    if not marks:
+        return fn(*args)
+    # a dispatch's first call ends the hold's head at its start
+    t0 = (marks.mark("device_enqueue") if marks._open == "launch_head"
+          else clock.mono())
+    try:
+        return fn(*args)
+    finally:
+        marks.launches.append([program, round(t0, 6),
+                               round(clock.mono() - t0, 6)])
 
 
 @contextmanager

@@ -40,7 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..infra import faults
+from ..infra import faults, tracing
 from ..infra.env import env_str
 from ..infra.metrics import GLOBAL_REGISTRY
 from . import limbs as fp
@@ -99,7 +99,9 @@ class H2cPointCache:
 
     Thread-safe: the batching service dispatches from worker threads.
     Arena updates are functional (`.at[].set` yields new arrays), so a
-    gather launched against the previous arena stays consistent.
+    gather launched against the previous arena stays consistent.  The
+    scatter and the gather are launches of the dispatch that calls
+    them (`tracing.launched`, by their jitted functions' names).
     """
 
     def __init__(self, capacity: Optional[int] = None):
@@ -199,7 +201,8 @@ class H2cPointCache:
                 shape = (self.capacity, fp.L)
                 self._arena = tuple(
                     jnp.zeros(shape, dtype=jnp.int64) for _ in range(4))
-            self._arena = _scatter(self._arena, idx, hm_bucket)
+            self._arena = tracing.launched(
+                _scatter.__name__, _scatter, self._arena, idx, hm_bucket)
         return idx[:k]
 
     # ------------------------------------------------------------------
@@ -210,7 +213,9 @@ class H2cPointCache:
         with self._lock:
             arena = self._arena
         assert arena is not None, "gather before any insert"
-        return _gather(arena, np.asarray(lane_slots, dtype=np.int64))
+        return tracing.launched(
+            _gather.__name__, _gather, arena,
+            np.asarray(lane_slots, dtype=np.int64))
 
     # ------------------------------------------------------------------
     def stats(self) -> dict:

@@ -45,7 +45,6 @@ two so the jit cache stays small and shapes stay static (XLA recompiles
 nothing after warm-up).
 """
 
-import contextlib
 import hashlib
 import os
 import secrets
@@ -235,8 +234,10 @@ class _DispatchHandle:
     """An in-flight batch dispatch.
 
     The device work was enqueued via JAX async dispatch when this was
-    created (the `device_enqueue` span, recorded by _launch,
-    covers the launch calls plus any XLA compile a first shape pays);
+    created (the `device_enqueue` phase runs from the first program
+    call to this handle's `result()`: the launch calls, each stamped
+    in the marks' `launches`, plus any XLA compile or program load a
+    first shape pays);
     result() forces the verdict arrays (the only host/device sync
     point) — callers may do arbitrary host work (e.g. host_prep of the
     NEXT batch) between the two.  result() records ONLY the blocking
@@ -890,17 +891,6 @@ class JaxBls12381(BLS12381):
             slots[np.asarray(missing)] = new_slots
         return self._h2c_cache.gather(slots[pack.row_msg])
 
-    @staticmethod
-    def _hm_programs(plan, pack: "_Packed") -> List[str]:
-        """The programs `_hm_device` launches for this plan, by the
-        names the profiler's module line gives them."""
-        slots, missing, _, _ = plan
-        if slots is None:
-            return ["stage_h2c"] + (
-                [] if pack.n_rows == pack.n_unique
-                else ["stage_gather_hm"])
-        return (["stage_h2c", "_scatter"] if missing else []) + ["_gather"]
-
     def _pack(self, semis: Sequence[_Semi],
               randomize: bool) -> "_Packed":
         """One dispatch's host half: numpy and plain Python alone."""
@@ -1053,6 +1043,9 @@ class JaxBls12381(BLS12381):
             if t_prep0 is None:
                 t_prep0 = marks.mark("host_prep")
             hm_plan = self._hm_arena_plan(pack.digests, pack.draws)
+        # what follows up to the first program call (counters, the
+        # ledger record) is the hold's head, whatever prep came before
+        t_head = marks.mark("launch_head")
         prep = "under_lock" if reasons else "outside_lock"
         _M_PREP.labels(prep=prep,
                        reason="+".join(reasons) or "none").inc()
@@ -1073,7 +1066,7 @@ class JaxBls12381(BLS12381):
                      "dispatch_bucket": h2c_bucket}
         if t_prep0 is not None:
             timeline.interval(
-                "worker", "host_prep", time.perf_counter() - t_prep0,
+                "worker", "host_prep", t_head - t_prep0,
                 t_mono=t_prep0, trace_id=tracing.current_trace_id())
         plan, padded = pack.plan, pack.padded
         mesh_n = (self._sharded.n_devices
@@ -1153,26 +1146,16 @@ class JaxBls12381(BLS12381):
             mesh=mesh_block, prep=prep)
         if reasons:
             rec["prep_reason"] = "+".join(reasons)
-        t_dev0 = marks.mark("device_enqueue")
+        # the first program call marks `device_enqueue`; the enqueue's
+        # seconds (the compile or load a first shape pays) count from
+        # here as they always have
+        t_dev0 = time.perf_counter()
+        n_launched = len(marks.launches)
         outcome = "cache_hit"
         enqueued = False
         try:
             hm_uniq = self._hm_device(hm_plan, pack)
             if self._sharded is not None:
-                # what a reader needs to split this dispatch: up to
-                # here every program ran on ONE chip (stage_h2c, the
-                # arena, the row gather below) while the others stood
-                # idle; from `sharded_at_s` (on device_enqueue's
-                # clock) the sharded programs launch on all
-                one_chip = [str(d) for d in jax.tree_util.tree_leaves(
-                    hm_uniq)[0].devices()]
-                mesh_block["programs"] = (
-                    [{"name": name, "on": one_chip}
-                     for name in self._hm_programs(hm_plan, pack)
-                     + ["stage_gather_hm"]]
-                    + [{"name": f"mesh_{name}",
-                        "on": mesh_block["live"]}
-                       for name in V.MESH_STAGES])
                 # `bls.mesh_shard` fault site: a wedged SHARD wedges
                 # the whole mesh dispatch.  The LIVE device names ride
                 # as keys so the chaos harness can wedge exactly one
@@ -1188,20 +1171,24 @@ class JaxBls12381(BLS12381):
                 hm_in = V.staged_jits()["gather"](
                     hm_uniq, jnp.asarray(pack.row_gather))
                 kernel = self._sharded.kernel()
-                mesh_block["sharded_at_s"] = round(
-                    time.perf_counter() - t_dev0, 6)
-                launch = jax.profiler.TraceAnnotation("mesh_launch")
             else:
                 kernel = V.verify_staged_grouped
                 hm_in = hm_uniq
-                launch = contextlib.nullcontext()
-            with launch:
-                ok, lane_ok = kernel(
-                    pack.pk_xs, pack.pk_ys, pack.pk_present, hm_in,
-                    pack.group_idx, pack.group_present, pack.sx,
-                    pack.s_large, pack.s_inf, pack.r_bits,
-                    pack.lane_valid)
+            ok, lane_ok = kernel(
+                pack.pk_xs, pack.pk_ys, pack.pk_present, hm_in,
+                pack.group_idx, pack.group_present, pack.sx,
+                pack.s_large, pack.s_inf, pack.r_bits, pack.lane_valid)
             enqueued = True
+            if self._sharded is not None and marks:
+                # where each launch ran: H(m) and the row gather on ONE
+                # chip while the others stood idle, the sharded
+                # programs on the live set
+                one_chip = [str(d) for d in jax.tree_util.tree_leaves(
+                    hm_uniq)[0].devices()]
+                for launch in marks.launches[n_launched:]:
+                    launch.append(mesh_block["live"]
+                                  if launch[0].startswith("mesh_")
+                                  else one_chip)
         finally:
             if first:
                 outcome = compilecache.classify_first_dispatch(

@@ -463,3 +463,62 @@ def test_load_record_of_a_failed_first_call_names_the_jit(aot_dir):
     _miss, failed = _records_of("test:records_fail")
     assert failed["outcome"] == "jit"
     assert failed["deserialize_s"] > 0 and failed["first_call_s"] > 0
+
+
+# --------------------------------------------------------------------------
+# Every call through the seam is a launch of the dispatch that makes it
+# --------------------------------------------------------------------------
+
+def stage_seam_probe(x):
+    return x * 5 + 1
+
+
+def test_the_seam_stamps_a_launch_only_under_marks(aot_dir):
+    """No dispatch marked (or tracing off): the call alone, nothing
+    stamped.  Under a dispatch's marks: one launch a call, named as
+    the profiler's module line names the program less `jit_`."""
+    from teku_tpu.infra import tracing
+    x = jnp.arange(8, dtype=jnp.int32)
+    jitted = jax.jit(stage_seam_probe)
+    disp = aotstore.wrap("test:seam", jitted)
+    np.asarray(disp(x))
+    assert not tracing.current_marks()
+    assert tracing.current_marks().launches == ()
+    module = jitted.lower(x).as_text().split("module @", 1)[1].split()[0]
+    assert module == "jit_" + disp.program == "jit_stage_seam_probe"
+    with tracing.dispatch_marks("launch_head") as marks:
+        np.asarray(disp(x))
+        np.asarray(disp(x))
+    assert [p for p, _t0, _s in marks.launches] == [disp.program] * 2
+    # the first call ends the hold's head and opens device_enqueue
+    assert [n for n, _t, _s in marks.phases] == ["launch_head",
+                                                 "device_enqueue"]
+    assert marks.phases[1][1] == marks.launches[0][1]
+    tracing.set_enabled(False)
+    try:
+        with tracing.dispatch_marks("launch_head") as off:
+            np.asarray(disp(x))
+        assert not off and off.launches == ()
+    finally:
+        tracing.set_enabled(True)
+
+
+def test_a_first_call_is_one_launch_that_holds_its_load(aot_dir):
+    """A signature's first call (a compile through the store on a miss,
+    a load in the next process) is ONE launch, whose seconds hold the
+    load record's own (compile and save, or read and deserialize)."""
+    from teku_tpu.infra import tracing
+    x = jnp.arange(16, dtype=jnp.int32)
+    disp = aotstore.wrap("test:seam_first", jax.jit(stage_seam_probe))
+    for outcome_of in (("compile", "cache_load"), ("aot_load",)):
+        with tracing.dispatch_marks("launch_head") as marks:
+            np.asarray(disp(x))
+        (launch,) = marks.launches
+        rec = _records_of("test:seam_first")[-1]
+        assert rec["outcome"] in outcome_of
+        paid = (rec["read_s"] + rec["deserialize_s"] + rec["compile_s"]
+                + rec["save_s"])
+        assert paid > 0
+        assert launch[2] >= paid + rec["first_call_s"] - 5e-6
+        assert launch[1] <= rec["t_mono"] + 1e-6
+        _reload(disp)

@@ -407,7 +407,8 @@ def test_record_fields_pinned_against_provider_counters(single_impl,
             <= rec["compile"]["enqueue_s"] + 1e-3
     # no service above: the provider's own phases, tiling
     assert [n for n, _t, _s in rec["phases"]] == [
-        "host_prep", "device_enqueue", "device_sync", "return_hop"]
+        "host_prep", "launch_head", "device_enqueue", "device_sync",
+        "return_hop"]
     for (_a, t0, secs), (_b, t1, _s) in zip(rec["phases"],
                                             rec["phases"][1:]):
         assert t0 + secs == pytest.approx(t1, abs=2.5e-6)
@@ -439,6 +440,30 @@ def test_the_fields_the_benchmark_reads_stay(single_impl, keys):
     for groups in ([1] * 256, [250], [32] * 128):
         assert shapeset.batch_plan(
             groups, min_bucket=256)["msm_path"] == "ladder"
+
+
+def test_a_record_names_its_launches_as_the_module_line_does(
+        single_impl, keys):
+    """Each program call of a dispatch is a launch of its record, named
+    as the profiler's module line names the program (less `jit_` and
+    the run id), in launch order and inside `device_enqueue`: a fresh
+    drain hashes its messages into the arena, the same drain again
+    gathers them alone."""
+    pure, sks, pks = keys
+    tail = ["stage_prepare", "stage_scalars", "stage_group",
+            "stage_miller", "stage_finish"]
+    triples = _grid_batch(pure, sks, pks)
+    for head in (["stage_h2c", "_scatter", "_gather"], ["_gather"]):
+        assert single_impl.batch_verify(triples)
+        rec = _last_record()
+        assert [p for p, _t0, _s in rec["launches"]] == head + tail
+        phases = {n: (t0, secs) for n, t0, secs in rec["phases"]}
+        enq0, enq_s = phases["device_enqueue"]
+        assert rec["launches"][0][1] == enq0
+        assert sum(phases["launch_head"]) == pytest.approx(enq0,
+                                                           abs=2.5e-6)
+        for _p, t0, secs in rec["launches"]:
+            assert enq0 <= t0 and t0 + secs <= enq0 + enq_s + 2.5e-6
 
 
 

@@ -30,7 +30,8 @@ from teku_tpu.services.signatures import (
     AggregatingSignatureVerificationService)
 
 SERVED = ["thread_hop", "prep_wait", "host_prep", "lock_wait",
-          "device_enqueue", "device_sync", "return_hop", "settle"]
+          "launch_head", "device_enqueue", "device_sync", "return_hop",
+          "settle"]
 PK = b"\xa0" + bytes(47)
 
 
@@ -38,10 +39,23 @@ class _Prepared(PreparedDispatch):
     __slots__ = ("triples",)
 
 
+# the device half's two programs: each is a launch of the dispatch
+def stage_fake_first(seconds):
+    time.sleep(seconds)
+
+
+def stage_fake_second(seconds):
+    time.sleep(seconds)
+
+
+PROGRAMS = ["stage_fake_first", "stage_fake_second"]
+
+
 class PhasedDevice:
-    """Packs for `hold_s / 2` in its host half and sleeps `hold_s` in
-    its device half (under the guard's lock); a task whose message
-    starts with b"bad" makes its batch false."""
+    """Packs for `hold_s / 2` in its host half and launches two
+    programs of `hold_s / 2` each in its device half (under the guard's
+    lock), after its bookkeeping as the provider's `_launch` does it; a
+    task whose message starts with b"bad" makes its batch false."""
 
     name = "phased-fake"
 
@@ -61,12 +75,13 @@ class PhasedDevice:
         triples = prepared.triples
         n = len(triples)
         marks = tracing.current_marks()
+        marks.mark("launch_head")
         traces = tracing.current_traces()
         rec = dispatchledger.open_record(
             trace_ids=[t.trace_id for t in traces], shape=f"{n}x1",
             lanes=n, prep="outside_lock")
-        marks.mark("device_enqueue")
-        time.sleep(self.hold_s)
+        for program in (stage_fake_first, stage_fake_second):
+            tracing.launched(program.__name__, program, self.hold_s / 2)
         rec["compile"] = {"outcome": "cache_hit", "enqueue_s": 0.0}
         ok = not any(msg.startswith(b"bad") for _pks, msg, _sig in triples)
         return _DispatchHandle(
@@ -192,6 +207,64 @@ def test_served_dispatch_phases_tile_in_order():
         for name, t0, secs in rec["phases"]:
             assert spans[name][0] == pytest.approx(t0, abs=1e-6)
             assert spans[name][1] == pytest.approx(secs, abs=1e-6)
+
+
+def test_served_dispatch_stamps_each_launch_inside_device_enqueue():
+    """Every program call of the device half is a launch of the record:
+    in launch order, by the program's name, the first one at
+    `device_enqueue`'s start and the last one ended inside it."""
+    hold = 0.04
+    _v, _t, (rec,) = _serve(_guarded(PhasedDevice(hold)), [b"m0", b"m1"],
+                            num_workers=1)
+    launches = rec["launches"]
+    assert [program for program, _t0, _s in launches] == PROGRAMS
+    by_name = {name: (t0, secs) for name, t0, secs in rec["phases"]}
+    enq0, enq_s = by_name["device_enqueue"]
+    assert launches[0][1] == enq0
+    for (_p, t0, secs), (_q, t1, _s) in zip(launches, launches[1:]):
+        assert t0 + secs <= t1 + 2.5e-6
+    for _p, t0, secs in launches:
+        assert enq0 <= t0 and t0 + secs <= enq0 + enq_s + 2.5e-6
+        assert hold / 2 <= secs + 2.5e-6 < hold / 2 + 0.05
+
+
+def test_launch_head_runs_from_the_lock_to_the_first_launch():
+    """The hold begins with `launch_head`, at the very instant stamped
+    as the lock's edge, and the first launch ends it: `lock_wait` is
+    the wait alone."""
+    hold = 0.1
+    _v, _t, records = _serve(
+        _guarded(PhasedDevice(hold)), [b"a0", b"a1", b"b0", b"b1"],
+        num_workers=2, max_batch_size=2)
+    assert len(records) == 2
+    for rec in records:
+        by_name = {name: (t0, secs) for name, t0, secs in rec["phases"]}
+        head0, head_s = by_name["launch_head"]
+        assert head0 == rec["lock"]["acquired"]
+        assert sum(by_name["lock_wait"]) == pytest.approx(head0,
+                                                          abs=2.5e-6)
+        assert head0 + head_s == pytest.approx(rec["launches"][0][1],
+                                               abs=2.5e-6)
+        # the record and the bookkeeping: none of the programs' sleep
+        assert head_s < hold / 2
+
+
+def test_phases_with_the_launches_in_still_tile_to_the_rounding():
+    """Many dispatches of two workers: every seam between consecutive
+    phases is within the record's rounding (three values to the µs, so
+    1.5 µs at worst)."""
+    _v, _t, records = _serve(
+        _guarded(PhasedDevice(0.004)), [b"m%d" % i for i in range(12)],
+        num_workers=2, max_batch_size=2)
+    assert len(records) == 6
+    seams = [abs(t0 + secs - t1)
+             for rec in records
+             for (_n0, t0, secs), (_n1, t1, _s1)
+             in zip(rec["phases"], rec["phases"][1:])]
+    assert max(seams) <= 1.5e-6 + 1e-9
+    for rec in records:
+        assert [n for n, _t, _s in rec["phases"]] == SERVED
+        assert [p for p, _t0, _s in rec["launches"]] == PROGRAMS
 
 
 def test_span_tree_names_the_whole_dispatch():
@@ -355,8 +428,8 @@ def test_profiler_annotations_cover_the_single_thread_phases(monkeypatch):
 
     monkeypatch.setattr(tracing, "_annotation", annotation)
     _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
-    want = ["prep_wait", "host_prep", "lock_wait", "device_enqueue",
-            "device_sync", "settle"]
+    want = ["prep_wait", "host_prep", "lock_wait", "launch_head",
+            "device_enqueue", "device_sync", "settle"]
     assert entered == want and exited == want
 
 
@@ -401,6 +474,19 @@ def test_disabled_tracing_leaves_no_marks_no_phases():
     assert not {"phases", "lock", "parent_seq"} & set(records[0])
     assert records[0]["verdict"] is True
     assert _stage_counts() == before
+
+
+def test_disabled_tracing_stamps_no_launch():
+    """With tracing off a launch is the program call alone: no marks to
+    stamp, no `launches` on the record."""
+    tracing.set_enabled(False)
+    calls = []
+    assert tracing.launched("stage_fake_first", calls.append, 7) is None
+    assert calls == [7]
+    assert tracing.current_marks().launches == ()
+    _v, _t, (rec,) = _serve(_guarded(PhasedDevice(0.01)), [b"m0"],
+                            traced=False, num_workers=1)
+    assert "launches" not in rec and rec["verdict"] is True
 
 
 # --------------------------------------------------------------------------
@@ -448,9 +534,9 @@ def test_async_seam_marks_both_hand_overs():
     assert verdicts == [True, True]
     assert len(records) == 1
     names = [n for n, _t, _s in records[0]["phases"]]
-    assert names == ["thread_hop", "host_prep", "device_enqueue",
-                     "return_hop", "thread_hop", "device_sync",
-                     "return_hop", "settle"]
+    assert names == ["thread_hop", "host_prep", "launch_head",
+                     "device_enqueue", "return_hop", "thread_hop",
+                     "device_sync", "return_hop", "settle"]
     _assert_tiles(records[0]["phases"])
     assert records[0]["lock"] == {}
     sync = dict((n, s) for n, _t, s in records[0]["phases"])["device_sync"]
@@ -467,8 +553,8 @@ def test_direct_caller_keeps_the_providers_phases_only():
     rec = [r for r in dispatchledger.LEDGER.snapshot()
            if r["seq"] > seq0][-1]
     names = [n for n, _t, _s in rec["phases"]]
-    assert names == ["host_prep", "device_enqueue", "device_sync",
-                     "return_hop"]
+    assert names == ["host_prep", "launch_head", "device_enqueue",
+                     "device_sync", "return_hop"]
     _assert_tiles(rec["phases"])
     assert rec["lock"] == {} and rec["parent_seq"] is None
     assert [s for s, _d in tr.stages] == names
@@ -550,6 +636,21 @@ def test_prep_wait_is_a_stage_of_the_histogram_and_an_annotation():
     labels = {key[0] for key, _child in hist._items()}
     assert "prep_wait" in labels
     assert labels <= set(tracing.STAGES), labels - set(tracing.STAGES)
+
+
+def test_launch_head_is_a_stage_of_the_histogram_and_an_annotation():
+    """`launch_head` begins and ends on the breaker's dispatch thread:
+    one more stage of the closed vocabulary, between `lock_wait` and
+    `device_enqueue`, one more annotation, and a label of
+    `verify_stage_duration_seconds` once a dispatch was served."""
+    assert tracing.STAGES.index("lock_wait") \
+        < tracing.STAGES.index("launch_head") \
+        < tracing.STAGES.index("device_enqueue")
+    assert "launch_head" in tracing._ANNOTATED
+    before = _stage_counts()["launch_head"]
+    _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
+    assert _stage_counts()["launch_head"] == before + 1
+    assert len(tracing.STAGES) == 14
 
 
 def test_new_stage_names_are_declared_and_no_timeline_phase_is_new():

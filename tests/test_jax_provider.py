@@ -255,11 +255,15 @@ def test_missed_key_is_validated_under_the_lock(jax_impl):
                                                      "pk_miss")
         assert _prep_count("under_lock", "pk_miss") == before + 1
         names = [n for n, _t, _s in rec["phases"]]
-        assert names[:6] == ["thread_hop", "prep_wait", "host_prep",
-                             "lock_wait", "host_prep", "device_enqueue"]
-        # the second `host_prep` (the validation) lies under the lock
-        t_validate = rec["phases"][4][1]
+        assert names[:8] == ["thread_hop", "prep_wait", "host_prep",
+                             "lock_wait", "launch_head", "host_prep",
+                             "launch_head", "device_enqueue"]
+        # the second `host_prep` (the validation) lies under the lock;
+        # its `pk_validate` call is a launch, and opens no phase
+        t_validate = rec["phases"][5][1]
         assert rec["lock"]["acquired"] <= t_validate + 2.5e-6
+        assert rec["launches"][0][0] == "_pk_validate_kernel"
+        assert rec["launches"][0][1] < rec["phases"][6][1]
         assert jax_impl._pk_cache.get(fresh)[0] == "ok"
         # the same batch again: every key cached, all prep off the lock
         before = _prep_count("outside_lock", "none")
@@ -268,9 +272,9 @@ def test_missed_key_is_validated_under_the_lock(jax_impl):
         assert ok is True and rec["prep"] == "outside_lock"
         assert "prep_reason" not in rec
         assert _prep_count("outside_lock", "none") == before + 1
-        assert [n for n, _t, _s in rec["phases"]][:5] == [
+        assert [n for n, _t, _s in rec["phases"]][:6] == [
             "thread_hop", "prep_wait", "host_prep", "lock_wait",
-            "device_enqueue"]
+            "launch_head", "device_enqueue"]
         # a forged lane behind a fresh key is false, not an error
         sk2 = keygen(b"\x78" * 32)
         fresh2 = PureBls12381().secret_key_to_public_key(sk2)
@@ -316,6 +320,29 @@ def test_arena_lookups_stay_under_the_lock(jax_impl):
     ok, (rec,) = _guarded_records(
         jax_impl, lambda g: g.batch_verify(triples))
     assert ok is True and rec["h2c"]["cache_hits"] == 3
+
+
+def test_arena_plan_sits_between_two_launch_head_pieces(jax_impl):
+    """With the arena on, the hold begins with `launch_head` at the
+    lock's edge, the arena's plan is `host_prep` under the lock, and
+    the bookkeeping after it is `launch_head` again up to the first
+    launch; the phases still tile."""
+    assert jax_impl._h2c_cache.enabled
+    triples = _signed(3, tag=b"heads")
+    ok, (rec,) = _guarded_records(
+        jax_impl, lambda g: g.batch_verify(triples))
+    assert ok is True and rec["prep_reason"] == "arena"
+    phases = rec["phases"]
+    assert [n for n, _t, _s in phases] == [
+        "thread_hop", "prep_wait", "host_prep", "lock_wait",
+        "launch_head", "host_prep", "launch_head", "device_enqueue",
+        "device_sync", "return_hop"]
+    for (_n0, t0, secs), (_n1, t1, _s1) in zip(phases, phases[1:]):
+        assert t0 + secs == pytest.approx(t1, abs=2.5e-6)
+    assert phases[4][1] == rec["lock"]["acquired"]
+    assert phases[7][1] == rec["launches"][0][1]
+    assert [p for p, _t0, _s in rec["launches"]][:3] == [
+        "stage_h2c", "_scatter", "_gather"]
 
 
 def test_guarded_key_check_takes_the_lock_only_for_a_miss(jax_impl):

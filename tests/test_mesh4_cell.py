@@ -16,8 +16,8 @@ shard's floors 2 lanes x 1 row where the cell has 64 x 4).
 - `plan_group_shards` gives every drain the cell can meet (one message
   of 250 and the 249 straddles of two, 464 tasks a message, rows of at
   most 32) 64 lanes x 4 rows a shard at group bucket 32: one shape;
-- a mesh dispatch's ledger record names its programs and where each
-  ran, and where on `device_enqueue`'s clock the sharded launch began.
+- a mesh dispatch's ledger record stamps each program launch, with
+  the chips it ran on, inside `device_enqueue`.
 """
 
 import asyncio
@@ -274,22 +274,25 @@ def test_a_mesh_record_names_its_programs_and_where_they_ran(impls):
     rec = next(r for r in dispatchledger.LEDGER.snapshot()
                if r["seq"] > seq)
     mesh = rec["mesh"]
-    names = [p["name"] for p in mesh["programs"]]
+    launches = rec["launches"]
+    names = [launch[0] for launch in launches]
     # a fresh drain: hashed and put into the arena on one chip, then
     # the row gather, then the sharded stages and the exchange
     assert names == ["stage_h2c", "_scatter", "_gather",
                      "stage_gather_hm"] + [f"mesh_{s}"
                                            for s in V.MESH_STAGES]
-    one_chip = {tuple(p["on"]) for p in mesh["programs"][:4]}
+    one_chip = {tuple(launch[3]) for launch in launches[:4]}
     assert one_chip == {(str(jax.devices()[0]),)}
-    assert all(p["on"] == mesh["live"] and len(p["on"]) == 4
-               for p in mesh["programs"][4:])
+    assert all(launch[3] == mesh["live"] and len(launch[3]) == 4
+               for launch in launches[4:])
     # inside device_enqueue, after the single-chip launches
-    assert 0.0 < mesh["sharded_at_s"] <= rec["compile"]["enqueue_s"]
+    enq0 = next(t0 for name, t0, _s in rec["phases"]
+                if name == "device_enqueue")
+    assert 0.0 < launches[4][1] - enq0 <= rec["compile"]["enqueue_s"]
     # the same messages again: no hashing, the arena's gather alone
     seq = dispatchledger.LEDGER.recorded_total
     assert meshed.batch_verify(_batch([4, 4], "record"))
     rec = next(r for r in dispatchledger.LEDGER.snapshot()
                if r["seq"] > seq)
-    assert [p["name"] for p in rec["mesh"]["programs"]][:2] \
+    assert [launch[0] for launch in rec["launches"]][:2] \
         == ["_gather", "stage_gather_hm"]

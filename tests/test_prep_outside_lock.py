@@ -489,7 +489,8 @@ def test_a_provider_without_halves_runs_whole_under_the_lock(kind):
     their verb is the device half.  So is a verb replaced on the
     instance (a fault harness: the halves would go around it).  They
     take no turn: neither `prep_wait` nor `host_prep` is marked, and
-    the packers' lock is never touched."""
+    the packers' lock is never touched.  Their hold is `launch_head`
+    to its end: they launch no program through the seam."""
     held = []
 
     def whole(triples):
@@ -510,7 +511,7 @@ def test_a_provider_without_halves_runs_whole_under_the_lock(kind):
         assert guarded.batch_verify([([b"pk"], b"m", b"sig")]) is True
     assert held == [(False, True)]
     names = [name for name, _t0, _s in marks.phases]
-    assert names == ["thread_hop", "lock_wait"]
+    assert names == ["thread_hop", "lock_wait", "launch_head"]
     assert getattr(device, "events", []) == []
 
 
