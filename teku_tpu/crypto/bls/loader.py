@@ -110,8 +110,8 @@ def device_label() -> str:
 # --------------------------------------------------------------------------
 
 # Atomically-swapped state registration for the static analyzer:
-# `_serving` holds the (provider, device-entry lock) PAIR as one tuple
-# so a reader can never observe a half-swap — which is only true if
+# `_serving` holds (provider, device-entry lock, packers' turn) as one
+# tuple so a reader can never observe a half-swap — which is only true if
 # every reader performs exactly ONE attribute load and destructures
 # the snapshot.  `cli lint`'s torn-read checker enforces the
 # single-read rule tree-wide for every attribute declared here (the
@@ -158,45 +158,60 @@ class GuardedBls12381(BLS12381):
             "bls_verify_requests_total",
             "guarded BLS dispatches by serving backend and reason",
             labelnames=("backend", "reason"))
-        # (provider, device-entry lock) as ONE atomically-swapped pair.
+        # how a dispatch with a host half gave the packers' turn back:
+        # after its launches (the served path), at once on a verdict
+        # the host half could give, or on a raise before its launches
+        # had returned; a closed set
+        self._m_turn = registry.labeled_counter(
+            "bls_prep_turn_total",
+            "guarded dispatches with a host half, by how they gave the "
+            "packers' turn back (launched|host_verdict|error)",
+            labelnames=("released",))
+        # (provider, device-entry lock, packers' turn) as ONE
+        # atomically-swapped triple.
+        #
         # The lock guards what ENTERS THE DEVICE, and nothing else: a
         # dispatch's launches and its sync, a `pk_validate` for a key
         # the cache lacked, the H(m) arena's slots (their state must
         # follow device order).  A provider's host half
         # (`prepare_dispatch`: parsing, cache lookups, array packing)
         # runs before the lock is taken, so one worker packs its batch
-        # while the other one's runs on the chip (one host half at a
-        # time: `_prep_lock` below); the provider's host caches carry
-        # their own locks (`LimitedMap`).  A timed-out
-        # dispatch's orphaned thread may still be on the device (e.g.
-        # finishing a cold compile): it keeps the lock for as long, a
-        # later dispatch preps, then blocks there until the orphan
-        # drains; the breaker deadline bounds prep + wait + device and
-        # accounts the overrun as a timeout, so a busy device reads as
-        # a busy device.  The mesh-reshape hot-swap replaces the PAIR
-        # in one reference assignment: dispatches that grabbed the old
-        # pair prepare for, and complete on, the old plan (their
-        # orphans keep the old lock), new dispatches take the new
-        # provider immediately and never queue behind a wedged orphan.
-        self._serving = (device, threading.Lock())
-        # Host halves take turns.  They are plain Python and small
-        # numpy calls under the interpreter lock, so two at once gain
-        # nothing, and they convoy: each call that lets the
-        # interpreter lock go waits a switch interval to get it back
-        # from the other packer.  Two workers that drained a burst
-        # together packed a 256 x 512 batch in 1.14-1.45 s each on the
-        # chip's host, where one alone takes 0.11 s (PERF.md, PR 36).
-        # The wait for the turn is the `prep_wait` phase; the turn is
-        # given back before `lock_wait` begins, so a worker standing at
-        # the device-entry lock never holds up the other's packing.
-        # Not part of the swapped pair: it guards no device state, and
-        # a reshape leaves it.  A host half that hangs keeps the turn
-        # for as long (a `with` block: it gives it back when it ends,
-        # however it ends); the breaker's deadline bounds wait + prep
-        # + wait + device, so a dispatch behind it is answered by the
-        # oracle and its orphaned thread packs, and runs, once the
-        # turn comes.
-        self._prep_lock = threading.Lock()
+        # while the other one's runs on the chip; the provider's host
+        # caches carry their own locks (`LimitedMap`).
+        #
+        # The turn orders the host's work in front of the device: a
+        # dispatch takes it to pack, keeps it through `lock_wait` and
+        # its launches, and gives it back once `launch_dispatch` has
+        # returned its handle, before the sync.  Host halves are plain
+        # Python and small numpy calls under the interpreter lock, so
+        # two at once gain nothing and convoy (a 256 x 512 batch packed
+        # in 1.14-1.45 s each where one alone takes 0.11 s; PERF.md
+        # §6), and a packer beside a launcher stretches every launch
+        # call that lets the interpreter lock go (the first ~31 ms of
+        # a hold; PERF.md §5).  So the other worker packs while this
+        # dispatch's programs run, then stands at the lock holding the
+        # turn, so no packing runs beside its own launches.  Taking the
+        # turn only at the lock would put the packing that has just
+        # begun in front of the launcher.  The wait for the turn is
+        # `prep_wait`, which holds the other worker's launches too.
+        # Order: turn, then lock; nobody holding the lock waits for
+        # the turn.
+        #
+        # A timed-out dispatch's orphaned thread may still be on the
+        # device (e.g. finishing a cold compile): it keeps the lock for
+        # as long, a later dispatch packs, then blocks there holding
+        # the turn until the orphan drains, and the one after it waits
+        # for the turn; the breaker deadline bounds wait + prep + wait
+        # + device and accounts the overrun as a timeout, so a busy
+        # device reads as a busy device.  An orphan gives the turn back
+        # when its launches end, however they end, as a host half that
+        # hangs does when it ends.  The mesh-reshape hot-swap replaces
+        # the TRIPLE in one reference assignment: dispatches that
+        # grabbed the old one prepare for, and complete on, the old
+        # plan (their orphans keep the old lock and turn), new
+        # dispatches take the new provider and turn immediately and
+        # never queue behind a wedged orphan.
+        self._serving = (device, threading.Lock(), threading.Lock())
 
     @property
     def device(self) -> BLS12381:
@@ -209,9 +224,9 @@ class GuardedBls12381(BLS12381):
     def swap_device(self, new_device: BLS12381) -> None:
         """Atomic mid-mesh hot-swap (the reshape install hook): one
         reference assignment, same invariant as the PR-1 install swap
-        — in-flight verifies complete on the implementation pair they
-        grabbed, new verifies take the reshaped provider."""
-        self._serving = (new_device, threading.Lock())
+        — in-flight verifies complete on the provider, lock and turn
+        they grabbed, new verifies take the reshaped provider."""
+        self._serving = (new_device, threading.Lock(), threading.Lock())
 
     def _notify_healer(self, exc: BaseException, timeout: bool) -> None:
         healer = self.healer
@@ -251,9 +266,10 @@ class GuardedBls12381(BLS12381):
 
     # --- guarded device dispatches ------------------------------------
     def _guarded(self, op: str, *args):
-        # ONE read of the serving pair: the provider and its entry
-        # lock stay consistent even when a reshape swaps mid-call
-        device, lock = self._serving
+        # ONE read of the serving triple: the provider, its entry lock
+        # and its turn stay consistent even when a reshape swaps
+        # mid-call
+        device, lock, turn = self._serving
         device_fn = getattr(device, op)
         # the provider's own split of the verb into a host half and a
         # device half; one that has none (the oracle family, a model)
@@ -264,24 +280,11 @@ class GuardedBls12381(BLS12381):
         if op not in getattr(device, "__dict__", ()):
             prepare = getattr(device, "prepare_dispatch", None)
 
-        def locked():
+        def whole():
             # runs on the breaker's dispatch thread: the hop to it ends
-            # with the first mark here.  The host half comes first, off
-            # the device-entry lock and in its turn (`prep_wait`: the
-            # other worker is packing); what follows until the lock is
-            # ours is the wait behind the other worker's launches and
-            # sync
+            # with the first mark here.  No host half, no turn: the
+            # verb runs whole under the lock
             marks = tracing.current_marks()
-            prepared = None
-            if prepare is not None:
-                marks.mark("prep_wait")
-                with self._prep_lock:
-                    marks.mark("host_prep")
-                    prepared = prepare(op, *args)
-                if prepared.verdict is not None:
-                    # known on the host (malformed wire, a cached
-                    # key): nothing enters the device
-                    return prepared.verdict
             marks.mark("lock_wait")
             with lock:
                 # the lock is ours: up to the provider's first program
@@ -289,11 +292,51 @@ class GuardedBls12381(BLS12381):
                 # begins at the very instant stamped as the lock's edge
                 marks.stamp_lock("acquired", marks.mark("launch_head"))
                 try:
-                    if prepared is None:
-                        return device_fn(*args)
-                    return device.launch_dispatch(prepared).result()
+                    return device_fn(*args)
                 finally:
                     marks.stamp_lock("released")
+
+        def in_turn():
+            # as `whole`, with the host half first, in its turn.  The
+            # wait for the turn (`prep_wait`) is the other worker's
+            # packing and launches; the host half runs off the
+            # device-entry lock, and the turn stays ours through the
+            # wait for that lock (`lock_wait`: the other worker's
+            # sync) and our launches, so the other worker's next
+            # packing runs beside our programs, not our launch calls
+            marks = tracing.current_marks()
+            held = True
+
+            def give_back(released):
+                nonlocal held
+                if held:
+                    held = False
+                    self._m_turn.labels(released=released).inc()
+                    turn.release()
+
+            marks.mark("prep_wait")
+            turn.acquire()
+            try:
+                marks.mark("host_prep")
+                prepared = prepare(op, *args)
+                if prepared.verdict is not None:
+                    # known on the host (malformed wire, a cached
+                    # key): nothing enters the device
+                    give_back("host_verdict")
+                    return prepared.verdict
+                marks.mark("lock_wait")
+                with lock:
+                    marks.stamp_lock("acquired", marks.mark("launch_head"))
+                    try:
+                        handle = device.launch_dispatch(prepared)
+                        give_back("launched")
+                        return handle.result()
+                    finally:
+                        marks.stamp_lock("released")
+            finally:
+                give_back("error")
+
+        locked = whole if prepare is None else in_turn
 
         try:
             result = self.breaker.call(locked)
@@ -690,10 +733,11 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
         oracle = PureBls12381()
         msg = b"teku-tpu reprobe"
         sig = oracle.sign(1, msg)
-        # ONE read of the (provider, lock) pair — two property reads
-        # could straddle a reshape swap and dispatch on the new
-        # provider while holding the OLD pair's lock
-        device, lock = guarded._serving
+        # ONE read of the serving triple — two property reads could
+        # straddle a reshape swap and dispatch on the new provider
+        # while holding the OLD lock.  The verb runs whole under the
+        # lock, with no host half ahead of it, so it takes no turn
+        device, lock, _turn = guarded._serving
         with lock:                     # same orphan-thread rule
             ok = device.batch_verify([([_PROBE_PK], msg, sig)])
         if not ok:

@@ -74,14 +74,17 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 #                   (`to_thread` pool wait, thread start)
 #   prep_wait       blocked on the guarded provider's turn to pack:
 #                   host halves run one at a time (two at once convoy
-#                   on the interpreter lock); absent where the
-#                   provider has no host half
+#                   on the interpreter lock), and a dispatch keeps the
+#                   turn through `lock_wait` and its launches, so the
+#                   wait holds the other worker's launches too; absent
+#                   where the provider has no host half
 #   host_prep       wire parse, key lookup, array packing: the
 #                   provider's host half, packing alone, off the
 #                   device-entry lock (a second, short one under the
 #                   lock where a key had to be validated or the H(m)
 #                   arena looked up)
-#   lock_wait       blocked on the serving pair's device-entry lock
+#   lock_wait       blocked on the serving triple's device-entry lock,
+#                   holding the turn
 #   launch_head     the lock is held, no program called yet: the
 #                   provider's bookkeeping and ledger record (a second
 #                   piece after the H(m) arena's plan, under the lock)

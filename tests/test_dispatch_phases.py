@@ -51,16 +51,31 @@ def stage_fake_second(seconds):
 PROGRAMS = ["stage_fake_first", "stage_fake_second"]
 
 
+class _Late:
+    """Verdict lanes handed over `seconds` after the sync asks for them,
+    as a real handle's sync waits out programs still on the device."""
+
+    def __init__(self, lanes, seconds):
+        self.lanes = lanes
+        self.seconds = seconds
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.seconds)
+        return self.lanes
+
+
 class PhasedDevice:
     """Packs for `hold_s / 2` in its host half and launches two
     programs of `hold_s / 2` each in its device half (under the guard's
-    lock), after its bookkeeping as the provider's `_launch` does it; a
-    task whose message starts with b"bad" makes its batch false."""
+    lock), after its bookkeeping as the provider's `_launch` does it;
+    its sync then waits `sync_s`.  A task whose message starts with
+    b"bad" makes its batch false."""
 
     name = "phased-fake"
 
-    def __init__(self, hold_s: float = 0.0):
+    def __init__(self, hold_s: float = 0.0, sync_s: float = 0.0):
         self.hold_s = hold_s
+        self.sync_s = sync_s
 
     def prepare_dispatch(self, op, *args):
         tracing.current_marks().mark("host_prep")
@@ -84,8 +99,10 @@ class PhasedDevice:
             tracing.launched(program.__name__, program, self.hold_s / 2)
         rec["compile"] = {"outcome": "cache_hit", "enqueue_s": 0.0}
         ok = not any(msg.startswith(b"bad") for _pks, msg, _sig in triples)
+        lanes = np.ones(n, dtype=bool)
         return _DispatchHandle(
-            np.bool_(ok), np.ones(n, dtype=bool), n, traces,
+            np.bool_(ok), _Late(lanes, self.sync_s) if self.sync_s else lanes,
+            n, traces,
             shape=f"{n}x1", path="vpu", t_enq_end=time.perf_counter(),
             rec=rec, marks=marks)
 
@@ -289,12 +306,14 @@ def test_span_tree_names_the_whole_dispatch():
 
 def test_second_worker_waits_out_the_first_ones_hold():
     """Two workers, two batches at once: they pack one after the
-    other (`prep_wait`), then the second one's `lock_wait` runs from
-    its host half's end to the first one's release of the lock."""
+    other (`prep_wait`), the second once the first has launched, then
+    the second one's `lock_wait` runs from its host half's end to the
+    first one's release of the lock, while the first one's sync waits
+    for the device."""
     hold = 0.12
     verdicts, _t, records = _serve(
-        _guarded(PhasedDevice(hold)), [b"a0", b"a1", b"b0", b"b1"],
-        num_workers=2, max_batch_size=2)
+        _guarded(PhasedDevice(0.02, sync_s=hold)),
+        [b"a0", b"a1", b"b0", b"b1"], num_workers=2, max_batch_size=2)
     assert verdicts == [True] * 4
     assert len(records) == 2
     first, second = sorted(records, key=lambda r: r["lock"]["acquired"])
@@ -335,9 +354,9 @@ class RecordingDevice(PhasedDevice):
 
 def test_two_workers_pack_in_turn_and_say_how_long_they_waited():
     """Two workers drain a burst together: the host halves never
-    overlap, the second one's `prep_wait` is the first one's packing,
-    its own `host_prep` is its packing alone, and both records still
-    tile first mark to last."""
+    overlap, the second one's `prep_wait` is the first one's packing
+    and launches, its own `host_prep` is its packing alone, and both
+    records still tile first mark to last."""
     hold = 0.4                       # a host half sleeps 0.2 s
     device = RecordingDevice(hold)
     verdicts, _t, records = _serve(
@@ -353,11 +372,12 @@ def test_two_workers_pack_in_turn_and_say_how_long_they_waited():
         by_name.append({n: (t, s) for n, t, s in rec["phases"]})
     first, second = sorted(by_name, key=lambda ph: ph["host_prep"][0])
     # the first found the turn free; the second waited out the first's
-    # packing (both began within the hand-over of each other)
+    # packing and its launches, which end where its sync begins (both
+    # began within the hand-over of each other)
     assert first["prep_wait"][1] < 0.05
     assert second["prep_wait"][1] == pytest.approx(
-        sum(first["host_prep"]) - second["prep_wait"][0], abs=0.01)
-    assert second["prep_wait"][1] > hold / 2 - 0.1
+        sum(first["device_enqueue"]) - second["prep_wait"][0], abs=0.01)
+    assert second["prep_wait"][1] > hold / 2 + hold - 0.1
     # `host_prep` is packing alone: the sleep, not the sleep twice over
     for phases in (first, second):
         assert hold / 2 <= phases["host_prep"][1] < hold / 2 + 0.1

@@ -363,7 +363,8 @@ def test_guarded_key_check_takes_the_lock_only_for_a_miss(jax_impl):
     def check(pk):
         def call(guarded):
             lock = Held()
-            guarded._serving = (guarded.device, lock)
+            device, _lock, turn = guarded._serving
+            guarded._serving = (device, lock, turn)
             return guarded.public_key_is_valid(pk), lock.n
         return _guarded_records(jax_impl, call)[0]
 

@@ -319,6 +319,66 @@ def test_dispatch_prep_family_label_contract():
         assert isinstance(metrics[fam], Counter), fam
 
 
+def test_prep_turn_family_label_contract():
+    """`bls_prep_turn_total` carries exactly one `released` label from
+    the CLOSED set of ways a guarded dispatch with a host half gives the
+    packers' turn back (after its launches, on a host verdict, on a
+    raise), one series each; a provider without a host half moves
+    none."""
+    from teku_tpu.crypto.bls import loader
+    from teku_tpu.crypto.bls.spi import PreparedDispatch, ResolvedHandle
+    from teku_tpu.infra.metrics import GLOBAL_REGISTRY
+    from teku_tpu.infra.supervisor import CircuitBreaker
+
+    vocab = ("launched", "host_verdict", "error")
+
+    class Halves:
+        name = "halves"
+
+        def prepare_dispatch(self, op, triples):
+            message = triples[0][1]
+            if message == b"raise":
+                raise RuntimeError("host half failed")
+            return PreparedDispatch(False if message == b"host" else None)
+
+        def launch_dispatch(self, prepared):
+            return ResolvedHandle(True)
+
+        def batch_verify(self, triples):    # the halves go around it
+            raise AssertionError("the guard bypassed the two halves")
+
+    class Whole:
+        name = "whole"
+
+        def batch_verify(self, triples):
+            return True
+
+    class Oracle:
+        def batch_verify(self, triples):
+            return True
+
+    reg = MetricsRegistry()
+    for device, messages in ((Halves(), (b"launch", b"host", b"raise")),
+                             (Whole(), (b"whole",))):
+        breaker = CircuitBreaker(failure_threshold=3, deadline_s=10.0,
+                                 cooldown_s=60.0, name="lint_turn",
+                                 registry=reg)
+        guarded = loader.GuardedBls12381(device, breaker, oracle=Oracle(),
+                                         registry=reg)
+        for message in messages:
+            guarded.batch_verify([([b"pk"], message, b"sig")])
+    fam = reg.metrics()["bls_prep_turn_total"]
+    assert isinstance(fam, LabeledCounter)
+    assert tuple(fam.labelnames) == ("released",)
+    assert {key: child.value for key, child in fam._items()} == {
+        (released,): 1 for released in vocab}
+    assert "bls_prep_turn_total" in parse_exposition(reg.expose())
+    # any series the global registry already holds stays inside the set
+    glob = GLOBAL_REGISTRY.metrics().get("bls_prep_turn_total")
+    for key, _child in (glob._items() if glob is not None else ()):
+        assert key[0] in vocab, key
+
+
 def test_key_slot_family_label_contract():
     """The key axis's two counters carry exactly one `kmax` label from
     the CLOSED power-of-two vocabulary `shapeset.kmax_bucket` emits
