@@ -57,9 +57,10 @@ CUTS = [
     f"batches are {SERVICE_BATCH} tasks (the batcher's size), not 256: "
     "the 6-task straggler dispatch would compile a shape of its own",
     "(c) 488-key aggregates and (d) the 512-key sync-committee verify "
-    "are not served: kmax 512 is in no warm profile, so their first "
-    "dispatch compiles under the 30 s dispatch deadline and the oracle "
-    "answers it by design — outside what a cold run can afford",
+    "are not served: the smoke's supervisor is given no key bucket "
+    "(`cli node` gives its network's, and then warms kmax 512), so "
+    "their first dispatch would compile under the 30 s dispatch "
+    "deadline — outside what a cold run can afford",
 ]
 RUN_BUDGET_S = 1150          # both boots; the whole run must end in 1200
 MESH4_BUDGET_S = 1750        # --mesh4 compiles one-chip AND mesh programs

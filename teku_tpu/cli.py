@@ -191,7 +191,7 @@ def _configure_kernel(args, yaml_cfg):
 
 
 def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
-                   mont_path=None, mesh=None):
+                   mont_path=None, mesh=None, key_bucket=None):
     """Choose the BLS bring-up shape BEFORE any service starts.
 
     ``auto`` (the default) and ``supervised`` boot the node immediately
@@ -200,14 +200,17 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     patience instead of a 120 s probe that a minutes-long cold compile
     can never beat, and on READY the facade hot-swaps.
     ``jax`` keeps the reference-style hard preflight (Teku.java:74);
-    ``pure`` opts out.  Returns (name, supervisor-or-None)."""
+    ``pure`` opts out.  `key_bucket`: the aggregates' key bucket the
+    supervisor warms (`_key_bucket`).  Returns (name,
+    supervisor-or-None)."""
     from .crypto.bls import loader
     choice = layered_value("bls-impl", getattr(args, "bls_impl", None),
                            yaml_cfg, "auto")
     if choice in ("auto", "supervised") and supervise:
         loader.configure("supervised")      # oracle serves from slot 0
         supervisor = loader.make_supervisor(mont_path=mont_path,
-                                            mesh=mesh)
+                                            mesh=mesh,
+                                            key_bucket=key_bucket)
         print("BLS implementation: pure (supervised device bring-up "
               "in background)")
         return "supervised", supervisor
@@ -220,6 +223,17 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     where = "" if name == "pure" else f" on {loader.device_label()}"
     print(f"BLS implementation: {name}{where}")
     return name, None
+
+
+def _key_bucket(spec, state) -> int:
+    """The `kmax` bucket of the largest aggregate this node verifies,
+    from its preset and its state's active validators
+    (`shapeset.aggregate_key_bucket`; mainnet at ~1M validators: 512)."""
+    from .ops import shapeset
+    from .spec.helpers import get_active_validator_indices
+    epoch = state.slot // spec.config.SLOTS_PER_EPOCH
+    active = len(get_active_validator_indices(state, epoch))
+    return shapeset.aggregate_key_bucket(spec.config, active)
 
 
 def cmd_node(args) -> int:
@@ -242,8 +256,6 @@ def cmd_node(args) -> int:
     from .infra import flightrecorder
     flightrecorder.install_crash_hooks()
     mont_path, mesh = _configure_kernel(args, yaml_cfg)
-    _, bls_supervisor = _configure_bls(args, yaml_cfg,
-                                       mont_path=mont_path, mesh=mesh)
     network = layered_value("network", args.network, yaml_cfg, "minimal")
     port = int(layered_value("p2p-port", args.p2p_port, yaml_cfg, 0, int))
     rest_port = int(layered_value("rest-port", args.rest_port, yaml_cfg,
@@ -306,6 +318,11 @@ def cmd_node(args) -> int:
         genesis_time = genesis_time_cfg or int(time.time())
         genesis_state, sks = interop_genesis(spec.config, total_interop,
                                              genesis_time)
+    # the supervisor warms the key bucket of the aggregates this state's
+    # committees and its preset's sync committee send
+    _, bls_supervisor = _configure_bls(
+        args, yaml_cfg, mont_path=mont_path, mesh=mesh,
+        key_bucket=_key_bucket(spec, genesis_state))
 
     async def run():
         from .infra.events import FinalizedCheckpointChannel
@@ -1131,8 +1148,11 @@ def cmd_precompile(args) -> int:
     impl = JaxBls12381(max_batch=max_batch,
                        min_bucket=min_bucket, mesh=mesh_obj)
     V.staged_jits()
+    # a mainnet-preset node's aggregates: the bucket its supervisor
+    # warms (`_key_bucket`), loaded from here at boot
     programs = list(shapeset.enumerate_programs(
         max_batch=max_batch, min_bucket=impl.min_bucket,
+        key_bucket=shapeset.SERVICE_KEY_BUCKET,
         h2c_min_bucket=impl._h2c_min_bucket,
         group_cap=impl._group_cap, mesh=mesh_obj))
     print(f"precompile: {len(programs)} program(s) -> "

@@ -32,7 +32,7 @@ import logging
 import os
 import threading
 import time
-from typing import Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 from . import get_implementation, reset_implementation, set_implementation
 from ...infra import aotstore, compilecache, faults, tracing
@@ -414,47 +414,121 @@ class GuardedBls12381(BLS12381):
         return ok
 
 
-def _warmup_batches(impl, max_batch: int) -> None:
+# every warm dispatch, by profile (`shapeset.warmup_profiles`' names: x1,
+# x<max_batch>, x<max_batch>dup8, aggregate, aggregate_forged; five a
+# process) and its key bucket
+_M_WARMUP = GLOBAL_REGISTRY.labeled_counter(
+    "bls_warmup_dispatches_total",
+    "warm-up dispatches at bring-up and reshape, by warm profile and "
+    "key bucket (kmax)",
+    labelnames=("profile", "kmax"))
+
+# the aggregate profile's signers: secret keys 1..16 (1 is the probe's
+# key), so the keys the provider resolves fit the probe's own
+# pubkey-validation bucket
+_WARM_SIGNERS = 16
+
+
+def _warm_dispatch(impl, name: str, kmax: int, batch, want: bool) -> None:
+    """One warm profile's dispatch, as a `warmup` span; a verdict other
+    than `want` vetoes the device."""
+    from ...ops import shapeset
+    shape = shapeset.shape_label(
+        shapeset.lane_bucket(len(batch), getattr(impl, "min_bucket", 1)),
+        kmax)
+    with tracing.warmup(name, shape):
+        got = impl.batch_verify(batch)
+    _M_WARMUP.labels(profile=name, kmax=str(kmax)).inc()
+    if got is not want:
+        # a wrong verdict on a known answer is a device we must never
+        # install
+        raise WarmupVetoError(
+            f"warmup batch ({name}, {shape}) read {got}, want {want}")
+
+
+def _aggregate_batches(oracle, tasks: int, kmax: int):
+    """The aggregate profiles' two drains: `tasks`
+    SignedAggregateAndProof-shaped tasks, each a selection proof (one
+    key, a message every task shares), the aggregator's own signature
+    (one key, its own message) and an aggregate of `kmax` keys (its
+    committee's message), signed by the oracle (an aggregate of keys
+    k_1..k_K over m is [sum k_i] H(m)); then the same tasks with fresh
+    messages of their own and the last aggregate signed by other keys,
+    which has to read False."""
+    from .constants import R
+    sks = list(range(1, _WARM_SIGNERS + 1))
+    pks = [oracle.secret_key_to_public_key(sk) for sk in sks]
+    shared = b"teku-tpu warmup selection"
+    first, later = [], []
+    for i in range(tasks):
+        sk, pk = sks[i % _WARM_SIGNERS], pks[i % _WARM_SIGNERS]
+        group = b"teku-tpu warmup aggregate %d" % i
+        signers = [(i + q) % _WARM_SIGNERS for q in range(kmax)]
+        agg_sk = sum(sks[j] for j in signers) % R
+        # a well-formed signature under other keys: only the pairing
+        # can tell
+        forged_sk = agg_sk % (R - 1) + 1 if i == tasks - 1 else agg_sk
+        selection = ([pk], shared, oracle.sign(sk, shared))
+        for out, own, a_sk in (
+                (first, b"teku-tpu warmup envelope %d" % i, agg_sk),
+                (later, b"teku-tpu warmup envelope %d'" % i, forged_sk)):
+            out += [selection, ([pk], own, oracle.sign(sk, own)),
+                    ([pks[j] for j in signers], group,
+                     oracle.sign(a_sk, group))]
+    return first, later
+
+
+def _warmup_batches(impl, max_batch: int,
+                    key_bucket: Optional[int] = None) -> List[int]:
     """Compile the verify pipeline OFF the gossip path (the first real
     batch used to pay a multi-minute staged compile in the hot path),
-    at the two batch shapes the node
-    dispatches most: the min_bucket pad and the primary bucket.
-    Other (pow-2 × kmax) shapes still compile lazily — a cold compile
-    that overruns the breaker deadline serves that call from the
-    oracle while the orphaned dispatch thread finishes populating the
-    jit cache, so the shape warms itself.  Shared by supervisor
-    WARMING and the mesh self-healer's reshape warm (the shrunken
-    sharded shape set must compile off-path too).  Raises
-    WarmupVetoError on a wrong verdict — a device that gets a KNOWN
-    answer wrong must never serve."""
+    one dispatch a profile of ``shapeset.warmup_profiles``: the
+    min_bucket pad, the primary bucket all-unique and duplicated and,
+    given the node's `key_bucket`, the aggregate-and-proof drain at
+    that many keys a lane (a slot's first, then a later one with a
+    forged aggregate).  Other (pow-2 × kmax) shapes still compile
+    lazily — a cold compile that overruns the breaker deadline serves
+    that call from the oracle while the orphaned dispatch thread
+    finishes populating the jit cache, so the shape warms itself.
+    Shared by supervisor WARMING and the mesh self-healer's reshape
+    warm (the shrunken sharded shape set must compile off-path too).
+    Raises WarmupVetoError on a wrong verdict — a device that gets a
+    KNOWN answer wrong must never serve.  Returns the key buckets
+    warmed."""
+    from ...ops import shapeset
     oracle = PureBls12381()
     msg = b"teku-tpu warmup"
-    sig = oracle.sign(1, msg)
-    triple = ([_PROBE_PK], msg, sig)
-    if not impl.batch_verify([triple]):
-        raise WarmupVetoError("warmup batch (x1) did not verify")
-    # primary bucket with DISTINCT messages: the dedup-aware
-    # pipeline specializes on the unique-message bucket, and
-    # all-unique (fresh gossip, dup factor 1) is the worst-case
-    # shape — warm that first
-    batch = [([_PROBE_PK], m, oracle.sign(1, m))
-             for m in (b"teku-tpu warmup %d" % i
-                       for i in range(max_batch))]
-    if not impl.batch_verify(batch):
-        # a wrong verdict on a known-good signature is a device
-        # we must never install
-        raise WarmupVetoError(
-            f"warmup batch (x{max_batch}) did not verify")
-    if max_batch >= 8:
-        # committee-duplicated shape (dup factor 8, the common
-        # gossip mix): the grouped pipeline specializes on the
-        # (unique, group) bucket pair, and the first REAL committee
-        # batch must not pay that compile inside a breaker-guarded
-        # live dispatch
-        dup = [batch[i // 8] for i in range(max_batch)]
-        if not impl.batch_verify(dup):
-            raise WarmupVetoError(
-                f"warmup batch (x{max_batch}, dup 8) did not verify")
+    unique = [([_PROBE_PK], m, oracle.sign(1, m))
+              for m in (b"teku-tpu warmup %d" % i
+                        for i in range(max_batch))]
+    profiles = shapeset.warmup_profiles(max_batch, key_bucket)
+    later = None        # `aggregate_forged`'s drain, made beside the first
+    for name, lane_groups, _missing, kmax in profiles:
+        if name == "x1":
+            _warm_dispatch(impl, name, kmax,
+                           [([_PROBE_PK], msg, oracle.sign(1, msg))], True)
+        elif name == "aggregate":
+            first, later = _aggregate_batches(oracle, lane_groups[0], kmax)
+            _warm_dispatch(impl, name, kmax, first, True)
+        elif name == "aggregate_forged":
+            _warm_dispatch(impl, name, kmax, later, False)
+        elif lane_groups[0] == 1:
+            # the primary bucket with DISTINCT messages: the
+            # dedup-aware pipeline specializes on the unique-message
+            # bucket, and all-unique (fresh gossip, dup factor 1) is
+            # the worst-case shape — warm that first
+            _warm_dispatch(impl, name, kmax, unique, True)
+        else:
+            # committee-duplicated (dup factor 8, the common gossip
+            # mix): the grouped pipeline specializes on the (unique,
+            # group) bucket pair, and the first REAL committee batch
+            # must not pay that compile inside a breaker-guarded live
+            # dispatch
+            dup = lane_groups[0]
+            _warm_dispatch(impl, name, kmax,
+                           [unique[i // dup] for i in range(max_batch)],
+                           True)
+    return sorted({kmax for *_, kmax in profiles})
 
 
 # --------------------------------------------------------------------------
@@ -467,12 +541,14 @@ def make_mesh_healer(guarded: GuardedBls12381,
                      supervisor=None,
                      registry: MetricsRegistry = GLOBAL_REGISTRY,
                      warm: bool = True,
+                     key_bucket: Optional[int] = None,
                      **healer_kw):
     """Wire shard-level fault isolation around a mesh-backed guarded
     provider: per-device health ledger, eject + reshape onto the
     largest surviving pow-2 subset, AOT warm of the shrunken shape
     set, atomic ``swap_device`` install, background readmit.
 
+    The reshape warm covers the node's `key_bucket` as the boot's does.
     Returns the ``MeshHealer`` (also assigned to ``guarded.healer``),
     or None when the serving provider is not mesh-backed or
     ``TEKU_TPU_MESH_SELF_HEAL=0`` opts out."""
@@ -534,7 +610,7 @@ def make_mesh_healer(guarded: GuardedBls12381,
         aot_before = aotstore.stats()
         t0 = time.monotonic()
         try:
-            _warmup_batches(new_impl, wb)
+            _warmup_batches(new_impl, wb, key_bucket)
         except WarmupVetoError as exc:
             raise selfheal.InstallVetoError(str(exc)) from exc
         moved = compilecache.delta(cc_before)
@@ -628,10 +704,17 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
                     breaker: Optional[CircuitBreaker] = None,
                     warm: bool = True, mont_path: Optional[str] = None,
                     mesh: Optional[str] = None,
+                    key_bucket: Optional[int] = None,
                     **supervisor_kw) -> BackendSupervisor:
     """Build the production BackendSupervisor: boot-on-oracle now,
     background JAX bring-up, breaker-guarded hot-swap at READY for both
     BLS (`set_implementation`) and KZG (`crypto/kzg.py:set_backend`).
+
+    `key_bucket` is the `kmax` bucket of the largest aggregate the
+    node's network sends (`shapeset.aggregate_key_bucket`, which `cli
+    node` derives from its preset and state): WARMING dispatches the
+    aggregate profile at it, so the breaker never meets that bucket's
+    cold compile; None warms one key a lane only.
 
     The node owns the returned service's lifecycle
     (`node/node.py:do_start`); nothing here blocks.
@@ -664,9 +747,11 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
 
     def warmup(backend):
         if not warm:
-            return
+            return None
         impl, _ = backend
-        _warmup_batches(impl, max_batch)
+        # the readiness snapshot's `warmup_cache` names them
+        return {"key_buckets": _warmup_batches(impl, max_batch,
+                                               key_bucket)}
 
     def install(backend):
         impl, device = backend
@@ -695,6 +780,7 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
                 healer = make_mesh_healer(
                     guarded, breaker, max_batch=max_batch,
                     min_bucket=min_bucket, registry=registry,
+                    key_bucket=key_bucket,
                     supervisor=(supervisor_box[0] if supervisor_box
                                 else None))
                 if healer is not None:

@@ -542,6 +542,9 @@ class BackendSupervisor(Service):
             # an overrun or a raising warmup installs anyway, with its
             # compiles still pending: the snapshot says which happened
             finished = False
+            # what the warmup says of itself (the loader's: the key
+            # buckets it warmed), merged into the snapshot
+            warmed = None
             try:
                 # bounded: WARMING must not become the one phase that
                 # can wedge forever (probing retries, READY has the
@@ -549,7 +552,7 @@ class BackendSupervisor(Service):
                 # compiling and we install anyway — a still-wedged
                 # device then trips the breaker, whose reprobe cycle
                 # owns recovery from there
-                await asyncio.wait_for(
+                warmed = await asyncio.wait_for(
                     self._in_daemon_thread(
                         lambda: self._warmup(backend),
                         f"{self.name}-warmup"),
@@ -586,6 +589,8 @@ class BackendSupervisor(Service):
                 "kernel_compiles": moved["kernel_compiles"],
                 "finished": finished,
                 "s": round(time.monotonic() - warm_t0, 1)}
+            if isinstance(warmed, dict):
+                self.warmup_cache.update(warmed)
             flightrecorder.record("warmup_cache", supervisor=self.name,
                                   **self.warmup_cache)
             _LOG.info(

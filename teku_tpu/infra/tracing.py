@@ -96,11 +96,13 @@ from .metrics import GLOBAL_REGISTRY, LATENCY_BUCKETS_S
 #   settle          the verified batch's futures resolved, to the last
 # `dispatch` is the service's span around the whole thread round-trip
 # (the parent of thread_hop .. return_hop in the span tree);
-# `oracle_execute` is a guarded call the oracle served for the device.
+# `oracle_execute` is a guarded call the oracle served for the device;
+# `warmup` is one warm profile's dispatch at bring-up, before the
+# provider serves (`warmup()`), in no verification's trace.
 STAGES = ("queue_wait", "assembly", "dispatch", "thread_hop",
           "prep_wait", "host_prep", "lock_wait", "launch_head",
           "device_enqueue", "device_sync", "return_hop", "settle",
-          "oracle_execute", "complete")
+          "oracle_execute", "complete", "warmup")
 
 # phases that begin and end on ONE thread: entered as profiler
 # annotations too (a hop crosses threads and cannot be)
@@ -324,14 +326,14 @@ def span(stage: str, traces: Optional[Sequence[Trace]] = None):
 # Dispatch marks: one dispatch's life, gap-free
 # --------------------------------------------------------------------------
 
-def _annotation(name: str):
-    """An entered `jax.profiler.TraceAnnotation`, or None in a process
-    that never imported JAX (no device, no profiler: this module must
-    import without it)."""
+def _annotation(name: str, **meta):
+    """An entered `jax.profiler.TraceAnnotation` (`meta` its
+    arguments), or None in a process that never imported JAX (no
+    device, no profiler: this module must import without it)."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    ann = jax.profiler.TraceAnnotation(name)
+    ann = jax.profiler.TraceAnnotation(name, **meta)
     ann.__enter__()
     return ann
 
@@ -510,6 +512,25 @@ def dispatch_marks(first: str):
     finally:
         _MARKS.reset(token)
         marks.close()
+
+
+@contextmanager
+def warmup(profile: str, shape: str):
+    """One warm profile's dispatch at bring-up: the `warmup` stage of
+    the stage histogram and a profiler annotation `warmup` carrying the
+    profile's name and its `{lanes}x{kmax}` shape.  In no root trace:
+    a warm-up is no verification's latency."""
+    if not _enabled:
+        yield
+        return
+    ann = _annotation("warmup", profile=profile, shape=shape)
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        record_stage("warmup", time.perf_counter() - t0, (), t0=t0)
 
 
 # --------------------------------------------------------------------------

@@ -35,6 +35,10 @@ PK_VALIDATE_FLOOR = 16          # pubkey-validation bucket floor
 # make_mesh_healer) — what a default `cli node` boot warms
 SERVICE_MAX_BATCH = 256
 SERVICE_MIN_BUCKET = 16
+# the key bucket a mainnet-preset node warms for its aggregates
+# (`aggregate_key_bucket` of the mainnet preset): what `cli precompile`
+# builds and the doctor's coverage oracle assumes
+SERVICE_KEY_BUCKET = 512
 
 
 # --------------------------------------------------------------------------
@@ -49,6 +53,29 @@ def lane_bucket(n: int, min_bucket: int) -> int:
 def kmax_bucket(max_keys: int) -> int:
     """Padded keys-per-lane width (the `kmax` shape axis)."""
     return next_pow2(max_keys)
+
+
+def aggregate_key_bucket(cfg, active_validators: Optional[int] = None
+                         ) -> int:
+    """The `kmax` bucket of the largest aggregate a node of this
+    preset verifies: a block's `SyncAggregate` (`SYNC_COMMITTEE_SIZE`
+    keys) and, where the node knows how many validators are active, an
+    attestation committee's aggregate on the `beacon_aggregate_and_proof`
+    topic (the spec's `get_committee_count_per_slot` split of the active
+    set; the largest committee is the ceiling).  `cfg` is a preset
+    (`spec.config.SpecConfig`).  Mainnet: 512 (a committee of 489 at
+    1,000,000 validators); minimal: 32."""
+    largest = cfg.SYNC_COMMITTEE_SIZE
+    if active_validators:
+        per_slot = max(1, min(
+            cfg.MAX_COMMITTEES_PER_SLOT,
+            active_validators // cfg.SLOTS_PER_EPOCH
+            // cfg.TARGET_COMMITTEE_SIZE))
+        committee = -(-active_validators
+                      // (cfg.SLOTS_PER_EPOCH * per_slot))
+        largest = max(largest, min(committee,
+                                   cfg.MAX_VALIDATORS_PER_COMMITTEE))
+    return kmax_bucket(largest)
 
 
 def _row_size(g) -> int:
@@ -166,20 +193,34 @@ def batch_plan(lane_groups: Sequence[int], *, min_bucket: int,
 # The warmup batch profiles (mirrors loader._warmup_batches)
 # --------------------------------------------------------------------------
 
-def warmup_profiles(max_batch: int) -> List[Tuple[str, List[int],
-                                                  Optional[int]]]:
-    """The (name, lane_groups, h2c_missing) profiles supervisor
+def warmup_profiles(max_batch: int, key_bucket: Optional[int] = None
+                    ) -> List[Tuple[str, List[int], Optional[int], int]]:
+    """The (name, lane_groups, h2c_missing, kmax) profiles supervisor
     WARMING and the selfheal reshape warm dispatch, in order: the x1
-    probe shape, the all-unique primary bucket, and (>= 8 lanes) the
+    probe shape, the all-unique primary bucket, (>= 8 lanes) the
     committee-duplicated shape whose messages the all-unique batch
-    already put in the H(m) arena (zero h2c)."""
-    profiles: List[Tuple[str, List[int], Optional[int]]] = [
-        ("x1", [1], None),
-        (f"x{max_batch}", [1] * max_batch, None),
+    already put in the H(m) arena (zero h2c) and, given a `key_bucket`,
+    the aggregate-and-proof drain twice.  It is `max_batch` lanes in
+    thirds, a task being a selection proof (1 key, one message all tasks
+    share), the aggregator's signature (1 key, a message of its own) and
+    the aggregate (`key_bucket` keys, its committee's message): first a
+    slot's first drain, every message fresh; then a later drain
+    (`aggregate_forged`, one aggregate forged), whose slot root and
+    committee messages the first put in the arena, so only the
+    aggregators' own messages are hashed."""
+    profiles: List[Tuple[str, List[int], Optional[int], int]] = [
+        ("x1", [1], None, 1),
+        (f"x{max_batch}", [1] * max_batch, None, 1),
     ]
     if max_batch >= 8:
         profiles.append(
-            (f"x{max_batch}dup8", [8] * (max_batch // 8), 0))
+            (f"x{max_batch}dup8", [8] * (max_batch // 8), 0, 1))
+    if key_bucket is not None and max_batch >= 3:
+        tasks = max_batch // 3
+        groups = [tasks] + [1] * (2 * tasks)
+        kmax = kmax_bucket(key_bucket)
+        profiles += [("aggregate", groups, None, kmax),
+                     ("aggregate_forged", groups, tasks, kmax)]
     return profiles
 
 
@@ -187,24 +228,28 @@ def serving_shapes(max_batch: int = SERVICE_MAX_BATCH,
                    min_bucket: int = SERVICE_MIN_BUCKET,
                    mesh_devices: int = 0,
                    h2c_min_bucket: int = H2C_MIN_BUCKET_DEFAULT,
-                   group_cap: int = GROUP_CAP_DEFAULT) -> set:
+                   group_cap: int = GROUP_CAP_DEFAULT,
+                   key_bucket: Optional[int] = None) -> set:
     """The ledger `shape` strings ``cli precompile`` covers for one
     serving config — the doctor's cold_compile_on_hot_path coverage
     oracle.  Includes every duplication profile from all-unique down
     to fully-duplicated at each pow-2 batch size up to max_batch (the
-    warmup profiles are a subset)."""
+    warmup profiles are a subset), at one key a lane and, given a
+    `key_bucket`, at that many."""
     shapes = set()
+    kmaxes = (1,) if key_bucket is None else (1, key_bucket)
     size = 1
     while size <= next_pow2(max_batch):
         dup = 1
         while dup <= size:
             groups = [dup] * (size // dup)
             if groups:
-                plan = batch_plan(
-                    groups, min_bucket=min_bucket,
-                    h2c_min_bucket=h2c_min_bucket,
-                    group_cap=group_cap, mesh_devices=mesh_devices)
-                shapes.add(plan["shape"])
+                for kmax in kmaxes:
+                    plan = batch_plan(
+                        groups, min_bucket=min_bucket, kmax=kmax,
+                        h2c_min_bucket=h2c_min_bucket,
+                        group_cap=group_cap, mesh_devices=mesh_devices)
+                    shapes.add(plan["shape"])
             dup *= 2
         size *= 2
     return shapes
@@ -221,7 +266,7 @@ def _sds(shape, dtype):
 
 def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
                        min_bucket: int = SERVICE_MIN_BUCKET,
-                       kmax: int = 1,
+                       key_bucket: Optional[int] = None,
                        h2c_min_bucket: int = H2C_MIN_BUCKET_DEFAULT,
                        group_cap: int = GROUP_CAP_DEFAULT,
                        mesh: Optional[object] = None,
@@ -234,7 +279,9 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
     ``ops/verify.py``/``teku_tpu/parallel`` register with the AOT
     store.  ``mesh`` is a live ``jax.sharding.Mesh`` (or None for
     single-device); mesh programs additionally need the gather
-    scatter program and the sharded kernel itself.
+    scatter program and the sharded kernel itself.  `key_bucket` adds
+    the aggregate profile (``warmup_profiles``) at that many keys a
+    lane.
     """
     import jax
     import numpy as np
@@ -273,7 +320,8 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
     if out:
         yield out
 
-    for name, lane_groups, h2c_missing in warmup_profiles(max_batch):
+    for name, lane_groups, h2c_missing, kmax in warmup_profiles(
+            max_batch, key_bucket):
         plan = batch_plan(lane_groups, min_bucket=min_bucket,
                           kmax=kmax, h2c_min_bucket=h2c_min_bucket,
                           group_cap=group_cap,

@@ -670,12 +670,13 @@ def test_launch_head_is_a_stage_of_the_histogram_and_an_annotation():
     before = _stage_counts()["launch_head"]
     _serve(_guarded(PhasedDevice()), [b"m0"], num_workers=1)
     assert _stage_counts()["launch_head"] == before + 1
-    assert len(tracing.STAGES) == 14
+    assert len(tracing.STAGES) == 15
 
 
 def test_new_stage_names_are_declared_and_no_timeline_phase_is_new():
     assert set(SERVED) | {"oracle_execute", "dispatch", "complete",
-                          "queue_wait", "assembly"} == set(tracing.STAGES)
+                          "queue_wait", "assembly",
+                          "warmup"} == set(tracing.STAGES)
     assert tracing._ANNOTATED <= set(tracing.STAGES)
     # hops cross threads: never a profiler annotation
     assert not {"thread_hop", "return_hop"} & tracing._ANNOTATED

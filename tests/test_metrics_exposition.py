@@ -405,6 +405,44 @@ def test_key_slot_family_label_contract():
         assert 0 < child.value <= dispatched[key]
 
 
+def test_warmup_dispatch_family_label_contract():
+    """`bls_warmup_dispatches_total` carries exactly (`profile`,
+    `kmax`): the names of `shapeset.warmup_profiles` (five a process:
+    x1, x<max_batch>, x<max_batch>dup8, aggregate, aggregate_forged)
+    and the power-of-two
+    key buckets `shapeset.kmax_bucket` emits; one warm dispatch moves
+    its own series by one."""
+    import re
+
+    from teku_tpu.crypto.bls import loader
+    from teku_tpu.infra.metrics import GLOBAL_REGISTRY
+    from teku_tpu.ops import shapeset
+
+    names = re.compile(r"^(x1|x\d+|x\d+dup8|aggregate|aggregate_forged)$")
+    assert all(names.match(name) for name, *_ in
+               shapeset.warmup_profiles(256, 512))
+
+    class Device:
+        min_bucket = 16
+
+        def batch_verify(self, triples):
+            return True
+
+    fam = GLOBAL_REGISTRY.metrics()["bls_warmup_dispatches_total"]
+    assert isinstance(fam, LabeledCounter)
+    assert tuple(fam.labelnames) == ("profile", "kmax")
+    before = fam.labels(profile="x1", kmax="1").value
+    loader._warm_dispatch(Device(), "x1", 1, [([b"pk"], b"m", b"s")],
+                          True)
+    assert fam.labels(profile="x1", kmax="1").value == before + 1
+    pow2_vocab = {str(1 << i) for i in range(0, 12)}
+    for (profile, kmax), _child in fam._items():
+        assert names.match(profile) and kmax in pow2_vocab, (profile,
+                                                              kmax)
+    assert "bls_warmup_dispatches_total" in parse_exposition(
+        GLOBAL_REGISTRY.expose())
+
+
 def test_service_task_and_triple_families():
     """The batching service counts tasks, their triples and the tasks
     of several triples apart, name-prefixed like its other families

@@ -90,19 +90,19 @@ def test_batch_plan_all_unique_and_duplicated():
 
 def test_warmup_profiles_shape():
     assert shapeset.warmup_profiles(4) == [
-        ("x1", [1], None), ("x4", [1, 1, 1, 1], None)]
+        ("x1", [1], None, 1), ("x4", [1, 1, 1, 1], None, 1)]
     profiles = shapeset.warmup_profiles(256)
-    assert [name for name, _, _ in profiles] \
+    assert [name for name, *_ in profiles] \
         == ["x1", "x256", "x256dup8"]
-    name, groups, missing = profiles[2]
+    name, groups, missing, kmax = profiles[2]
     assert groups == [8] * 32
     assert missing == 0, "dup8 rides the arena the x256 warm filled"
 
 
 def test_serving_shapes_cover_warmup_profiles():
     shapes = shapeset.serving_shapes(max_batch=256, min_bucket=16)
-    for _name, groups, missing in shapeset.warmup_profiles(256):
-        plan = shapeset.batch_plan(groups, min_bucket=16,
+    for _name, groups, missing, kmax in shapeset.warmup_profiles(256):
+        plan = shapeset.batch_plan(groups, min_bucket=16, kmax=kmax,
                                    h2c_missing=missing)
         assert plan["shape"] in shapes
     assert "16x1" in shapes, "the x1 probe shape is a serving shape"
@@ -242,7 +242,8 @@ def test_enumerated_programs_are_the_launched_ones(
     impl.batch_verify(_drain(pks, lane_groups, b"drain"))
     monkeypatch.setattr(
         shapeset, "warmup_profiles",
-        lambda max_batch: [("drain", lane_groups, h2c_missing)])
+        lambda max_batch, key_bucket=None: [
+            ("drain", lane_groups, h2c_missing, 1)])
     enumerated = {
         (kernel, aotstore.shape_sig(avals))
         for kernel, avals, meta in shapeset.enumerate_programs(
@@ -269,7 +270,8 @@ def test_registry_follows_the_signature_row(monkeypatch, env, lane_groups,
     from teku_tpu.ops import limbs as fp
     impl, _pks = _cell_provider(monkeypatch, env)
     monkeypatch.setattr(shapeset, "warmup_profiles",
-                        lambda max_batch: [("drain", lane_groups, None)])
+                        lambda max_batch, key_bucket=None: [
+                            ("drain", lane_groups, None, 1)])
     avals = {meta["stage"]: avals for _k, avals, meta in
              shapeset.enumerate_programs(
                  max_batch=256, min_bucket=256,
@@ -303,7 +305,8 @@ def test_wide_batches_plan_the_same_stage_programs(monkeypatch, lanes,
     plan = shapeset.batch_plan(groups, min_bucket=16)
     assert plan["lanes"] == lanes and plan["msm_path"] == "ladder"
     monkeypatch.setattr(shapeset, "warmup_profiles",
-                        lambda max_batch: [("wide", groups, None)])
+                        lambda max_batch, key_bucket=None: [
+                            ("wide", groups, None, 1)])
     stages = [m["stage"] for _k, _a, m in shapeset.enumerate_programs(
         max_batch=4096, min_bucket=16)]
     assert stages == ["pk_validate", "h2c", "prepare", "scalars",

@@ -253,7 +253,7 @@ def test_the_cells_serving_config_enumerates_one_scalars_program():
     and the committee-duplicated warm profile's `group` (256 lanes, 8 a
     message)."""
     programs = list(shapeset.enumerate_programs(
-        max_batch=256, kmax=1, **CELL))
+        max_batch=256, **CELL))
     stages = [m["stage"] for _k, _a, m in programs]
     assert stages.count("scalars") == 1         # one lane shape
     dup = [a for _k, a, m in programs
