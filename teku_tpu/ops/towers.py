@@ -24,6 +24,8 @@ BlstBLS12381.java).  Validation: tests/test_ops_towers.py checks every op
 against the oracle.
 """
 
+import math
+
 import numpy as np
 
 import jax
@@ -98,31 +100,81 @@ def tree_unstack(t, n):
     return [jax.tree_util.tree_map(lambda x: x[i], t) for i in range(n)]
 
 
+# A pow-2 fold's loop combines no fewer pairs a step than make this
+# many points (pairs times the batch between the fold axis and the limb
+# axis).  On a v5e a G1 addition step's time grows with its width above
+# ~2,048 points and hardly shrinks below it (the step's floor): a
+# halving above it saves time, one below it only adds steps.
+FOLD_STEP_MIN_POINTS = 2048
+
+
+def _fold_schedule(n, width):
+    """(live pairs h, offset o) of each step of a pow-2 fold of width n
+    at `width` pairs a step: a round of h >= width pairs in h / width
+    steps, one a chunk [o, o + width); a narrower round in one step
+    from 0."""
+    steps = []
+    h = n // 2
+    while h:
+        steps += [(h, o) for o in range(0, h, width)] if h >= width \
+            else [(h, 0)]
+        h //= 2
+    return np.asarray(steps, dtype=np.int32).T
+
+
 def tree_fold_pairs(combine, t):
     """Reduce a pytree over its leading axis with log-depth pairwise
     `combine` rounds: round k folds lanes [0, h) with lanes [h, 2h),
     h halving each round (an odd tail lane rides along unfolded).
 
-    A pow-2 width >= 4 runs its rounds as ONE fori_loop at the fixed
-    width n/2 — the graph holds a single `combine` body instead of
-    log2(n) inlined copies (the TPU compiler pays per call site) at the
-    price of computing dead lanes in the later rounds.  Live lanes see
-    exactly the pairing of the unrolled form, so results are
-    bit-identical."""
-    n = jax.tree_util.tree_leaves(t)[0].shape[0]
+    A pow-2 width n >= 4 runs its rounds as ONE fori_loop whose body
+    combines a fixed `width` of pairs — the graph holds a single
+    `combine` body instead of log2(n) inlined copies (the TPU compiler
+    pays 0.3–0.45 s a mont_mul call site).  At width n/2 each round is
+    one step, and the later rounds compute dead pairs: (n/2)·log2(n)
+    combines for n − 1 live, 4.5× at n = 512.  Where a pair holds a
+    wide batch those dead pairs cost time, so the width halves while
+    the step stays at or above FOLD_STEP_MIN_POINTS, and a round wider
+    than the step takes one step a chunk: at (512, 256) 8 pairs a
+    step, 66 steps, 528 combines a lane.  Live lanes see exactly the
+    pairing of the unrolled form, so results are bit-identical."""
+    leaf = jax.tree_util.tree_leaves(t)[0]
+    n = leaf.shape[0]
     if n >= 4 and n & (n - 1) == 0:
         half = n // 2
+        batch = math.prod(leaf.shape[1:-1])
+        width = half
+        while width >= 2 and width // 2 * batch >= FOLD_STEP_MIN_POINTS:
+            width //= 2
+        if width == half:             # a step a round, at offset 0
+            def fold(k, t):
+                h = half >> k             # live pairs this round
+                a = jax.tree_util.tree_map(lambda x: x[:half], t)
+                b = jax.tree_util.tree_map(
+                    lambda x: lax.dynamic_slice_in_dim(x, h, half, axis=0),
+                    t)
+                s = combine(a, b)
+                return jax.tree_util.tree_map(
+                    lambda x, y: jnp.concatenate([y, x[half:]], axis=0),
+                    t, s)
 
-        def fold(k, t):
-            h = half >> k             # live pairs this round
-            a = jax.tree_util.tree_map(lambda x: x[:half], t)
-            b = jax.tree_util.tree_map(
-                lambda x: lax.dynamic_slice_in_dim(x, h, half, axis=0), t)
-            s = combine(a, b)
-            return jax.tree_util.tree_map(
-                lambda x, y: jnp.concatenate([y, x[half:]], axis=0), t, s)
+            t = lax.fori_loop(0, n.bit_length() - 1, fold, t)
+        else:
+            hs, offs = _fold_schedule(n, width)
 
-        t = lax.fori_loop(0, n.bit_length() - 1, fold, t)
+            def chunk(t, at):
+                return jax.tree_util.tree_map(
+                    lambda x: lax.dynamic_slice_in_dim(x, at, width, axis=0),
+                    t)
+
+            def fold(k, t):
+                h, o = jnp.asarray(hs)[k], jnp.asarray(offs)[k]
+                s = combine(chunk(t, o), chunk(t, o + h))
+                return jax.tree_util.tree_map(
+                    lambda x, y: lax.dynamic_update_slice_in_dim(
+                        x, y, o, axis=0), t, s)
+
+            t = lax.fori_loop(0, len(hs), fold, t)
         return jax.tree_util.tree_map(lambda x: x[0], t)
     while n > 1:
         half = n // 2
