@@ -191,7 +191,8 @@ def _configure_kernel(args, yaml_cfg):
 
 
 def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
-                   mont_path=None, mesh=None, key_bucket=None):
+                   mont_path=None, mesh=None, key_bucket=None,
+                   block_key_bucket=None):
     """Choose the BLS bring-up shape BEFORE any service starts.
 
     ``auto`` (the default) and ``supervised`` boot the node immediately
@@ -200,17 +201,18 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     patience instead of a 120 s probe that a minutes-long cold compile
     can never beat, and on READY the facade hot-swaps.
     ``jax`` keeps the reference-style hard preflight (Teku.java:74);
-    ``pure`` opts out.  `key_bucket`: the aggregates' key bucket the
-    supervisor warms (`_key_bucket`).  Returns (name,
+    ``pure`` opts out.  `key_bucket`, `block_key_bucket`: the
+    aggregates' and the blocks' key buckets the supervisor warms
+    (`_key_bucket`, `_block_key_bucket`).  Returns (name,
     supervisor-or-None)."""
     from .crypto.bls import loader
     choice = layered_value("bls-impl", getattr(args, "bls_impl", None),
                            yaml_cfg, "auto")
     if choice in ("auto", "supervised") and supervise:
         loader.configure("supervised")      # oracle serves from slot 0
-        supervisor = loader.make_supervisor(mont_path=mont_path,
-                                            mesh=mesh,
-                                            key_bucket=key_bucket)
+        supervisor = loader.make_supervisor(
+            mont_path=mont_path, mesh=mesh, key_bucket=key_bucket,
+            block_key_bucket=block_key_bucket)
         print("BLS implementation: pure (supervised device bring-up "
               "in background)")
         return "supervised", supervisor
@@ -225,15 +227,29 @@ def _configure_bls(args, yaml_cfg, *, supervise: bool = True,
     return name, None
 
 
-def _key_bucket(spec, state) -> int:
-    """The `kmax` bucket of the largest aggregate this node verifies,
-    from its preset and its state's active validators
-    (`shapeset.aggregate_key_bucket`; mainnet at ~1M validators: 512)."""
-    from .ops import shapeset
+def _active_validators(spec, state) -> int:
     from .spec.helpers import get_active_validator_indices
     epoch = state.slot // spec.config.SLOTS_PER_EPOCH
-    active = len(get_active_validator_indices(state, epoch))
-    return shapeset.aggregate_key_bucket(spec.config, active)
+    return len(get_active_validator_indices(state, epoch))
+
+
+def _key_bucket(spec, state) -> int:
+    """The `kmax` bucket of the largest aggregate this node verifies on
+    gossip, from its preset and its state's active validators
+    (`shapeset.aggregate_key_bucket`; mainnet at ~1M validators: 512)."""
+    from .ops import shapeset
+    return shapeset.aggregate_key_bucket(
+        spec.config, _active_validators(spec, state))
+
+
+def _block_key_bucket(spec, state) -> int:
+    """The `kmax` bucket of the widest signature of a block this node
+    imports, from its preset, its fork schedule and its state's active
+    validators (`shapeset.block_key_bucket`; an Electra block's
+    slot-wide attestation at ~1M validators: 32,768)."""
+    from .ops import shapeset
+    return shapeset.block_key_bucket(
+        spec.config, _active_validators(spec, state))
 
 
 def cmd_node(args) -> int:
@@ -318,11 +334,13 @@ def cmd_node(args) -> int:
         genesis_time = genesis_time_cfg or int(time.time())
         genesis_state, sks = interop_genesis(spec.config, total_interop,
                                              genesis_time)
-    # the supervisor warms the key bucket of the aggregates this state's
-    # committees and its preset's sync committee send
+    # the supervisor warms the key buckets of the aggregates this
+    # state's committees and its preset's sync committee send, and of
+    # the blocks that carry them
     _, bls_supervisor = _configure_bls(
         args, yaml_cfg, mont_path=mont_path, mesh=mesh,
-        key_bucket=_key_bucket(spec, genesis_state))
+        key_bucket=_key_bucket(spec, genesis_state),
+        block_key_bucket=_block_key_bucket(spec, genesis_state))
 
     async def run():
         from .infra.events import FinalizedCheckpointChannel
@@ -1148,11 +1166,13 @@ def cmd_precompile(args) -> int:
     impl = JaxBls12381(max_batch=max_batch,
                        min_bucket=min_bucket, mesh=mesh_obj)
     V.staged_jits()
-    # a mainnet-preset node's aggregates: the bucket its supervisor
-    # warms (`_key_bucket`), loaded from here at boot
+    # a mainnet-preset node's aggregates and blocks: the buckets its
+    # supervisor warms (`_key_bucket`, `_block_key_bucket`), loaded
+    # from here at boot
     programs = list(shapeset.enumerate_programs(
         max_batch=max_batch, min_bucket=impl.min_bucket,
         key_bucket=shapeset.SERVICE_KEY_BUCKET,
+        block_key_bucket=shapeset.SERVICE_BLOCK_KEY_BUCKET,
         h2c_min_bucket=impl._h2c_min_bucket,
         group_cap=impl._group_cap, mesh=mesh_obj))
     print(f"precompile: {len(programs)} program(s) -> "

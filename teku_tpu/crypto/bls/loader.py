@@ -415,8 +415,8 @@ class GuardedBls12381(BLS12381):
 
 
 # every warm dispatch, by profile (`shapeset.warmup_profiles`' names: x1,
-# x<max_batch>, x<max_batch>dup8, aggregate, aggregate_forged; five a
-# process) and its key bucket
+# x<max_batch>, x<max_batch>dup8, aggregate, aggregate_forged, block,
+# block_forged, block_narrow) and its key bucket
 _M_WARMUP = GLOBAL_REGISTRY.labeled_counter(
     "bls_warmup_dispatches_total",
     "warm-up dispatches at bring-up and reshape, by warm profile and "
@@ -478,15 +478,55 @@ def _aggregate_batches(oracle, tasks: int, kmax: int):
     return first, later
 
 
+def _wide_lane(oracle, kmax: int, message: bytes, forged: bool = False):
+    """An aggregate of `kmax` keys (the warm signers in turn) over
+    `message`; `forged` signs it by other keys, which has to read
+    False."""
+    from .constants import R
+    sks = range(1, _WARM_SIGNERS + 1)
+    pks = [oracle.secret_key_to_public_key(sk) for sk in sks]
+    agg_sk = sum(sks) * (kmax // _WARM_SIGNERS) + sum(
+        sks[:kmax % _WARM_SIGNERS])
+    agg_sk %= R
+    return ([pks[q % _WARM_SIGNERS] for q in range(kmax)], message,
+            oracle.sign(agg_sk % (R - 1) + 1 if forged else agg_sk,
+                        message))
+
+
+def _block_lanes(oracle, kmax: int, tag: bytes, forged: bool = False):
+    """A block's signature set as the block profiles warm it
+    (`shapeset.BLOCK_LANES` lanes, a message each): the proposer's
+    signature, the RANDAO reveal over the x1 probe's message (already
+    in the H(m) arena), an attestation of `kmax` keys (`_wide_lane`,
+    forged where asked), the other attestations and the SyncAggregate
+    of one key each."""
+    from ...ops import shapeset
+    lanes = []
+    for j in range(shapeset.BLOCK_LANES):
+        sk = j % _WARM_SIGNERS + 1
+        msg = b"teku-tpu warmup block %s lane %d" % (tag, j)
+        if j == 1:
+            msg = b"teku-tpu warmup"
+        if j == 2:
+            lanes.append(_wide_lane(oracle, kmax, msg, forged))
+        else:
+            lanes.append(([oracle.secret_key_to_public_key(sk)], msg,
+                          oracle.sign(sk, msg)))
+    return lanes
+
+
 def _warmup_batches(impl, max_batch: int,
-                    key_bucket: Optional[int] = None) -> List[int]:
+                    key_bucket: Optional[int] = None,
+                    block_key_bucket: Optional[int] = None) -> List[int]:
     """Compile the verify pipeline OFF the gossip path (the first real
     batch used to pay a multi-minute staged compile in the hot path),
     one dispatch a profile of ``shapeset.warmup_profiles``: the
     min_bucket pad, the primary bucket all-unique and duplicated and,
     given the node's `key_bucket`, the aggregate-and-proof drain at
     that many keys a lane (a slot's first, then a later one with a
-    forged aggregate).  Other (pow-2 × kmax) shapes still compile
+    forged aggregate) and, given its `block_key_bucket`, a block's
+    signature set at that bucket (then forged) and at every narrower
+    bucket a block can reach.  Other (pow-2 × kmax) shapes still compile
     lazily — a cold compile that overruns the breaker deadline serves
     that call from the oracle while the orphaned dispatch thread
     finishes populating the jit cache, so the shape warms itself.
@@ -501,8 +541,10 @@ def _warmup_batches(impl, max_batch: int,
     unique = [([_PROBE_PK], m, oracle.sign(1, m))
               for m in (b"teku-tpu warmup %d" % i
                         for i in range(max_batch))]
-    profiles = shapeset.warmup_profiles(max_batch, key_bucket)
+    profiles = shapeset.warmup_profiles(max_batch, key_bucket,
+                                        block_key_bucket)
     later = None        # `aggregate_forged`'s drain, made beside the first
+    block = None        # the `block` profile's set, which block_narrow re-uses
     for name, lane_groups, _missing, kmax in profiles:
         if name == "x1":
             _warm_dispatch(impl, name, kmax,
@@ -512,6 +554,18 @@ def _warmup_batches(impl, max_batch: int,
             _warm_dispatch(impl, name, kmax, first, True)
         elif name == "aggregate_forged":
             _warm_dispatch(impl, name, kmax, later, False)
+        elif name == "block":
+            block = _block_lanes(oracle, kmax, b"first")
+            _warm_dispatch(impl, name, kmax, block, True)
+        elif name == "block_forged":
+            _warm_dispatch(impl, name, kmax, _block_lanes(
+                oracle, kmax, b"forged", forged=True), False)
+        elif name == "block_narrow":
+            # the first block's messages, already in the H(m) arena:
+            # only the wide lane's keys change
+            narrow = _wide_lane(oracle, kmax, block[2][1])
+            _warm_dispatch(impl, name, kmax,
+                           block[:2] + [narrow] + block[3:], True)
         elif lane_groups[0] == 1:
             # the primary bucket with DISTINCT messages: the
             # dedup-aware pipeline specializes on the unique-message
@@ -542,13 +596,15 @@ def make_mesh_healer(guarded: GuardedBls12381,
                      registry: MetricsRegistry = GLOBAL_REGISTRY,
                      warm: bool = True,
                      key_bucket: Optional[int] = None,
+                     block_key_bucket: Optional[int] = None,
                      **healer_kw):
     """Wire shard-level fault isolation around a mesh-backed guarded
     provider: per-device health ledger, eject + reshape onto the
     largest surviving pow-2 subset, AOT warm of the shrunken shape
     set, atomic ``swap_device`` install, background readmit.
 
-    The reshape warm covers the node's `key_bucket` as the boot's does.
+    The reshape warm covers the node's `key_bucket` and
+    `block_key_bucket` as the boot's does.
     Returns the ``MeshHealer`` (also assigned to ``guarded.healer``),
     or None when the serving provider is not mesh-backed or
     ``TEKU_TPU_MESH_SELF_HEAL=0`` opts out."""
@@ -610,7 +666,7 @@ def make_mesh_healer(guarded: GuardedBls12381,
         aot_before = aotstore.stats()
         t0 = time.monotonic()
         try:
-            _warmup_batches(new_impl, wb, key_bucket)
+            _warmup_batches(new_impl, wb, key_bucket, block_key_bucket)
         except WarmupVetoError as exc:
             raise selfheal.InstallVetoError(str(exc)) from exc
         moved = compilecache.delta(cc_before)
@@ -705,6 +761,7 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
                     warm: bool = True, mont_path: Optional[str] = None,
                     mesh: Optional[str] = None,
                     key_bucket: Optional[int] = None,
+                    block_key_bucket: Optional[int] = None,
                     **supervisor_kw) -> BackendSupervisor:
     """Build the production BackendSupervisor: boot-on-oracle now,
     background JAX bring-up, breaker-guarded hot-swap at READY for both
@@ -714,7 +771,10 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
     node's network sends (`shapeset.aggregate_key_bucket`, which `cli
     node` derives from its preset and state): WARMING dispatches the
     aggregate profile at it, so the breaker never meets that bucket's
-    cold compile; None warms one key a lane only.
+    cold compile; None warms one key a lane only.  `block_key_bucket`
+    (`shapeset.block_key_bucket`, likewise derived) adds a block's
+    signature set at that bucket and at every narrower one a block can
+    reach.
 
     The node owns the returned service's lifecycle
     (`node/node.py:do_start`); nothing here blocks.
@@ -751,7 +811,8 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
         impl, _ = backend
         # the readiness snapshot's `warmup_cache` names them
         return {"key_buckets": _warmup_batches(impl, max_batch,
-                                               key_bucket)}
+                                               key_bucket,
+                                               block_key_bucket)}
 
     def install(backend):
         impl, device = backend
@@ -781,6 +842,7 @@ def make_supervisor(*, max_batch: int = 256, min_bucket: int = 16,
                     guarded, breaker, max_batch=max_batch,
                     min_bucket=min_bucket, registry=registry,
                     key_bucket=key_bucket,
+                    block_key_bucket=block_key_bucket,
                     supervisor=(supervisor_box[0] if supervisor_box
                                 else None))
                 if healer is not None:

@@ -113,7 +113,8 @@ def _precompile_findings(records: List[dict]) -> List[dict]:
                 from ..ops import shapeset
                 covered_memo[mesh_n] = shapeset.serving_shapes(
                     mesh_devices=mesh_n,
-                    key_bucket=shapeset.SERVICE_KEY_BUCKET)
+                    key_bucket=shapeset.SERVICE_KEY_BUCKET,
+                    block_key_bucket=shapeset.SERVICE_BLOCK_KEY_BUCKET)
             except Exception:  # pragma: no cover - odd mesh widths
                 covered_memo[mesh_n] = set()
         return shape in covered_memo[mesh_n]

@@ -156,6 +156,15 @@ _M_KEY_SLOTS = GLOBAL_REGISTRY.labeled_counter(
     "lanes x key bucket), by key bucket",
     labelnames=("kmax",))
 
+# A lane wider than any valid signature (`max_keys_per_lane`) is refused
+# as malformed, a False verdict with no dispatch: counted, so that a
+# refusal is never silent.
+_M_KEY_CAP = GLOBAL_REGISTRY.counter(
+    "bls_key_cap_refusals_total",
+    "verify lanes refused without a dispatch because they held more "
+    "public keys than the preset's widest valid signature "
+    "(MAX_VALIDATORS_PER_COMMITTEE x MAX_COMMITTEES_PER_SLOT)")
+
 
 def _dedup_ratio() -> float:
     # read unique BEFORE lanes (writers inc lanes first): a dispatch
@@ -220,14 +229,16 @@ class _Semi(BatchSemiAggregate):
     """Parsed, host-validated triple awaiting the device dispatch."""
 
     __slots__ = ("pk_limbs", "message", "sig_x_bytes", "sig_large",
-                 "sig_inf")
+                 "sig_inf", "key_s")
 
-    def __init__(self, pk_limbs, message, sig_x_bytes, sig_large, sig_inf):
+    def __init__(self, pk_limbs, message, sig_x_bytes, sig_large, sig_inf,
+                 key_s: float = 0.0):
         self.pk_limbs = pk_limbs     # list of (x_mont, y_mont) np (L,)
         self.message = message
         self.sig_x_bytes = sig_x_bytes  # (2, 48) BE bytes of (x1, x0)
         self.sig_large = sig_large
         self.sig_inf = sig_inf
+        self.key_s = key_s           # host seconds of the key lookup
 
 
 class _DispatchHandle:
@@ -450,7 +461,8 @@ class JaxBls12381(BLS12381):
 
     name = "jax-tpu"
 
-    def __init__(self, max_batch: int = 4096, max_keys_per_lane: int = 2048,
+    def __init__(self, max_batch: int = 4096,
+                 max_keys_per_lane: int = SS.MAX_KEYS_PER_LANE,
                  min_bucket: int = 4, mesh=None):
         self._pure = PureBls12381()
         self.max_batch = max_batch
@@ -467,6 +479,9 @@ class JaxBls12381(BLS12381):
                                                  min_bucket=min_bucket)
             min_bucket = self._sharded.min_bucket
             self.mesh_info = self._sharded.describe()
+        # the widest valid signature: an Electra attestation over a
+        # whole slot's committees (`shapeset.MAX_KEYS_PER_LANE`, 131,072
+        # on the mainnet preset); a wider lane is refused, and counted
         self.max_keys_per_lane = max_keys_per_lane
         # tiny batches pad up to one shared bucket: a couple of masked
         # lanes cost microseconds on device, a fresh XLA compile costs
@@ -645,8 +660,12 @@ class JaxBls12381(BLS12381):
         the cache lacks stays in the semi as its BYTES, its parsed wire
         goes into `miss`, and the device half resolves both."""
         public_keys, message, signature = triple
-        if not public_keys or len(public_keys) > self.max_keys_per_lane:
+        if not public_keys:
             return None
+        if len(public_keys) > self.max_keys_per_lane:
+            _M_KEY_CAP.inc()
+            return None
+        t0 = time.perf_counter()
         resolved: dict = {}
         self._lookup_pks(public_keys, resolved, miss)
         points = []
@@ -658,10 +677,11 @@ class JaxBls12381(BLS12381):
                 return None
             else:
                 points.append((entry[1], entry[2]))
+        key_s = time.perf_counter() - t0
         sig = _parse_g2_wire(signature)
         if sig is None:
             return None
-        return _Semi(points, message, *sig)
+        return _Semi(points, message, *sig, key_s=key_s)
 
     def prepare_batch_verify(
         self, triple: Tuple[Sequence[bytes], bytes, bytes]
@@ -951,6 +971,7 @@ class JaxBls12381(BLS12381):
         # (lane, key slot, pk bytes) of the keys the cache lacked: the
         # device half validates them and `fill_keys` packs them
         pk_pending = []
+        t_keys = time.perf_counter()
         for i, s in enumerate(semis):
             p = i if lane_pos is None else int(lane_pos[i])
             for j, pt in enumerate(s.pk_limbs):
@@ -963,6 +984,10 @@ class JaxBls12381(BLS12381):
             s_large[p] = s.sig_large
             s_inf[p] = s.sig_inf
             lane_valid[p] = True
+        # the key lookups (in `_host_semi`) and this fill: the host's
+        # part of the key axis
+        key_pack_s = (time.perf_counter() - t_keys
+                      + sum(s.key_s for s in semis))
         group_idx = np.zeros((u_total, g_bucket), dtype=np.int32)
         group_present = np.zeros((u_total, g_bucket), dtype=bool)
         row_gather = None
@@ -1007,7 +1032,7 @@ class JaxBls12381(BLS12381):
             "worker", "host_prep", time.perf_counter() - t_hp0,
             t_mono=t_hp0, trace_id=tracing.current_trace_id())
         return _Packed(
-            n=n, randomize=randomize, kmax=kmax,
+            n=n, randomize=randomize, kmax=kmax, key_pack_s=key_pack_s,
             n_unique=len(uniq_msgs), n_rows=len(rows),
             g_bucket=g_bucket, u_hm=u_hm, plan=plan, padded=padded,
             u_total=u_total, lane_pos=lane_pos, pk_xs=pk_xs,
@@ -1133,6 +1158,7 @@ class JaxBls12381(BLS12381):
             trace_ids=[t.trace_id for t in traces],
             shape=shape, mont_path=mont_path, randomized=pack.randomize,
             lanes=n, kmax=pack.kmax, keys=keys,
+            key_pack_s=round(pack.key_pack_s, 6),
             unique_messages=pack.n_unique, rows=pack.n_rows,
             group_bucket=pack.g_bucket,
             dedup_ratio=round((n - pack.n_unique) / n, 4),

@@ -39,6 +39,19 @@ SERVICE_MIN_BUCKET = 16
 # (`aggregate_key_bucket` of the mainnet preset): what `cli precompile`
 # builds and the doctor's coverage oracle assumes
 SERVICE_KEY_BUCKET = 512
+# the block key bucket of a mainnet node at ~1,000,000 validators
+# (`block_key_bucket`: an Electra block's slot-wide aggregate of 64
+# committees of 489), likewise
+SERVICE_BLOCK_KEY_BUCKET = 32768
+# the most keys one lane may hold: the widest valid signature of the
+# mainnet preset, an Electra attestation (EIP-7549 `committee_bits`)
+# over every committee of its slot, MAX_VALIDATORS_PER_COMMITTEE x
+# MAX_COMMITTEES_PER_SLOT; the provider refuses a wider lane
+MAX_KEYS_PER_LANE = 2048 * 64
+# a block's signature set as the node warms it: the proposer's
+# signature, the RANDAO reveal, the SyncAggregate and
+# MAX_ATTESTATIONS_ELECTRA (8) attestations, a lane each
+BLOCK_LANES = 3 + 8
 
 
 # --------------------------------------------------------------------------
@@ -58,24 +71,50 @@ def kmax_bucket(max_keys: int) -> int:
 def aggregate_key_bucket(cfg, active_validators: Optional[int] = None
                          ) -> int:
     """The `kmax` bucket of the largest aggregate a node of this
-    preset verifies: a block's `SyncAggregate` (`SYNC_COMMITTEE_SIZE`
+    preset verifies on gossip: the `SyncAggregate` (`SYNC_COMMITTEE_SIZE`
     keys) and, where the node knows how many validators are active, an
     attestation committee's aggregate on the `beacon_aggregate_and_proof`
     topic (the spec's `get_committee_count_per_slot` split of the active
-    set; the largest committee is the ceiling).  `cfg` is a preset
+    set; the largest committee is the ceiling).  It covers ONE
+    committee: an Electra block's attestations aggregate a whole slot's
+    committees, which is `block_key_bucket`'s.  `cfg` is a preset
     (`spec.config.SpecConfig`).  Mainnet: 512 (a committee of 489 at
     1,000,000 validators); minimal: 32."""
     largest = cfg.SYNC_COMMITTEE_SIZE
     if active_validators:
-        per_slot = max(1, min(
-            cfg.MAX_COMMITTEES_PER_SLOT,
-            active_validators // cfg.SLOTS_PER_EPOCH
-            // cfg.TARGET_COMMITTEE_SIZE))
-        committee = -(-active_validators
-                      // (cfg.SLOTS_PER_EPOCH * per_slot))
-        largest = max(largest, min(committee,
-                                   cfg.MAX_VALIDATORS_PER_COMMITTEE))
+        largest = max(largest, _committees(cfg, active_validators)[1])
     return kmax_bucket(largest)
+
+
+def _committees(cfg, active_validators: int) -> Tuple[int, int]:
+    """(committees a slot, members of the largest committee) at
+    `active_validators`: the spec's `get_committee_count_per_slot`
+    split of the active set."""
+    per_slot = max(1, min(
+        cfg.MAX_COMMITTEES_PER_SLOT,
+        active_validators // cfg.SLOTS_PER_EPOCH
+        // cfg.TARGET_COMMITTEE_SIZE))
+    committee = -(-active_validators // (cfg.SLOTS_PER_EPOCH * per_slot))
+    return per_slot, min(committee, cfg.MAX_VALIDATORS_PER_COMMITTEE)
+
+
+def block_key_bucket(cfg, active_validators: Optional[int] = None
+                     ) -> int:
+    """The `kmax` bucket of the widest signature a block of this node
+    carries.  From Electra on (`cfg.ELECTRA_FORK_EPOCH` scheduled) an
+    on-chain attestation aggregates every committee of its slot that
+    voted the same `AttestationData` (EIP-7549), so a block's majority
+    vote carries up to committees a slot x the largest committee
+    (mainnet at 1,000,000 validators: 64 x 489 = 31,296 -> 32,768).
+    Before Electra an attestation is one committee's, which
+    `aggregate_key_bucket` already covers with the SyncAggregate; so
+    does a node that does not know its active count."""
+    from ..spec.config import FAR_FUTURE_EPOCH
+    bucket = aggregate_key_bucket(cfg, active_validators)
+    if not active_validators or cfg.ELECTRA_FORK_EPOCH == FAR_FUTURE_EPOCH:
+        return bucket
+    per_slot, committee = _committees(cfg, active_validators)
+    return max(bucket, kmax_bucket(per_slot * committee))
 
 
 def _row_size(g) -> int:
@@ -193,7 +232,19 @@ def batch_plan(lane_groups: Sequence[int], *, min_bucket: int,
 # The warmup batch profiles (mirrors loader._warmup_batches)
 # --------------------------------------------------------------------------
 
-def warmup_profiles(max_batch: int, key_bucket: Optional[int] = None
+def block_buckets(block_key_bucket: int) -> List[int]:
+    """Every key bucket a valid block's widest lane can fall in, the
+    block bucket first: a late or low-participation slot's majority
+    vote is narrower, down to one key."""
+    out, k = [], kmax_bucket(block_key_bucket)
+    while k:
+        out.append(k)
+        k //= 2
+    return out
+
+
+def warmup_profiles(max_batch: int, key_bucket: Optional[int] = None,
+                    block_key_bucket: Optional[int] = None
                     ) -> List[Tuple[str, List[int], Optional[int], int]]:
     """The (name, lane_groups, h2c_missing, kmax) profiles supervisor
     WARMING and the selfheal reshape warm dispatch, in order: the x1
@@ -207,7 +258,13 @@ def warmup_profiles(max_batch: int, key_bucket: Optional[int] = None
     slot's first drain, every message fresh; then a later drain
     (`aggregate_forged`, one aggregate forged), whose slot root and
     committee messages the first put in the arena, so only the
-    aggregators' own messages are hashed."""
+    aggregators' own messages are hashed.  Given a `block_key_bucket`,
+    a block's signature set (`BLOCK_LANES` lanes, a message each, its
+    widest lane at that bucket) twice, every message fresh but the
+    RANDAO root (the x1 probe's), the second (`block_forged`) with its
+    widest lane forged; then `block_narrow` at each narrower bucket a
+    block can reach (`block_buckets`), on the first block's messages,
+    so that no block's key bucket compiles on the hot path."""
     profiles: List[Tuple[str, List[int], Optional[int], int]] = [
         ("x1", [1], None, 1),
         (f"x{max_batch}", [1] * max_batch, None, 1),
@@ -221,6 +278,12 @@ def warmup_profiles(max_batch: int, key_bucket: Optional[int] = None
         kmax = kmax_bucket(key_bucket)
         profiles += [("aggregate", groups, None, kmax),
                      ("aggregate_forged", groups, tasks, kmax)]
+    if block_key_bucket is not None and max_batch >= BLOCK_LANES:
+        groups = [1] * BLOCK_LANES
+        widest, *narrower = block_buckets(block_key_bucket)
+        profiles += [("block", groups, BLOCK_LANES - 1, widest),
+                     ("block_forged", groups, BLOCK_LANES - 1, widest)]
+        profiles += [("block_narrow", groups, 0, k) for k in narrower]
     return profiles
 
 
@@ -229,14 +292,22 @@ def serving_shapes(max_batch: int = SERVICE_MAX_BATCH,
                    mesh_devices: int = 0,
                    h2c_min_bucket: int = H2C_MIN_BUCKET_DEFAULT,
                    group_cap: int = GROUP_CAP_DEFAULT,
-                   key_bucket: Optional[int] = None) -> set:
+                   key_bucket: Optional[int] = None,
+                   block_key_bucket: Optional[int] = None) -> set:
     """The ledger `shape` strings ``cli precompile`` covers for one
     serving config — the doctor's cold_compile_on_hot_path coverage
     oracle.  Includes every duplication profile from all-unique down
     to fully-duplicated at each pow-2 batch size up to max_batch (the
     warmup profiles are a subset), at one key a lane and, given a
-    `key_bucket`, at that many."""
+    `key_bucket`, at that many; given a `block_key_bucket`, a block's
+    lanes at every bucket `block_buckets` names."""
     shapes = set()
+    for kmax in (block_buckets(block_key_bucket)
+                 if block_key_bucket is not None else ()):
+        shapes.add(batch_plan(
+            [1] * BLOCK_LANES, min_bucket=min_bucket, kmax=kmax,
+            h2c_min_bucket=h2c_min_bucket, group_cap=group_cap,
+            mesh_devices=mesh_devices)["shape"])
     kmaxes = (1,) if key_bucket is None else (1, key_bucket)
     size = 1
     while size <= next_pow2(max_batch):
@@ -267,6 +338,7 @@ def _sds(shape, dtype):
 def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
                        min_bucket: int = SERVICE_MIN_BUCKET,
                        key_bucket: Optional[int] = None,
+                       block_key_bucket: Optional[int] = None,
                        h2c_min_bucket: int = H2C_MIN_BUCKET_DEFAULT,
                        group_cap: int = GROUP_CAP_DEFAULT,
                        mesh: Optional[object] = None,
@@ -281,7 +353,7 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
     single-device); mesh programs additionally need the gather
     scatter program and the sharded kernel itself.  `key_bucket` adds
     the aggregate profile (``warmup_profiles``) at that many keys a
-    lane.
+    lane, `block_key_bucket` the block profiles.
     """
     import jax
     import numpy as np
@@ -321,7 +393,7 @@ def enumerate_programs(*, max_batch: int = SERVICE_MAX_BATCH,
         yield out
 
     for name, lane_groups, h2c_missing, kmax in warmup_profiles(
-            max_batch, key_bucket):
+            max_batch, key_bucket, block_key_bucket):
         plan = batch_plan(lane_groups, min_bucket=min_bucket,
                           kmax=kmax, h2c_min_bucket=h2c_min_bucket,
                           group_cap=group_cap,

@@ -95,6 +95,16 @@ class BatchSignatureVerifier(SignatureVerifier):
     dispatch via bls.batch_verify).  Use once per imported block; a
     False batch_verify invalidates every optimistic True.
 
+    A lane's keys: since Electra (EIP-7549) one on-chain attestation
+    aggregates every committee of its slot that voted the same
+    `AttestationData`, so a block's majority vote carries up to
+    `MAX_COMMITTEES_PER_SLOT` x the largest committee (~31,300 keys at
+    1,000,000 validators; `shapeset.block_key_bucket` is the bucket the
+    node warms for it), and an attester slashing's indexed attestation
+    up to `MAX_COMMITTEES_PER_SLOT` x `MAX_VALIDATORS_PER_COMMITTEE`
+    (131,072 on mainnet), the provider's `max_keys_per_lane`, beyond
+    which it refuses a lane (`shapeset.MAX_KEYS_PER_LANE`).
+
     Class: BLOCK_IMPORT — this path bypasses the batching queue
     entirely (one direct dispatch), which IS the strongest priority:
     it never waits behind gossip and can never be shed.  The class is

@@ -132,7 +132,7 @@ def test_enumerate_programs_include_the_key_bucket():
 
 def test_cli_precompile_enumerates_the_key_bucket(monkeypatch, tmp_path,
                                                   capsys):
-    """`cli precompile` builds the mainnet key bucket into the AOT
+    """`cli precompile` builds the mainnet key buckets into the AOT
     store (the XLA work stubbed: the enumeration is what is pinned)."""
     from teku_tpu import cli
     from teku_tpu.infra import aotstore
@@ -155,6 +155,10 @@ def test_cli_precompile_enumerates_the_key_bucket(monkeypatch, tmp_path,
                 if kernel.startswith("stage:prepare:")]
     assert (MAX_BATCH, shapeset.SERVICE_KEY_BUCKET, fp.L) in prepares
     assert (MAX_BATCH, 1, fp.L) in prepares
+    # and a block's widest bucket and every narrower one (a block is
+    # eleven lanes: lane bucket 16)
+    assert {(16, k, fp.L) for k in shapeset.block_buckets(
+        shapeset.SERVICE_BLOCK_KEY_BUCKET)} <= set(prepares)
 
 
 def _leaves(tree):

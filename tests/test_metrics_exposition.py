@@ -382,17 +382,19 @@ def test_prep_turn_family_label_contract():
 def test_key_slot_family_label_contract():
     """The key axis's two counters carry exactly one `kmax` label from
     the CLOSED power-of-two vocabulary `shapeset.kmax_bucket` emits
-    (keys a lane, padded; a committee or the sync committee is 512, a
-    block's largest aggregate at most 2048), and a dispatch moves the
-    filled one by no more than the dispatched one."""
+    (keys a lane, padded; a committee or the sync committee is 512, an
+    Electra block's slot-wide vote 32,768 at ~1M validators, the
+    provider's ceiling `shapeset.MAX_KEYS_PER_LANE` 131,072), and a
+    dispatch moves the filled one by no more than the dispatched one."""
     import teku_tpu.ops.provider  # noqa: F401 - registers families
     from teku_tpu.infra.metrics import GLOBAL_REGISTRY
     from teku_tpu.ops import shapeset
 
     metrics = GLOBAL_REGISTRY.metrics()
-    pow2_vocab = {str(1 << i) for i in range(0, 12)}    # 1..2048
+    pow2_vocab = {str(1 << i) for i in range(0, 18)}    # 1..131072
     assert {str(shapeset.kmax_bucket(k))
-            for k in (1, 2, 3, 400, 488, 512, 2048)} <= pow2_vocab
+            for k in (1, 2, 3, 400, 488, 512, 2048, 31_296,
+                      shapeset.MAX_KEYS_PER_LANE)} <= pow2_vocab
     filled = metrics["bls_key_slots_filled_total"]
     slots = metrics["bls_key_slots_dispatched_total"]
     for fam in (filled, slots):
@@ -407,20 +409,21 @@ def test_key_slot_family_label_contract():
 
 def test_warmup_dispatch_family_label_contract():
     """`bls_warmup_dispatches_total` carries exactly (`profile`,
-    `kmax`): the names of `shapeset.warmup_profiles` (five a process:
-    x1, x<max_batch>, x<max_batch>dup8, aggregate, aggregate_forged)
-    and the power-of-two
-    key buckets `shapeset.kmax_bucket` emits; one warm dispatch moves
-    its own series by one."""
+    `kmax`): the names of `shapeset.warmup_profiles` (x1,
+    x<max_batch>, x<max_batch>dup8, aggregate, aggregate_forged, block,
+    block_forged, block_narrow) and the power-of-two key buckets
+    `shapeset.kmax_bucket` emits, up to the block bucket; one warm
+    dispatch moves its own series by one."""
     import re
 
     from teku_tpu.crypto.bls import loader
     from teku_tpu.infra.metrics import GLOBAL_REGISTRY
     from teku_tpu.ops import shapeset
 
-    names = re.compile(r"^(x1|x\d+|x\d+dup8|aggregate|aggregate_forged)$")
+    names = re.compile(r"^(x1|x\d+|x\d+dup8|aggregate|aggregate_forged"
+                       r"|block|block_forged|block_narrow)$")
     assert all(names.match(name) for name, *_ in
-               shapeset.warmup_profiles(256, 512))
+               shapeset.warmup_profiles(256, 512, 32_768))
 
     class Device:
         min_bucket = 16
@@ -435,7 +438,7 @@ def test_warmup_dispatch_family_label_contract():
     loader._warm_dispatch(Device(), "x1", 1, [([b"pk"], b"m", b"s")],
                           True)
     assert fam.labels(profile="x1", kmax="1").value == before + 1
-    pow2_vocab = {str(1 << i) for i in range(0, 12)}
+    pow2_vocab = {str(1 << i) for i in range(0, 18)}
     for (profile, kmax), _child in fam._items():
         assert names.match(profile) and kmax in pow2_vocab, (profile,
                                                               kmax)

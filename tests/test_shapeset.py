@@ -242,7 +242,7 @@ def test_enumerated_programs_are_the_launched_ones(
     impl.batch_verify(_drain(pks, lane_groups, b"drain"))
     monkeypatch.setattr(
         shapeset, "warmup_profiles",
-        lambda max_batch, key_bucket=None: [
+        lambda max_batch, *_buckets: [
             ("drain", lane_groups, h2c_missing, 1)])
     enumerated = {
         (kernel, aotstore.shape_sig(avals))
@@ -270,7 +270,7 @@ def test_registry_follows_the_signature_row(monkeypatch, env, lane_groups,
     from teku_tpu.ops import limbs as fp
     impl, _pks = _cell_provider(monkeypatch, env)
     monkeypatch.setattr(shapeset, "warmup_profiles",
-                        lambda max_batch, key_bucket=None: [
+                        lambda max_batch, *_buckets: [
                             ("drain", lane_groups, None, 1)])
     avals = {meta["stage"]: avals for _k, avals, meta in
              shapeset.enumerate_programs(
@@ -305,7 +305,7 @@ def test_wide_batches_plan_the_same_stage_programs(monkeypatch, lanes,
     plan = shapeset.batch_plan(groups, min_bucket=16)
     assert plan["lanes"] == lanes and plan["msm_path"] == "ladder"
     monkeypatch.setattr(shapeset, "warmup_profiles",
-                        lambda max_batch, key_bucket=None: [
+                        lambda max_batch, *_buckets: [
                             ("wide", groups, None, 1)])
     stages = [m["stage"] for _k, _a, m in shapeset.enumerate_programs(
         max_batch=4096, min_bucket=16)]
